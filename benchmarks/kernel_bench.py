@@ -36,7 +36,7 @@ def run():
     wq = quantize(w, MXFormat(6, 256), axis=0)
 
     t = timer(lambda: mxint_matmul(x, wq.mantissa, wq.exponent, w_block=256,
-                                   bm=128, bn=128, bk=256))
+                                   bm=128, bn=128))
     rows.append(("kernel/mxint_matmul_128x1024x512", round(t, 1),
                  "pallas interpret"))
     t = timer(lambda: ref.mxint_matmul_ref(x, wq.mantissa, wq.exponent,
@@ -87,7 +87,6 @@ def run():
 
     rows.extend(deit_mode_rows())
     rows.extend(deit_ln_fusion_rows())
-    rows.extend(deit_sharded_rows())
     rows.extend(lm_batching_rows())
     return rows
 
@@ -212,49 +211,6 @@ def deit_mode_rows(archs=("deit_tiny", "deit_small"), batch: int = 1,
                          round(t, 1),
                          "pallas interpret" if mode == "kernel"
                          else "xla"))
-    return rows
-
-
-def deit_sharded_rows(tp: int = 2):
-    """off / sim / kernel / kernel-sharded forward wall-clock (CPU).
-
-    The sharded cell needs a multi-device backend, which can only be
-    forced BEFORE jax initializes — so this row runs
-    ``repro.serving.sharded_check --bench`` as a subprocess (the dryrun
-    pattern) and converts its timings.  Returns a skip row when the
-    subprocess fails (e.g. single-core CI without fakeable devices).
-    """
-    import json
-    import os
-    import subprocess
-    import sys
-    from pathlib import Path
-
-    root = Path(__file__).resolve().parents[1]
-    env = dict(os.environ)
-    env["REPRO_XLA_FLAGS"] = f"--xla_force_host_platform_device_count={tp}"
-    env["PYTHONPATH"] = str(root / "src")
-    try:
-        proc = subprocess.run(
-            [sys.executable, "-m", "repro.serving.sharded_check",
-             "--bench", "--tp", str(tp)],
-            capture_output=True, text=True, timeout=560, env=env,
-            cwd=str(root))
-        rep = json.loads(proc.stdout.strip().splitlines()[-1])
-    except Exception as e:                       # bench must never hard-fail
-        return [("kernel/deit_tiny_sharded_skipped", 0.0, f"skipped: {e}")]
-    if proc.returncode != 0:
-        return [("kernel/deit_tiny_sharded_skipped", 0.0,
-                 "skipped: " + proc.stderr[-200:])]
-    rows = []
-    for mode, ms in rep["bench_ms"].items():
-        note = ("pallas interpret, shard_map" if mode.startswith("kernel_tp")
-                else "pallas interpret" if mode == "kernel" else "xla")
-        rows.append((f"kernel/{rep['arch']}_forward_tp_bench_{mode}",
-                     round(ms * 1e3, 1), note))     # ms -> us (CSV unit)
-    rows.append((f"kernel/{rep['arch']}_sharded_bit_exact",
-                 float(rep["parity"]["column"]["bit_exact"]),
-                 "column TP == single-device sim, bitwise"))
     return rows
 
 

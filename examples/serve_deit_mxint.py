@@ -15,7 +15,8 @@ bit-for-bit — the serving datapath IS the validated datapath.
 With ``--tp N`` the engine serves SHARDED: the packed planes are
 partitioned over an N-way 'model' mesh and every linear runs per shard
 under shard_map — still bit-identical to the single-device sim oracle
-(DESIGN.md §10).  On CPU the fake devices are forced automatically.
+(DESIGN.md §10).  Under ``JAX_PLATFORMS=cpu`` the N fake host devices are
+forced automatically; on a TPU host the mesh takes N chips.
 
 Requests are streamed through ``ClassifyScheduler``: each request carries
 a RANDOM number of images, and the scheduler packs them across request
@@ -54,7 +55,9 @@ def _parse_args():
 
 def main():
     args = _parse_args()
-    if args.tp > 1 and "xla_force_host_platform_device_count" not in \
+    on_cpu = os.environ.get("JAX_PLATFORMS", "").split(",")[0] == "cpu"
+    if args.tp > 1 and on_cpu and \
+            "xla_force_host_platform_device_count" not in \
             os.environ.get("XLA_FLAGS", ""):
         # must land before the first jax device query (backend init)
         os.environ["XLA_FLAGS"] = (
@@ -69,10 +72,13 @@ def main():
     from benchmarks import common
     from repro.core.mx_types import QuantConfig
     from repro.data.pipeline import SyntheticImageData
+    from repro.launch.compile_cache import use_persistent_compile_cache
     from repro.models import build_model
     from repro.serving.engine import ServeConfig, ViTServingEngine
     from repro.serving.scheduler import ClassifyRequest, ClassifyScheduler
 
+    use_persistent_compile_cache()
+    print(f"devices: {jax.devices()[0].platform} x{jax.device_count()}")
     print("training/loading the float DeiT (synthetic 100-class task)...")
     model_f, params = common.trained_deit_micro()
 

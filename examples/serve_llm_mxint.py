@@ -22,6 +22,7 @@ import numpy as np
 
 from repro.configs import smoke_config
 from repro.core.mx_types import MXINT8_WEIGHT, QuantConfig
+from repro.launch.compile_cache import use_persistent_compile_cache
 from repro.models import build_model
 from repro.serving.engine import ServeConfig, ServingEngine
 from repro.serving.scheduler import BatchScheduler, Request
@@ -36,6 +37,7 @@ def main():
                     help="mode='kernel': Pallas linears + fused decode "
                          "attention over the cache ring")
     args = ap.parse_args()
+    use_persistent_compile_cache()
 
     cfg = smoke_config(args.arch)
     if args.kernel:

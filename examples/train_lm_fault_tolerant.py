@@ -16,6 +16,7 @@ import jax.numpy as jnp
 
 from repro.configs import smoke_config
 from repro.data.pipeline import SyntheticLMData
+from repro.launch.compile_cache import use_persistent_compile_cache
 from repro.models import build_model
 from repro.optim.adamw import AdamWConfig
 from repro.optim.schedules import cosine_schedule
@@ -47,6 +48,7 @@ def main():
     ap.add_argument("--batch", type=int, default=8)
     ap.add_argument("--seq", type=int, default=32)
     args = ap.parse_args()
+    use_persistent_compile_cache()
 
     tmpdir = Path("/tmp/repro_train_demo")
     shutil.rmtree(tmpdir, ignore_errors=True)

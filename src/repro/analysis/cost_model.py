@@ -14,7 +14,7 @@ execution:
   operands, so their bytes are accounted separately, at 1 byte/element —
   the paper's packed-plane memory win is visible per row.
 * **FLOPs** — closed-form per kernel family from block shapes and the
-  partial's config (dot products 2·m·k·n; one-hot LUT contractions
+  partial's config (dot products 2·m·k·n; select-chain LUT lookups
   2·elements·2^bits; O(10)·elements vector work for the rowwise
   datapaths).  Formulas are in DESIGN.md §14; they feed the arithmetic-
   intensity column of the roofline table, while the BYTE columns are the
@@ -290,7 +290,7 @@ def fusion_study(arch: str = "deit_tiny") -> Dict[str, object]:
     mm_caps = capture_pallas_calls(
         lambda x, m, e: mxint_matmul.__wrapped__(
             x, m, e, w_block=w_block, act_block=16, act_mant_bits=8,
-            quantize_act=True, bm=1, bn=bn, bk=d, interpret=True,
+            quantize_act=True, bm=1, bn=bn, interpret=True,
             out_dtype=jnp.float32),
         _sds((M, d)), _sds((d, d), jnp.int8),
         _sds((d // w_block, d), jnp.int8),

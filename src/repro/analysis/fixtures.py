@@ -100,9 +100,7 @@ def float_softmax_in_kernel_trace() -> List[Violation]:
 def f64_leak() -> List[Violation]:
     """An f64 upcast mid-trace (x64 enabled only inside the fixture —
     the default f32 canonicalisation would silently hide the leak)."""
-    from jax.experimental import enable_x64
-
-    with enable_x64():
+    with jax.enable_x64(True):
         return lint_fn(
             lambda x: (x.astype(jnp.float64) * 2.0).astype(jnp.float32),
             (jnp.zeros((4, 4), jnp.float32),), TraceRules(),
@@ -172,7 +170,7 @@ def race_parallel_accumulator() -> List[Violation]:
     from repro.analysis.grid_semantics import check_captures_semantics
 
     return check_captures_semantics(_acc_capture(
-        _acc_kernel, pltpu.TPUCompilerParams(
+        _acc_kernel, pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel"))))
 
 
@@ -184,7 +182,7 @@ def reversed_init_flush() -> List[Violation]:
     from repro.analysis.grid_semantics import check_captures_semantics
 
     return check_captures_semantics(_acc_capture(
-        _reversed_acc_kernel, pltpu.TPUCompilerParams(
+        _reversed_acc_kernel, pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"))))
 
 
@@ -197,7 +195,7 @@ def unaliased_inplace_output() -> List[Violation]:
 
     return check_captures_semantics(_capture_2d(
         (512, 256), (128, 256), kernel=_inplace_kernel,
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel"))))
 
 

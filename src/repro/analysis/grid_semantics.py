@@ -345,7 +345,7 @@ def check_capture_semantics(cap: PallasCapture) -> List[Violation]:
             f"pallas_call declares no dimension_semantics for grid "
             f"{cap.grid}; required: "
             f"{tuple('arbitrary' if a in required else 'parallel' for a in range(naxes))} "
-            f"(declare via compiler_params=pltpu.TPUCompilerParams(...))"))
+            f"(declare via compiler_params=pltpu.CompilerParams(...))"))
     elif len(ds) != naxes:
         out.append(Violation(
             "grid-semantics", _where(cap),

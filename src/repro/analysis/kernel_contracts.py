@@ -92,7 +92,7 @@ class PallasCapture:
 
 def _dimension_semantics(compiler_params) -> Optional[Tuple[str, ...]]:
     """Extract dimension_semantics from a ``compiler_params`` kwarg in any
-    of the forms pallas_call accepts (TPUCompilerParams dataclass, flat
+    of the forms pallas_call accepts (CompilerParams dataclass, flat
     dict, or the legacy {"mosaic": {...}} nesting)."""
     if compiler_params is None:
         return None
@@ -241,9 +241,8 @@ def _check_alignment(cap: PallasCapture, use: BlockUse) -> List[Violation]:
                     "kernel-contracts", _where(cap),
                     f"{use.name}: second-minor tiled block dim {blk} is not "
                     f"a multiple of {use.dtype}'s native ({native},{LANE}) "
-                    f"tile — Mosaic may need a relayout on real hardware "
-                    f"(for mxint exponent planes, exp_block_rows={native} "
-                    f"selects the native fetch)", severity=WARN))
+                    f"tile — Mosaic may need a relayout on real hardware",
+                    severity=WARN))
     return out
 
 
@@ -378,18 +377,17 @@ def _sweep_matmul() -> List[PallasCapture]:
     caps += capture_pallas_calls(
         lambda x, m, e: mxint_matmul.__wrapped__(
             x, m, e, w_block=256, act_block=16, act_mant_bits=8,
-            quantize_act=True, bm=128, bn=128, bk=256, interpret=True,
+            quantize_act=True, bm=128, bn=128, interpret=True,
             out_dtype=jnp.float32),
         _sds((128, 1024)), _sds((1024, 512), jnp.int8),
         _sds((4, 512), jnp.int8), label="matmul-bench")
-    # mxint_linear compiled-TPU tiling: bk=512, OCP-32 weight blocks,
-    # exponent plane fetched in its native int8 (32, 128) tile (the
-    # exp_block_rows ops.py wiring — keeps the relayout WARN retired)
+    # OCP-32 weight blocks over K=1024: the exponent plane's (32, 128)
+    # block is int8's native tile
     caps += capture_pallas_calls(
         lambda x, m, e: mxint_matmul.__wrapped__(
             x, m, e, w_block=32, act_block=16, act_mant_bits=8,
-            quantize_act=True, bm=128, bn=128, bk=512, exp_block_rows=32,
-            interpret=False, out_dtype=jnp.float32),
+            quantize_act=True, bm=128, bn=128, interpret=False,
+            out_dtype=jnp.float32),
         _sds((128, 1024)), _sds((1024, 768), jnp.int8),
         _sds((32, 768), jnp.int8), label="matmul-compiled")
     # DeiT-Tiny model-path linear: 2x197 tokens padded to 400 rows,
@@ -400,7 +398,7 @@ def _sweep_matmul() -> List[PallasCapture]:
     caps += capture_pallas_calls(
         lambda x, m, e: mxint_matmul.__wrapped__(
             x, m, e, w_block=32, act_block=16, act_mant_bits=8,
-            quantize_act=True, bm=16, bn=128, bk=192, interpret=True,
+            quantize_act=True, bm=16, bn=128, interpret=True,
             out_dtype=jnp.float32),
         _sds((400, 192)), _sds((192, 256), jnp.int8),
         _sds((6, 256), jnp.int8), label="matmul-deit")

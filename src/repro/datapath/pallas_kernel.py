@@ -18,6 +18,7 @@ bit-identical to the unfused two-kernel sequence by construction
 """
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 
 from repro.datapath.base import Datapath
@@ -79,10 +80,9 @@ class PallasKernelDatapath(Datapath):
 
     # -- fused LN -> linear composite (DESIGN.md §12) ------------------------
     def fuses_norm_linear(self, q, x=None, w=None) -> bool:
-        """Fusion needs the MXInt LN datapath (float LN has no kernel),
+        """Fusion needs the MXInt LN datapath (float LN has no kernel) and
         un-psum-sharded planes (the contraction shard never sees the full
-        row the LN normalizes) and — on compiled TPU — the tileability
-        gate of ``mxint_ln_linear_op``; interpret mode pads any shape in.
+        row the LN normalizes); the fused kernel pads any shape in.
         Callers hoist the norm whenever this says False, so the composite
         never degrades into replaying the unfused pair per consumer."""
         if not self.nl_on(q, "layernorm"):
@@ -91,19 +91,7 @@ class PallasKernelDatapath(Datapath):
             return True
         from repro.core.quantize import MXTensor
         wv = w.value
-        if isinstance(wv, MXTensor):
-            if wv.tp_mode == "psum":
-                return False
-            n = wv.mantissa.shape[-1]
-        else:
-            n = wv.shape[-1]
-        from repro.kernels import ops
-        if ops._interpret() or x is None:
-            return True
-        m = 1
-        for d in x.shape[:-1]:
-            m *= d
-        return m % 8 == 0 and x.shape[-1] % 128 == 0 and n % 128 == 0
+        return not (isinstance(wv, MXTensor) and wv.tp_mode == "psum")
 
     def _norm_then_linear(self, x, gamma, beta, wv, b, *, q, eps,
                           rms_only):
@@ -166,36 +154,32 @@ class PallasKernelDatapath(Datapath):
     # -- attention -----------------------------------------------------------
     def attention(self, qv, k, v, *, q, positions, causal: bool,
                   window: int, scale: float, chunk: int):
-        # heads-major layout into attention_op.  'paper' variant =
-        # whole-row MXInt softmax in the Pallas kernel (bit-identical to
-        # the sim direct path); blocked mxint flash for long sequences;
-        # float flash otherwise.
-        from repro.kernels import ops as kops
         b, s, kvh, g, hd = qv.shape
         S = k.shape[1]
+        if self.nl_on(q, "softmax") and s * S <= 512 * 512:
+            # whole-row paper softmax (the ViT / encoder path): the sim
+            # oracle's own masking and contractions around the Eq. 14-20
+            # softmax kernel (``self.softmax``), so 'kernel' and 'sim'
+            # agree bit for bit.  The contractions take f32 operands off
+            # any quantization grid; full precision keeps them f32 on the
+            # TPU, whose default would round them to bf16.
+            with jax.default_matmul_precision("highest"):
+                return super().attention(
+                    qv, k, v, q=q, positions=positions, causal=causal,
+                    window=window, scale=scale, chunk=chunk)
+        # heads-major layout into the flash kernel: the blocked mxint
+        # datapath for long sequences (no O(S^2) score matrix —
+        # DESIGN.md §11), float flash otherwise
+        from repro.kernels import ops as kops
         qh = jnp.einsum("bskgd->bkgsd", qv).reshape(b, kvh * g, s, hd)
         kh = jnp.einsum("bSkd->bkSd", k)          # (b, kvh, S, hd), no copy
         vh = jnp.einsum("bSkd->bkSd", v)
         if self.nl_on(q, "softmax"):
-            if s * S <= 512 * 512:
-                # whole-row 'paper' softmax: bit-identical to the sim
-                # direct path (the ViT / encoder production path)
-                o = kops.attention_op(
-                    qh, kh, vh, causal=causal, window=window,
-                    softmax_variant="paper",
-                    act_block=q.act_fmt.block_size,
-                    mant_bits=q.act_fmt.mant_bits,
-                    r_bits=q.nonlinear.softmax_r_bits)
-            else:
-                # long sequences: blocked mxint flash — the Eq. 14-20
-                # datapath without the O(S^2) score matrix (DESIGN.md §11)
-                o = kops.attention_op(
-                    qh, kh, vh, causal=causal, window=window,
-                    softmax_variant="online", exp_mode="mxint",
-                    quantize_scores=True,
-                    act_block=q.act_fmt.block_size,
-                    mant_bits=q.act_fmt.mant_bits,
-                    r_bits=q.nonlinear.softmax_r_bits)
+            o = kops.attention_op(
+                qh, kh, vh, causal=causal, window=window, exp_mode="mxint",
+                quantize_scores=True, act_block=q.act_fmt.block_size,
+                mant_bits=q.act_fmt.mant_bits,
+                r_bits=q.nonlinear.softmax_r_bits)
         else:
             o = kops.attention_op(qh, kh, vh, causal=causal, window=window,
                                   exp_mode="float")

@@ -49,9 +49,8 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import luts
 from repro.core.quantize import _resolve_block
-from repro.kernels.mxint_layernorm import (block_quantize_rows,
-                                           requantize_rows,
-                                           requantize_to_grid)
+from repro.kernels.block_quant import (block_quantize, requantize_rows,
+                                       requantize_to_grid)
 from repro.kernels.mxint_softmax import exp2_datapath
 
 _LOG2E = 1.4426950408889634
@@ -88,13 +87,13 @@ def _softmax_block_update(s, mask, pad_mask, v, write, m_sc, l_sc, acc_sc,
             # _PAD_FILL for the quantizer's amax (see its comment),
             # reinstate NEG_INF after dequantization
             s = jnp.where(pad_mask, s, _PAD_FILL)
-        m, e = block_quantize_rows(s, act_block, mant_bits)
+        m, e = block_quantize(s, act_block, mant_bits)
         mf, lam = requantize_rows(m, e)
         # exact dequantize: integer-valued f32 mantissas times a power of
         # two — (mf_i - mf_max) * 2^lam stays exact, so the z fed to the
         # LUT is bit-identical to the whole-row kernel's mantissa-domain
         # subtract when one k block covers the row
-        s = mf.reshape(s.shape) * jnp.exp2(lam.astype(jnp.float32))
+        s = mf * jnp.exp2(lam.astype(jnp.float32))
     if pad_mask is not None:
         s = jnp.where(pad_mask, s, NEG_INF)
 
@@ -166,8 +165,8 @@ def _softmax_block_update(s, mask, pad_mask, v, write, m_sc, l_sc, acc_sc,
             write(o)
 
 
-def _flash_kernel(q_ref, k_ref, v_ref, lut_ref, o_ref, m_sc, l_sc, acc_sc, *,
-                  scale: float, causal: bool, window: int,
+def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_sc, l_sc, acc_sc, *,
+                  lut: tuple, scale: float, causal: bool, window: int,
                   kv_len: int | None, exp_mode: str, r_bits: int,
                   quantize_scores: bool, act_block: int, mant_bits: int,
                   block_q: int, block_k: int, n_k: int):
@@ -200,7 +199,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, lut_ref, o_ref, m_sc, l_sc, acc_sc, *,
         o_ref[0] = o.astype(o_ref.dtype)
 
     _softmax_block_update(s, mask, pad_mask, v, write, m_sc, l_sc, acc_sc,
-                          lut_ref[...], exp_mode=exp_mode, r_bits=r_bits,
+                          lut, exp_mode=exp_mode, r_bits=r_bits,
                           quantize_scores=quantize_scores,
                           act_block=act_block, mant_bits=mant_bits,
                           kb=kb, n_k=n_k)
@@ -240,10 +239,10 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
     if scale is None:
         scale = 1.0 / (d ** 0.5)
     n_k = sk // block_k
-    lut = luts.pow2_lut(r_bits)
 
     kernel = functools.partial(
-        _flash_kernel, scale=scale, causal=causal, window=window,
+        _flash_kernel, lut=luts.pow2_table(r_bits), scale=scale,
+        causal=causal, window=window,
         kv_len=kv_len if (kv_len is not None and kv_len < sk) else None,
         exp_mode=exp_mode, r_bits=r_bits, quantize_scores=quantize_scores,
         act_block=act_block, mant_bits=mant_bits, block_q=block_q,
@@ -258,7 +257,6 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
                          lambda b, i, j: (b // kv_groups, j, 0)),
             pl.BlockSpec((1, block_k, d),
                          lambda b, i, j: (b // kv_groups, j, 0)),
-            pl.BlockSpec((lut.shape[0],), lambda b, i, j: (0,)),
         ],
         out_specs=pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
@@ -270,17 +268,17 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
         # (batch*head, q-block) tiles are independent; the key axis
         # carries the online-softmax (m, l, acc) scratch sequentially
         # (DESIGN.md §14).
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-    )(q, k, v, lut)
+    )(q, k, v)
 
 
 # ---------------------------------------------------------------------------
 # single-query decode variant (DESIGN.md §11)
 # ---------------------------------------------------------------------------
-def _decode_kernel(q_ref, k_ref, v_ref, valid_ref, lut_ref, o_ref,
-                   m_sc, l_sc, acc_sc, *, scale: float, w_len: int | None,
+def _decode_kernel(q_ref, k_ref, v_ref, valid_ref, o_ref, m_sc, l_sc,
+                   acc_sc, *, lut: tuple, scale: float, w_len: int | None,
                    exp_mode: str, r_bits: int, quantize_scores: bool,
                    act_block: int, mant_bits: int, block_k: int, n_k: int):
     kb = pl.program_id(2)
@@ -310,7 +308,7 @@ def _decode_kernel(q_ref, k_ref, v_ref, valid_ref, lut_ref, o_ref,
         o_ref[0, 0] = o.astype(o_ref.dtype)
 
     _softmax_block_update(s, mask, pad_mask, v, write, m_sc, l_sc, acc_sc,
-                          lut_ref[...], exp_mode=exp_mode, r_bits=r_bits,
+                          lut, exp_mode=exp_mode, r_bits=r_bits,
                           quantize_scores=quantize_scores,
                           act_block=act_block, mant_bits=mant_bits,
                           kb=kb, n_k=n_k)
@@ -355,10 +353,9 @@ def flash_attention_decode(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     if scale is None:
         scale = 1.0 / (d ** 0.5)
     n_k = W // block_k
-    lut = luts.pow2_lut(r_bits)
 
     kernel = functools.partial(
-        _decode_kernel, scale=scale,
+        _decode_kernel, lut=luts.pow2_table(r_bits), scale=scale,
         w_len=w_len if (w_len is not None and w_len < W) else None,
         exp_mode=exp_mode, r_bits=r_bits, quantize_scores=quantize_scores,
         act_block=act_block, mant_bits=mant_bits, block_k=block_k, n_k=n_k)
@@ -371,7 +368,6 @@ def flash_attention_decode(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
             pl.BlockSpec((1, block_k, 1, d), lambda i, h, j: (i, j, h, 0)),
             pl.BlockSpec((1, block_k, 1, d), lambda i, h, j: (i, j, h, 0)),
             pl.BlockSpec((1, block_k), lambda i, h, j: (i, j)),
-            pl.BlockSpec((lut.shape[0],), lambda i, h, j: (0,)),
         ],
         out_specs=pl.BlockSpec((1, 1, g, d), lambda i, h, j: (i, h, 0, 0)),
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
@@ -382,7 +378,7 @@ def flash_attention_decode(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
         ],
         # (batch, kv-head) tiles are independent; the cache-window axis
         # carries the online-softmax scratch sequentially (DESIGN.md §14).
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-    )(q, k, v, valid.astype(jnp.int32), lut)
+    )(q, k, v, valid.astype(jnp.int32))

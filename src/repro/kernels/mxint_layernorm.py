@@ -10,11 +10,9 @@ VMEM):
      exponent split of Eq. 9; exponent handled by shift,
   5. scale, gamma/beta, write.
 
-The LUT lives in VMEM and is applied as a one-hot contraction — on TPU a
-32-entry lookup over a (rows, d) tile is a (rows*d, 32) x (32,) matvec, which
-the MXU eats for free; this is the TPU-native analogue of the FPGA LUT
-(DESIGN.md §2) and is bit-identical to `jnp.take` (one-hot rows select a
-single f32 entry exactly).
+The LUT is baked into the kernel as constants and applied as a select
+chain over its entries (``lut_lookup``) — the TPU-native analogue of the
+FPGA LUT (DESIGN.md §2), bit-identical to `jnp.take`.
 """
 from __future__ import annotations
 
@@ -26,58 +24,23 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import luts
+from repro.kernels.block_quant import (block_quantize, requantize_rows,
+                                       requantize_to_grid)
 
 
-def lut_lookup(idx: jnp.ndarray, table: jnp.ndarray) -> jnp.ndarray:
-    """One-hot-matmul LUT gather (MXU-friendly, exact)."""
-    entries = table.shape[0]
-    onehot = (idx[..., None] == jnp.arange(entries, dtype=jnp.int32)
-              ).astype(table.dtype)
-    return jax.lax.dot_general(
-        onehot.reshape(-1, entries), table[:, None],
-        (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32).reshape(idx.shape)
+def lut_lookup(idx: jnp.ndarray, table: tuple) -> jnp.ndarray:
+    """``table[idx]`` for a static table of f32 entries, as a select chain.
 
-
-def block_quantize_rows(x: jnp.ndarray, block: int, mant_bits: int):
-    """Quantize (rows, d) along d in blocks; returns (mantissa f32, exp i32)."""
-    r, d = x.shape
-    xb = x.reshape(r, d // block, block)
-    amax = jnp.max(jnp.abs(xb), axis=-1)
-    _, k = jnp.frexp(jnp.maximum(amax, jnp.finfo(jnp.float32).tiny))
-    e = jnp.where(amax > 0, k - 1 - (mant_bits - 2), 0)
-    e = jnp.clip(e, -127, 127)
-    lim = float(2 ** (mant_bits - 1) - 1)
-    m = jnp.clip(jnp.round(xb * jnp.exp2(-e.astype(jnp.float32))[..., None]),
-                 -lim, lim)
-    return m, e.astype(jnp.int32)                      # (r, nb, blk), (r, nb)
-
-
-def requantize_rows(m: jnp.ndarray, e: jnp.ndarray):
-    """Align all blocks of each row to the row-max exponent (Eq. 3)."""
-    e_max = jnp.max(e, axis=-1, keepdims=True)
-    shift = jnp.minimum(e_max - e, 31)
-    # arithmetic right shift on integer-valued f32 mantissas: floor of the
-    # exact power-of-two scale matches >> for the int32 the hardware holds
-    # (incl. negatives, floor -> -inf), and unlike `1 << shift` it cannot
-    # overflow at the shift=31 saturation point (hit when masked -inf
-    # scores share a row with real scores).
-    mi = jnp.floor(m * jnp.exp2(-shift.astype(jnp.float32))[..., None])
-    return mi, e_max
-
-
-def requantize_to_grid(y: jnp.ndarray, block: int, mant_bits: int):
-    """Snap a (rows, d) tile onto the MXInt act grid (quantize-dequantize).
-
-    The shared epilogue of the LayerNorm and softmax kernels: the 'sim'
-    datapath quantizes each op's output back to act_fmt before the next op
-    consumes it.
+    Exact, and it lowers on Mosaic, unlike a gather; a one-hot MXU
+    contraction would round the f32 entries to bf16 at default precision.
     """
-    m, e = block_quantize_rows(y, block, mant_bits)
-    return (m * jnp.exp2(e.astype(jnp.float32))[..., None]).reshape(y.shape)
+    y = jnp.full(idx.shape, table[0], jnp.float32)
+    for i, v in enumerate(table[1:], 1):
+        y = jnp.where(idx == i, jnp.float32(v), y)
+    return y
 
 
-def _rsqrt_lut_stage(var: jnp.ndarray, table: jnp.ndarray, bits: int):
+def _rsqrt_lut_stage(var: jnp.ndarray, table: tuple, bits: int):
     var = jnp.maximum(var, 2.0 ** -24)
     v_m, v_e = jnp.frexp(var)
     v_m, v_e = v_m * 2.0, v_e - 1
@@ -90,23 +53,22 @@ def _rsqrt_lut_stage(var: jnp.ndarray, table: jnp.ndarray, bits: int):
     return r * jnp.exp2(-e_half.astype(jnp.float32))
 
 
-def _mxint_layernorm_kernel(x_ref, g_ref, b_ref, lut_ref, o_ref, *,
-                            act_block: int, mant_bits: int, lut_bits: int,
+def _mxint_layernorm_kernel(x_ref, g_ref, b_ref, o_ref, *, act_block: int,
+                            mant_bits: int, lut: tuple, lut_bits: int,
                             rms_only: bool, quantize_out: bool):
     x = x_ref[...].astype(jnp.float32)                 # (br, d)
-    m, e = block_quantize_rows(x, act_block, mant_bits)
+    m, e = block_quantize(x, act_block, mant_bits)
     mf, _ = requantize_rows(m, e)                      # lambda cancels
-    mf = mf.reshape(x.shape)
     if rms_only:
         centered = mf
     else:
         centered = mf - jnp.mean(mf, axis=-1, keepdims=True)
     var = jnp.mean(centered * centered, axis=-1, keepdims=True)
-    inv = _rsqrt_lut_stage(var, lut_ref[...], lut_bits)
+    inv = _rsqrt_lut_stage(var, lut, lut_bits)
     y = centered * inv
-    y = y * g_ref[...][None, :]
+    y = y * g_ref[...]
     if not rms_only:
-        y = y + b_ref[...][None, :]
+        y = y + b_ref[...]
     if quantize_out:
         y = requantize_to_grid(y, act_block, mant_bits)
     o_ref[...] = y.astype(o_ref.dtype)
@@ -126,26 +88,25 @@ def mxint_layernorm(x: jnp.ndarray, gamma: jnp.ndarray, beta: jnp.ndarray, *,
     assert rows % br == 0, (rows, br)
     assert d % min(act_block, d) == 0
     act_block = min(act_block, d)
-    lut = luts.rsqrt_lut(lut_bits)
 
     kernel = functools.partial(
         _mxint_layernorm_kernel, act_block=act_block, mant_bits=mant_bits,
-        lut_bits=lut_bits, rms_only=rms_only, quantize_out=quantize_out)
+        lut=luts.rsqrt_table(lut_bits), lut_bits=lut_bits,
+        rms_only=rms_only, quantize_out=quantize_out)
 
     return pl.pallas_call(
         kernel,
         grid=(rows // br,),
         in_specs=[
             pl.BlockSpec((br, d), lambda i: (i, 0)),
-            pl.BlockSpec((d,), lambda i: (0,)),
-            pl.BlockSpec((d,), lambda i: (0,)),
-            pl.BlockSpec((lut.shape[0],), lambda i: (0,)),
+            pl.BlockSpec((1, d), lambda i: (0, 0)),
+            pl.BlockSpec((1, d), lambda i: (0, 0)),
         ],
         out_specs=pl.BlockSpec((br, d), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((rows, d), x.dtype),
         # Row blocks touch disjoint state: the whole grid is
         # parallel (DESIGN.md §14).
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
-    )(x, gamma, beta, lut)
+    )(x, gamma.reshape(1, d), beta.reshape(1, d))

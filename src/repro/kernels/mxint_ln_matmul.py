@@ -36,51 +36,43 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import luts
-from repro.kernels.mxint_layernorm import (_rsqrt_lut_stage,
-                                           block_quantize_rows,
-                                           requantize_rows,
-                                           requantize_to_grid)
-from repro.kernels.mxint_matmul import (_broadcast_block_exp,
-                                        _quantize_act_tile)
+from repro.kernels.block_quant import (block_quantize, requantize_rows,
+                                       requantize_to_grid)
+from repro.kernels.mxint_layernorm import _rsqrt_lut_stage
+from repro.kernels.mxint_matmul import mxint_tile_product
 
 
-def _mxint_ln_matmul_kernel(x_ref, g_ref, b_ref, lut_ref, wm_ref, we_ref,
-                            o_ref, y_ref, *, act_block: int, mant_bits: int,
-                            lut_bits: int, rms_only: bool, w_block: int):
+def _mxint_ln_matmul_kernel(x_ref, g_ref, b_ref, wm_ref, we_ref, o_ref,
+                            y_ref, *, act_block: int, mant_bits: int,
+                            lut: tuple, lut_bits: int, rms_only: bool,
+                            w_block: int):
     """One (bm, bn) output tile; the LN stage runs only at j == 0 and its
     result stays resident in the ``y_ref`` VMEM scratch for every j."""
 
     @pl.when(pl.program_id(1) == 0)
     def _ln():
         x = x_ref[...].astype(jnp.float32)             # (bm, d)
-        m, e = block_quantize_rows(x, act_block, mant_bits)
+        m, e = block_quantize(x, act_block, mant_bits)
         mf, _ = requantize_rows(m, e)                  # lambda cancels
-        mf = mf.reshape(x.shape)
         if rms_only:
             centered = mf
         else:
             centered = mf - jnp.mean(mf, axis=-1, keepdims=True)
         var = jnp.mean(centered * centered, axis=-1, keepdims=True)
-        inv = _rsqrt_lut_stage(var, lut_ref[...], lut_bits)
+        inv = _rsqrt_lut_stage(var, lut, lut_bits)
         y = centered * inv
-        y = y * g_ref[...][None, :]
+        y = y * g_ref[...]
         if not rms_only:
-            y = y + b_ref[...][None, :]
+            y = y + b_ref[...]
         y = requantize_to_grid(y, act_block, mant_bits)
         y_ref[...] = y.astype(y_ref.dtype)
 
-    # matmul stage — identical to _mxint_matmul_kernel's quantize_act path
-    # with a single K tile (bk == d)
-    y = y_ref[...].astype(jnp.float32)                 # (bm, d)
-    wm = wm_ref[...].astype(jnp.float32)               # (d, bn) ints
-    w_scale = _broadcast_block_exp(we_ref[...], w_block)
-    xm, x_scale = _quantize_act_tile(y, act_block, mant_bits)
-    bm_, bk_ = xm.shape
-    nb = bk_ // act_block
-    xg = (xm.reshape(bm_, nb, act_block) * x_scale[:, :, None])
-    o_ref[...] = jax.lax.dot_general(
-        xg.reshape(bm_, bk_), wm * w_scale, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32).astype(o_ref.dtype)
+    # matmul stage — the same tile product as mxint_matmul with
+    # quantize_act=True (a single K tile, bk == d)
+    o_ref[...] = mxint_tile_product(
+        y_ref[...], wm_ref[...], we_ref[...], w_block=w_block,
+        act_block=act_block, act_mant_bits=mant_bits,
+        quantize_act=True).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=(
@@ -111,21 +103,20 @@ def mxint_ln_matmul(x: jnp.ndarray, gamma: jnp.ndarray, beta: jnp.ndarray,
     assert rows % bm == 0 and N % bn == 0, (rows, N, bm, bn)
     assert d % min(act_block, d) == 0
     act_block = min(act_block, d)
-    lut = luts.rsqrt_lut(lut_bits)
     beta_arr = beta if beta is not None else jnp.zeros_like(gamma)
 
     kernel = functools.partial(
         _mxint_ln_matmul_kernel, act_block=act_block, mant_bits=mant_bits,
-        lut_bits=lut_bits, rms_only=rms_only, w_block=w_block)
+        lut=luts.rsqrt_table(lut_bits), lut_bits=lut_bits,
+        rms_only=rms_only, w_block=w_block)
 
     return pl.pallas_call(
         kernel,
         grid=(rows // bm, N // bn),
         in_specs=[
             pl.BlockSpec((bm, d), lambda i, j: (i, 0)),
-            pl.BlockSpec((d,), lambda i, j: (0,)),
-            pl.BlockSpec((d,), lambda i, j: (0,)),
-            pl.BlockSpec((lut.shape[0],), lambda i, j: (0,)),
+            pl.BlockSpec((1, d), lambda i, j: (0, 0)),
+            pl.BlockSpec((1, d), lambda i, j: (0, 0)),
             pl.BlockSpec((d, bn), lambda i, j: (0, j)),
             pl.BlockSpec((d // w_block, bn), lambda i, j: (0, j)),
         ],
@@ -135,7 +126,7 @@ def mxint_ln_matmul(x: jnp.ndarray, gamma: jnp.ndarray, beta: jnp.ndarray,
         # Row blocks are independent; the N axis reuses the normalised
         # tile cached in scratch at j == 0, so it must run in order
         # (DESIGN.md §14).
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(x, gamma, beta_arr, lut, w_mant, w_exp)
+    )(x, gamma.reshape(1, d), beta_arr.reshape(1, d), w_mant, w_exp)

@@ -7,18 +7,17 @@ reading of that datapath:
   * weight mantissas live in HBM as int8 planes; the shared exponents are a
     (K/B, N) int8 plane — HBM->VMEM traffic is the *quantized* bytes, which
     is the paper's memory win, preserved;
-  * inside the kernel each (bk, bn) mantissa tile is scaled by
+  * inside the kernel each (K, bn) mantissa tile is scaled by
     2^exponent once per block — the "one dynamic shift per block", expressed
     as a broadcasted `exp2` multiply feeding the MXU;
-  * optionally the activation tile is block-quantized in-register and the
-    product runs as int8 x int8 -> int32 on the MXU (2x peak vs bf16), with
-    the combined scale 2^(e_x + e_w) applied on the int32 tile — the full
-    integer-only datapath of Fig. 2b;
-  * accumulation is a f32 VMEM scratch across the K grid dimension
-    (TPU gives a lossless >=int32 accumulator for free; the paper's 12-bit
-    accumulator DSE is subsumed — DESIGN.md §2).
+  * optionally the activation tile is block-quantized in-register, and
+    the mantissas scaled by their block exponents feed the MXU — the
+    integer datapath of Fig. 2b, exact in f32 (the operands are <=8-bit
+    mantissas times powers of two);
+  * the full K is contracted in one tile with an f32 result (the paper's
+    12-bit accumulator DSE is subsumed — DESIGN.md §2).
 
-Grid: (M/bm, N/bn, K/bk), K innermost so the accumulator stays resident.
+Grid: (M/bm, N/bn).
 """
 from __future__ import annotations
 
@@ -29,96 +28,61 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.block_quant import block_quantize
+
 
 def _broadcast_block_exp(e_tile: jnp.ndarray, block: int) -> jnp.ndarray:
-    """(kb, bn) int8 exponents -> (kb*block, bn) f32 scales, 2^e."""
+    """(kb, bn) int8 exponents -> (kb*block, bn) f32 scales, 2^e.
+
+    Expands along the sublane axis (a leading-dim merge), which Mosaic
+    lowers when ``block`` is a multiple of the 8-row sublane tile.
+    """
     kb, bn = e_tile.shape
     s = jnp.exp2(e_tile.astype(jnp.float32))
     s = jnp.broadcast_to(s[:, None, :], (kb, block, bn))
     return s.reshape(kb * block, bn)
 
 
-def _quantize_act_tile(x: jnp.ndarray, block: int, mant_bits: int):
-    """In-register block quantization of an activation tile along K.
+def mxint_tile_product(x: jnp.ndarray, wm: jnp.ndarray, we: jnp.ndarray, *,
+                       w_block: int, act_block: int, act_mant_bits: int,
+                       quantize_act: bool) -> jnp.ndarray:
+    """(bm, K) activations x packed (K, bn) planes -> (bm, bn) f32.
 
-    Returns (int mantissa tile as f32-exact ints, per-block scale 2^e with
-    shape (bm, bk/block)).  Mirrors repro.core.quantize numerics exactly.
+    The whole contraction is one tile, so the accumulation order matches
+    the XLA einsum of the 'sim' oracle.  With ``quantize_act`` the
+    activation tile is block-quantized in-register and its mantissas,
+    scaled by their block exponent, feed the contraction: the integer
+    datapath of Fig. 2b, exact in f32 for <=11-bit mantissa products.
     """
-    bm, bk = x.shape
-    xb = x.reshape(bm, bk // block, block)
-    amax = jnp.max(jnp.abs(xb), axis=-1)                      # (bm, kb)
-    _, k = jnp.frexp(jnp.maximum(amax, jnp.finfo(jnp.float32).tiny))
-    e = k - 1 - (mant_bits - 2)
-    e = jnp.where(amax > 0, e, 0)
-    e = jnp.clip(e, -127, 127)
-    scale = jnp.exp2(-e.astype(jnp.float32))
-    lim = float(2 ** (mant_bits - 1) - 1)
-    m = jnp.clip(jnp.round(xb * scale[..., None]), -lim, lim)
-    return m.reshape(bm, bk), jnp.exp2(e.astype(jnp.float32))
-
-
-def _mxint_matmul_kernel(x_ref, wm_ref, we_ref, o_ref, acc_ref, *,
-                         w_block: int, act_block: int, act_mant_bits: int,
-                         quantize_act: bool, n_k: int, n_exp_sub: int = 1):
-    """One (bm, bn) output tile; K accumulated across grid dim 2."""
-
-    @pl.when(pl.program_id(2) == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    x = x_ref[...].astype(jnp.float32)                        # (bm, bk)
-    wm = wm_ref[...].astype(jnp.float32)                      # (bk, bn) ints
-    e = we_ref[...]                                           # int8 exponents
-    if n_exp_sub > 1:
-        # The exponent block spans n_exp_sub K-steps (native-sublane
-        # fetch); slice this step's (bk/w_block) rows out of it.
-        kb_rows = e.shape[0] // n_exp_sub
-        sub = jax.lax.rem(pl.program_id(2), n_exp_sub)
-        e = jax.lax.dynamic_slice_in_dim(e, sub * kb_rows, kb_rows, axis=0)
-    w_scale = _broadcast_block_exp(e, w_block)                # (bk, bn)
-
+    x = x.astype(jnp.float32)
     if quantize_act:
-        # Full integer datapath: int mantissas into the MXU, one combined
-        # scale per (act-block x weight-block) pair.
-        xm, x_scale = _quantize_act_tile(x, act_block, act_mant_bits)
-        # Fold the per-(row x K-block) activation scale into the mantissas,
-        # then one MXU contraction per tile.  On real TPU hardware this is
-        # the int8 x int8 -> int32 MXU path with the combined 2^(e_x + e_w)
-        # applied to the int32 tile; the f32 emulation here is exact for
-        # <=11-bit mantissa products.
-        bm_, bk_ = xm.shape
-        nb = bk_ // act_block
-        xg = (xm.reshape(bm_, nb, act_block) * x_scale[:, :, None])
-        acc_ref[...] += jax.lax.dot_general(
-            xg.reshape(bm_, bk_), wm * w_scale, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-    else:
-        w = wm * w_scale                                      # dequant once/blk
-        acc_ref[...] += jax.lax.dot_general(
-            x, w, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+        xm, xe = block_quantize(x, act_block, act_mant_bits)
+        x = xm * jnp.exp2(xe.astype(jnp.float32))
+    w = wm.astype(jnp.float32) * _broadcast_block_exp(we, w_block)
+    return jax.lax.dot_general(x, w, (((1,), (0,)), ((), ())),
+                               preferred_element_type=jnp.float32)
 
-    @pl.when(pl.program_id(2) == n_k - 1)
-    def _flush():
-        o_ref[...] = acc_ref[...].astype(o_ref.dtype)
+
+def _mxint_matmul_kernel(x_ref, wm_ref, we_ref, o_ref, **kw):
+    """One (bm, bn) output tile over the full K."""
+    o_ref[...] = mxint_tile_product(x_ref[...], wm_ref[...], we_ref[...],
+                                    **kw).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=(
     "w_block", "act_block", "act_mant_bits", "quantize_act",
-    "bm", "bn", "bk", "exp_block_rows", "interpret", "out_dtype"))
+    "bm", "bn", "interpret", "out_dtype"))
 def mxint_matmul(x: jnp.ndarray, w_mant: jnp.ndarray, w_exp: jnp.ndarray, *,
                  w_block: int, act_block: int = 16, act_mant_bits: int = 8,
                  quantize_act: bool = False, bm: int = 128, bn: int = 128,
-                 bk: int = 512, exp_block_rows: int | None = None,
                  interpret: bool = True,
                  out_dtype=jnp.float32) -> jnp.ndarray:
     """y[M,N] = x[M,K] @ (w_mant * 2^w_exp)[K,N] with MXInt weights.
 
     w_mant: (K, N) int8 mantissas; w_exp: (K/w_block, N) int8 exponents.
-    exp_block_rows widens the exponent-plane fetch to that many rows per
-    block (32 matches the int8 native sublane tile, so Mosaic needs no
-    relayout on real hardware); the kernel slices the current K-step's
-    rows out of the wider resident block.
+    Grid (M/bm, N/bn); each step contracts the full K, so the exponent
+    plane's block spans its whole first dim, which the (8,128) block rule
+    accepts for any K.
     """
     M, K = x.shape
     K2, N = w_mant.shape
@@ -127,54 +91,27 @@ def mxint_matmul(x: jnp.ndarray, w_mant: jnp.ndarray, w_exp: jnp.ndarray, *,
 
     bm = min(bm, M)
     bn = min(bn, N)
-    bk = min(bk, K)
-    assert M % bm == 0 and N % bn == 0 and K % bk == 0, (M, N, K, bm, bn, bk)
-    assert bk % w_block == 0 or w_block % bk == 0
+    assert M % bm == 0 and N % bn == 0, (M, N, bm, bn)
     if quantize_act:
-        assert bk % act_block == 0
-    n_k = K // bk
-
-    n_exp_sub = 1
-    if bk >= w_block:
-        kb = bk // w_block
-        if exp_block_rows is not None and exp_block_rows > kb:
-            # Native-tile exponent fetch (ROADMAP "int8 exponent-plane
-            # tiling"): one (exp_block_rows, bn) block covers
-            # exp_block_rows/kb consecutive K-steps.
-            assert exp_block_rows % kb == 0, (exp_block_rows, kb)
-            assert (K // w_block) % exp_block_rows == 0, \
-                (K, w_block, exp_block_rows)
-            n_exp_sub = exp_block_rows // kb
-            we_spec = pl.BlockSpec((exp_block_rows, bn),
-                                   lambda i, j, k: (k // n_exp_sub, j))
-        else:
-            we_spec = pl.BlockSpec((kb, bn), lambda i, j, k: (k, j))
-        eff_w_block = w_block
-    else:
-        # several K tiles share one exponent row
-        ratio = w_block // bk
-        we_spec = pl.BlockSpec((1, bn), lambda i, j, k: (k // ratio, j))
-        eff_w_block = bk
+        assert K % act_block == 0, (K, act_block)
 
     kernel = functools.partial(
-        _mxint_matmul_kernel, w_block=eff_w_block, act_block=act_block,
-        act_mant_bits=act_mant_bits, quantize_act=quantize_act, n_k=n_k,
-        n_exp_sub=n_exp_sub)
+        _mxint_matmul_kernel, w_block=w_block, act_block=act_block,
+        act_mant_bits=act_mant_bits, quantize_act=quantize_act)
 
     return pl.pallas_call(
         kernel,
-        grid=(M // bm, N // bn, n_k),
+        grid=(M // bm, N // bn),
         in_specs=[
-            pl.BlockSpec((bm, bk), lambda i, j, k: (i, k)),
-            pl.BlockSpec((bk, bn), lambda i, j, k: (k, j)),
-            we_spec,
+            pl.BlockSpec((bm, K), lambda i, j: (i, 0)),
+            pl.BlockSpec((K, bn), lambda i, j: (0, j)),
+            pl.BlockSpec((K // w_block, bn), lambda i, j: (0, j)),
         ],
-        out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
+        out_specs=pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((M, N), out_dtype),
-        scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        # M/N tiles are independent; K revisits the acc scratch and the
-        # output block, so it must stay sequential (DESIGN.md §14).
-        compiler_params=pltpu.TPUCompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        # every (bm, bn) output tile is computed once from its own inputs
+        # (DESIGN.md §14).
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel")),
         interpret=interpret,
     )(x, w_mant, w_exp)

@@ -23,14 +23,14 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import luts
-from repro.kernels.mxint_layernorm import (block_quantize_rows, lut_lookup,
-                                           requantize_rows,
-                                           requantize_to_grid)
+from repro.kernels.block_quant import (block_quantize, requantize_rows,
+                                       requantize_to_grid)
+from repro.kernels.mxint_layernorm import lut_lookup
 
 _LOG2E = 1.4426950408889634
 
 
-def exp2_datapath(z: jnp.ndarray, table: jnp.ndarray, r_bits: int):
+def exp2_datapath(z: jnp.ndarray, table: tuple, r_bits: int):
     """2^z for z <= 0 via 2^n * LUT_pow2(r)."""
     n = jnp.floor(z)
     r = z - n
@@ -40,15 +40,14 @@ def exp2_datapath(z: jnp.ndarray, table: jnp.ndarray, r_bits: int):
     return p_m * jnp.exp2(jnp.maximum(n, -126.0))
 
 
-def _mxint_softmax_kernel(x_ref, lut_ref, o_ref, *, act_block: int,
-                          mant_bits: int, r_bits: int, quantize_out: bool):
+def _mxint_softmax_kernel(x_ref, o_ref, *, act_block: int, mant_bits: int,
+                          lut: tuple, r_bits: int, quantize_out: bool):
     x = x_ref[...].astype(jnp.float32)                  # (br, n)
-    m, e = block_quantize_rows(x, act_block, mant_bits)
+    m, e = block_quantize(x, act_block, mant_bits)
     mf, lam = requantize_rows(m, e)
-    mf = mf.reshape(x.shape)
     t = mf - jnp.max(mf, axis=-1, keepdims=True)        # <= 0, mantissa units
     z = t * jnp.exp2(lam.astype(jnp.float32)) * _LOG2E
-    p = exp2_datapath(z, lut_ref[...], r_bits)
+    p = exp2_datapath(z, lut, r_bits)
     s = jnp.sum(p, axis=-1, keepdims=True)
     s_m, s_e = jnp.frexp(s)                             # LZC + shift in HW
     y = (p / s_m) * jnp.exp2(-s_e.astype(jnp.float32))
@@ -72,23 +71,20 @@ def mxint_softmax(x: jnp.ndarray, *, act_block: int = 16, mant_bits: int = 8,
     assert rows % br == 0
     act_block = min(act_block, n)
     assert n % act_block == 0, (n, act_block)
-    lut = luts.pow2_lut(r_bits)
 
     kernel = functools.partial(_mxint_softmax_kernel, act_block=act_block,
-                               mant_bits=mant_bits, r_bits=r_bits,
+                               mant_bits=mant_bits,
+                               lut=luts.pow2_table(r_bits), r_bits=r_bits,
                                quantize_out=quantize_out)
     return pl.pallas_call(
         kernel,
         grid=(rows // br,),
-        in_specs=[
-            pl.BlockSpec((br, n), lambda i: (i, 0)),
-            pl.BlockSpec((lut.shape[0],), lambda i: (0,)),
-        ],
+        in_specs=[pl.BlockSpec((br, n), lambda i: (i, 0))],
         out_specs=pl.BlockSpec((br, n), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((rows, n), x.dtype),
         # Row blocks touch disjoint state: the whole grid is
         # parallel (DESIGN.md §14).
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
-    )(x, lut)
+    )(x)

@@ -1,9 +1,12 @@
 """Public jit'd wrappers around the Pallas kernels.
 
-These handle shape plumbing (leading-dim flattening, row padding to tile
-multiples), backend selection (Pallas compiled on TPU, interpret=True on
-CPU, pure-XLA fallback for odd shapes) and expose the kernels under the
-names the model zoo consumes.
+These handle shape plumbing (leading-dim flattening, row padding to the
+sublane multiple, output columns to the lane multiple), backend selection
+(Pallas compiled on TPU, interpret=True on CPU — the same wrapper code
+either way) and expose the kernels under the names the model zoo
+consumes.  Every linear and norm shape runs in its kernel; the only XLA
+fallback left is attention's pathological head dim, counted in
+``FALLBACKS``.
 
 This module is the execution layer behind the ``pallas_kernel`` datapath
 backend (``QuantConfig(mode='kernel')`` — DESIGN.md §12):
@@ -24,14 +27,12 @@ import jax.numpy as jnp
 
 from repro.core.quantize import _resolve_block
 from repro.kernels import ref
-from repro.kernels.flash_attention import (NEG_INF, flash_attention,
+from repro.kernels.flash_attention import (flash_attention,
                                            flash_attention_decode)
 from repro.kernels.mxint_gelu import mxint_gelu as _gelu_kernel
 from repro.kernels.mxint_layernorm import mxint_layernorm as _ln_kernel
 from repro.kernels.mxint_matmul import mxint_matmul as _mm_kernel
 from repro.kernels.mxint_softmax import mxint_softmax as _sm_kernel
-
-_NEG_INF = NEG_INF     # unified sentinel (defined in core/mx_types.py)
 
 # ---------------------------------------------------------------------------
 # flash-attention fallback accounting.  The shape gate is STATIC (python
@@ -157,17 +158,10 @@ def _pick_block_rows(rows: int, cap: int = 256) -> int:
     return 1
 
 
-def _pick_exp_block_rows(K: int, w_block: int, bk: int) -> int | None:
-    """Widen the exponent-plane fetch to the native int8 (32, 128) tile
-    when the plane shape allows it (ROADMAP "int8 exponent-plane
-    tiling"); None keeps the per-K-step (bk/w_block, bn) fetch."""
-    if bk < w_block:
-        return None
-    kb = bk // w_block
-    native = 32                   # int8 sublane rows
-    if kb >= native or native % kb or (K // w_block) % native:
-        return None
-    return native
+def _pad_planes(w_mant: jnp.ndarray, w_exp: jnp.ndarray):
+    """Pad packed (K, N) / (K/B, N) planes to a lane multiple of N."""
+    n_p = _ceil_to(w_mant.shape[1], 128)
+    return _pad_dim(w_mant, 1, n_p), _pad_dim(w_exp, 1, n_p)
 
 
 # ---------------------------------------------------------------------------
@@ -207,13 +201,12 @@ def mxint_linear(x: jnp.ndarray, w_mant: jnp.ndarray, w_exp: jnp.ndarray,
         numerically close but NOT bit-exact (DESIGN.md §10).
 
     The packed planes go into the Pallas kernel untouched — HBM traffic is
-    the quantized bytes (the paper's memory win).  In interpret mode
-    (CPU/CI) rows are padded to the sublane multiple and output columns to
-    the lane multiple so ANY model shape runs through the kernel; the K
-    contraction stays a single tile, which keeps the accumulation order
-    identical to the XLA einsum of the 'sim' oracle (bit-exact parity).
-    On TPU the MXU-aligned multi-tile path is used, falling back to the
-    jnp oracle for shapes the compiled kernel cannot tile.
+    the quantized bytes (the paper's memory win).  Rows are padded to the
+    sublane multiple and output columns to the lane multiple so ANY model
+    shape runs through the kernel, compiled or interpreted alike; the K
+    contraction is a single tile, which keeps the accumulation order
+    identical to the XLA einsum of the 'sim' oracle (bit-exact parity on
+    the CPU).
     """
     x2, lead = _flatten_rows(x)
     if tp_axis is not None and tp_mode == "psum":
@@ -222,36 +215,15 @@ def mxint_linear(x: jnp.ndarray, w_mant: jnp.ndarray, w_exp: jnp.ndarray,
         k_local = w_mant.shape[0]
         x2 = jax.lax.dynamic_slice_in_dim(
             x2, jax.lax.axis_index(tp_axis) * k_local, k_local, axis=1)
-    M, K = x2.shape
+    K = x2.shape[1]
     N = w_mant.shape[1]
-    act_block = _resolve_block(K, act_block)
-    interp = _interpret()
-    if interp:
-        x2p, rows = _pad_rows(x2, 8)
-        npad = (-N) % 128
-        wm, we = w_mant, w_exp
-        if npad:
-            wm = jnp.pad(wm, ((0, 0), (0, npad)))
-            we = jnp.pad(we, ((0, 0), (0, npad)))
-        y = _mm_kernel(x2p, wm, we, w_block=w_block,
-                       act_block=act_block, act_mant_bits=act_mant_bits,
-                       quantize_act=quantize_act,
-                       bm=_pick_block_rows(x2p.shape[0], 128),
-                       bn=128, bk=K, interpret=interp)[:rows, :N]
-    elif M % 8 == 0 and K % 128 == 0 and N % 128 == 0:
-        bm = _pick_block_rows(M, 128)
-        bk = 512 if K % 512 == 0 else 128
-        bn = 128
-        y = _mm_kernel(x2, w_mant, w_exp, w_block=w_block,
-                       act_block=act_block, act_mant_bits=act_mant_bits,
-                       quantize_act=quantize_act, bm=bm, bn=bn, bk=bk,
-                       exp_block_rows=_pick_exp_block_rows(K, w_block, bk),
-                       interpret=False)
-    else:
-        y = ref.mxint_matmul_ref(x2, w_mant, w_exp, w_block=w_block,
-                                 act_block=act_block,
-                                 act_mant_bits=act_mant_bits,
-                                 quantize_act=quantize_act)
+    x2p, rows = _pad_rows(x2, 8)
+    wm, we = _pad_planes(w_mant, w_exp)
+    y = _mm_kernel(x2p, wm, we, w_block=w_block,
+                   act_block=_resolve_block(K, act_block),
+                   act_mant_bits=act_mant_bits, quantize_act=quantize_act,
+                   bm=_pick_block_rows(x2p.shape[0], 128), bn=128,
+                   interpret=_interpret())[:rows, :N]
     if tp_axis is not None:
         if tp_mode == "gather":
             y = jax.lax.all_gather(y, tp_axis, axis=1, tiled=True)
@@ -316,56 +288,29 @@ def mxint_ln_linear_op(x: jnp.ndarray, gamma: jnp.ndarray,
     unfused path's dtype round-trip is reproduced.  Only the 'gather'
     tensor-parallel mode composes (the collective moves output columns —
     pure data movement after the fused kernel); 'psum' shards the
-    contraction axis, which the full-row LN never sees, so callers fall
-    back to the two-op sequence (``repro.datapath.pallas_kernel``).
-    Shapes the kernel cannot tile fall back to that same unfused pair —
-    numerically identical by the same argument.
+    contraction axis, which the full-row LN never sees, so callers run
+    the two-op sequence instead (``repro.datapath.pallas_kernel``).
     """
     from repro.kernels.mxint_ln_matmul import mxint_ln_matmul
 
     if tp_mode not in (None, "gather") or \
             (tp_axis is not None and tp_mode is None):
         # mirror mxint_linear: a sharded call with anything but 'gather'
-        # fails loudly (the fused kernel and its unfused fallback must
-        # never diverge on the same arguments)
+        # fails loudly
         raise ValueError(f"fused ln_linear shards only with "
                          f"tp_mode='gather', got tp_axis={tp_axis!r} "
                          f"tp_mode={tp_mode!r}")
     x2, lead = _flatten_rows(x)
-    M, K = x2.shape
+    K = x2.shape[1]
     N = w_mant.shape[1]
-    act_block = _resolve_block(K, act_block)
-    interp = _interpret()
-    if interp:
-        x2p, rows = _pad_rows(x2, 8)
-        npad = (-N) % 128
-        wm, we = w_mant, w_exp
-        if npad:
-            wm = jnp.pad(wm, ((0, 0), (0, npad)))
-            we = jnp.pad(we, ((0, 0), (0, npad)))
-        y = mxint_ln_matmul(x2p, gamma, beta, wm, we, w_block=w_block,
-                            act_block=act_block, mant_bits=mant_bits,
-                            lut_bits=lut_bits, rms_only=rms_only,
-                            bm=_pick_block_rows(x2p.shape[0], 128), bn=128,
-                            interpret=interp)[:rows, :N]
-    elif M % 8 == 0 and K % 128 == 0 and N % 128 == 0:
-        y = mxint_ln_matmul(x2, gamma, beta, w_mant, w_exp, w_block=w_block,
-                            act_block=act_block, mant_bits=mant_bits,
-                            lut_bits=lut_bits, rms_only=rms_only,
-                            bm=_pick_block_rows(M, 128), bn=128,
-                            interpret=False)
-    else:
-        # untileable on compiled TPU: unfused two-kernel sequence (the
-        # numerics the fused kernel replicates, so this is not a fallback
-        # in the FALLBACKS sense — same datapath, one extra HBM trip)
-        h = mxint_layernorm_op(
-            x2.astype(jnp.float32), gamma, beta, act_block=act_block,
-            mant_bits=mant_bits, lut_bits=lut_bits, rms_only=rms_only,
-            quantize_out=True).astype(x.dtype)
-        return mxint_linear(h, w_mant, w_exp, bias, w_block=w_block,
-                            quantize_act=True, act_block=act_block,
-                            act_mant_bits=mant_bits, tp_axis=tp_axis,
-                            tp_mode=tp_mode).reshape(*lead, -1)
+    x2p, rows = _pad_rows(x2, 8)
+    wm, we = _pad_planes(w_mant, w_exp)
+    y = mxint_ln_matmul(x2p, gamma, beta, wm, we, w_block=w_block,
+                        act_block=_resolve_block(K, act_block),
+                        mant_bits=mant_bits, lut_bits=lut_bits,
+                        rms_only=rms_only,
+                        bm=_pick_block_rows(x2p.shape[0], 128), bn=128,
+                        interpret=_interpret())[:rows, :N]
     if tp_axis is not None and tp_mode == "gather":
         y = jax.lax.all_gather(y, tp_axis, axis=1, tiled=True)
         N = y.shape[1]
@@ -413,66 +358,21 @@ def mxint_gelu_op(x: jnp.ndarray, *, fn: str = "gelu", act_block: int = 16,
     return y[:rows].reshape(x.shape)
 
 
-def _paper_softmax_attention(qf, kf, vf, *, causal: bool, window: int,
-                             scale: float, act_block: int, mant_bits: int,
-                             r_bits: int, groups: int = 1) -> jnp.ndarray:
-    """Whole-row attention with the Pallas MXInt softmax kernel.
-
-    The paper's FPGA design streams entire score rows through the softmax
-    datapath (no online rescale), which is also what the 'sim' oracle
-    emulates — so this path is the bit-exact kernel reading of the ViT
-    attention: score matmul on the MXU, Eq. 14-20 softmax in the Pallas
-    kernel (including the final quantize of the probabilities), p @ V on
-    the MXU.
-
-    GQA: ``groups`` query heads share each KV head.  qf packs them as
-    (b*kv_heads, groups*sq, d) — group-major rows — so K/V are contracted
-    once per KV head with NO per-query-head broadcast copy; the query
-    position of row i is ``i % sq``.
-    """
-    bh, gsq, d = qf.shape
-    sq = gsq // groups
-    sk = kf.shape[1]
-    s = jnp.einsum("bqd,bkd->bqk", qf.astype(jnp.float32),
-                   kf.astype(jnp.float32)) * scale
-    q_pos = (jnp.arange(gsq) % sq)[:, None]
-    k_pos = jnp.arange(sk)[None, :]
-    mask = jnp.ones((gsq, sk), dtype=bool)
-    if causal:
-        mask &= q_pos >= k_pos
-    if window > 0:
-        mask &= (q_pos - k_pos) < window
-    masked = bool(causal or window > 0)
-    if masked:
-        s = jnp.where(mask[None], s, _NEG_INF)
-    p = mxint_softmax_op(s, act_block=act_block, mant_bits=mant_bits,
-                         r_bits=r_bits, quantize_out=True)
-    if masked:
-        p = jnp.where(mask[None], p, 0.0)
-    o = jnp.einsum("bqk,bkd->bqd", p, vf.astype(jnp.float32))
-    return o.astype(qf.dtype)
-
-
 def attention_op(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
                  causal: bool = True, window: int = 0,
                  exp_mode: str = "float", r_bits: int = 2,
                  quantize_scores: bool = False,
-                 softmax_variant: str = "online",
                  act_block: int = 16, mant_bits: int = 8) -> jnp.ndarray:
-    """(B, H, S, D) attention through the Pallas kernels.
+    """(B, H, S, D) attention through the blocked flash kernel.
 
-    softmax_variant:
-      'online' — blocked flash kernel (online softmax); ``exp_mode='mxint'``
-                 runs the Eq. 14-19 exp LUT inside the flash kernel, and
-                 ``quantize_scores=True`` adds the Eq. 2-3 score and Eq. 20
-                 probability quantization stages (the full paper datapath,
-                 blocked — DESIGN.md §11).  The long-sequence LM path.
-      'paper'  — whole-row MXInt softmax through the Pallas softmax kernel
-                 (quantized scores AND quantized probabilities, Eq. 14-20
-                 exactly as the FPGA streams rows).  The ViT / encoder path;
-                 bit-identical to the 'sim' oracle.
+    ``exp_mode='mxint'`` runs the Eq. 14-19 exp LUT inside the flash
+    kernel, and ``quantize_scores=True`` adds the Eq. 2-3 score and Eq. 20
+    probability quantization stages (the full paper datapath, blocked —
+    DESIGN.md §11).  The whole-row ViT path does not come here: it runs
+    the model's own contractions around the softmax kernel
+    (``repro.datapath.pallas_kernel``).
 
-    Padding contract ('online' path): ANY shape reaches the flash kernel —
+    Padding contract: ANY shape reaches the flash kernel —
     query rows are padded to the sublane multiple (8), keys and head lanes
     to the lane multiple (128), and the pads are sliced off the result.
     Padded KEYS are masked inside the kernel via the static ``kv_len``
@@ -486,9 +386,8 @@ def attention_op(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
     it is never taken silently.
 
     GQA: k/v may carry fewer heads than q (q heads must be a multiple,
-    laid out KV-major: q[:, i] attends k[:, i // groups]).  Neither path
-    copies K/V per query head: the 'paper' variant folds the group dim
-    into query rows, the flash path maps query head b to KV head
+    laid out KV-major: q[:, i] attends k[:, i // groups]).  K/V are not
+    copied per query head: the flash kernel maps query head b to KV head
     b // groups in its BlockSpec index map (``kv_groups``); only the
     pathological-head-dim oracle fallback broadcasts.
     """
@@ -497,13 +396,6 @@ def attention_op(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
     sk = k.shape[2]
     groups = h // hkv
     scale = d ** -0.5
-    if softmax_variant == "paper":
-        o = _paper_softmax_attention(
-            q.reshape(b * hkv, groups * sq, d),
-            k.reshape(b * hkv, sk, d), v.reshape(b * hkv, sk, d),
-            causal=causal, window=window, scale=scale, act_block=act_block,
-            mant_bits=mant_bits, r_bits=r_bits, groups=groups)
-        return o.reshape(b, h, sq, d)
     qf = q.reshape(b * h, sq, d)
     kf = k.reshape(b * hkv, sk, d)
     vf = v.reshape(b * hkv, sk, d)

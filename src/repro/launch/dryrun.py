@@ -32,7 +32,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from repro import telemetry as T
 from repro.configs import ARCH_IDS, full_config, shape_supported, skip_reason
 from repro.launch import hlo_analysis, specs as S
-from repro.launch.mesh import make_production_mesh, mesh_context
+from repro.launch.mesh import make_production_mesh
 from repro.models import build_model, shape_by_name, ALL_SHAPES
 from repro.models.model_api import axes_tree
 from repro.optim.adamw import AdamWConfig
@@ -123,7 +123,7 @@ def run_cell(arch: str, shape_name: str, mesh, mesh_name: str,
             jitted = jax.jit(step, in_shardings=(st_sh, b_sh),
                              out_shardings=(st_sh, None),
                              donate_argnums=(0,))
-            with mesh_context(mesh):
+            with jax.set_mesh(mesh):
                 lowered = jitted.lower(state, batch)
                 compiled = lowered.compile()
         else:
@@ -144,7 +144,7 @@ def run_cell(arch: str, shape_name: str, mesh, mesh_name: str,
                 jitted = jax.jit(step, in_shardings=(p_sh, b_sh, c_sh),
                                  out_shardings=(None, c_sh),
                                  donate_argnums=(2,))
-                with mesh_context(mesh):
+                with jax.set_mesh(mesh):
                     lowered = jitted.lower(params, batch, cache)
                     compiled = lowered.compile()
             else:
@@ -154,7 +154,7 @@ def run_cell(arch: str, shape_name: str, mesh, mesh_name: str,
                 jitted = jax.jit(step, in_shardings=(p_sh, tok_sh, c_sh),
                                  out_shardings=(tok_sh, c_sh),
                                  donate_argnums=(2,))
-                with mesh_context(mesh):
+                with jax.set_mesh(mesh):
                     lowered = jitted.lower(params, batch["tokens"], cache)
                     compiled = lowered.compile()
 

@@ -1,32 +1,18 @@
 """Production mesh construction.
 
 Importing this module never touches jax device state; meshes are built by
-FUNCTIONS so the dry-run controls XLA_FLAGS before first jax init.
-
-``jax.sharding.AxisType`` only exists from jax 0.5; on older jax the
-explicit-sharding axis types simply don't apply, so the shim below passes
-``axis_types`` only when the running jax supports it.
+FUNCTIONS so the dry-run controls XLA_FLAGS before first jax init.  Every
+axis is ``AxisType.Auto``: GSPMD propagates shardings, and the serving
+path goes manual only inside its own ``jax.shard_map``.
 """
 from __future__ import annotations
 
 import jax
 
 
-def _mesh_kwargs(n_axes: int) -> dict:
-    """axis_types kwarg when this jax has AxisType; empty dict otherwise."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return {}
-    return {"axis_types": (axis_type.Auto,) * n_axes}
-
-
 def _make_mesh(shape, axes):
-    if hasattr(jax, "make_mesh"):
-        return jax.make_mesh(shape, axes, **_mesh_kwargs(len(axes)))
-    # very old jax: build the device mesh by hand
-    from jax.experimental import mesh_utils
-    devices = mesh_utils.create_device_mesh(shape)
-    return jax.sharding.Mesh(devices, axes)
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -52,11 +38,9 @@ def make_tp_mesh(n_shards: int):
 
     Uses the first ``n_shards`` visible devices.  On CPU, force host
     devices first: ``XLA_FLAGS=--xla_force_host_platform_device_count=N``
-    (must be set before jax initializes its backend — see
-    repro.serving.sharded_check for the pattern).
+    (must be set before jax initializes its backend).
     """
-    import jax as _jax
-    n_dev = _jax.device_count()
+    n_dev = jax.device_count()
     if n_dev < n_shards:
         raise ValueError(
             f"make_tp_mesh({n_shards}) needs {n_shards} devices, have "
@@ -71,9 +55,8 @@ def make_serving_mesh(dp: int, tp: int):
     data shards, packed weight planes over ``tp`` model shards
     (DESIGN.md §10).  Needs ``dp * tp`` visible devices (on CPU force
     host devices first — see ``make_tp_mesh``)."""
-    import jax as _jax
     need = dp * tp
-    n_dev = _jax.device_count()
+    n_dev = jax.device_count()
     if n_dev < need:
         raise ValueError(
             f"make_serving_mesh(dp={dp}, tp={tp}) needs {need} devices, "
@@ -81,15 +64,3 @@ def make_serving_mesh(dp: int, tp: int):
             "XLA_FLAGS=--xla_force_host_platform_device_count before the "
             "first jax call")
     return _make_mesh((dp, tp), ("data", "model"))
-
-
-def mesh_context(mesh):
-    """Ambient-mesh context manager across jax versions.
-
-    ``jax.set_mesh`` is the modern entry point; on older jax the Mesh
-    object itself is the (legacy thread-resources) context manager.
-    """
-    set_mesh = getattr(jax, "set_mesh", None)
-    if set_mesh is not None:
-        return set_mesh(mesh)
-    return mesh

@@ -220,46 +220,10 @@ def tp_shard_packed_params(packed_params, n_shards: int,
     return marked, specs
 
 
-def shard_map_compat(f, mesh, in_specs, out_specs):
-    """shard_map across jax versions (mirrors repro.train.step's shim).
-
-    Modern jax exposes ``jax.shard_map`` (VMA-checked); the pinned
-    jax 0.4.37 only has ``jax.experimental.shard_map``.  Both are called
-    with replication checking off: the collectives inserted by
-    ``mxint_linear`` make outputs replicated by construction.
-    """
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_vma=False)
-    from jax.experimental.shard_map import shard_map as _legacy_sm
-    return _legacy_sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_rep=False)
-
-
 def ambient_mesh():
-    """The mesh the current trace runs under, or None.
-
-    Modern jax exposes it as ``jax.sharding.get_abstract_mesh()``; older
-    jax keeps the ``with mesh:`` context in the legacy thread-resources
-    global — check both so shard_hint works across versions.
-    """
-    try:
-        m = jax.sharding.get_abstract_mesh()
-        if m is not None and not m.empty:
-            return m
-    except AttributeError:
-        pass
-    except Exception:
-        return None
-    try:
-        from jax._src.mesh import thread_resources
-        m = thread_resources.env.physical_mesh
-        if m is not None and not m.empty:
-            return m
-    except Exception:
-        pass
-    return None
+    """The mesh the current trace runs under (``jax.set_mesh``), or None."""
+    m = jax.sharding.get_abstract_mesh()
+    return None if m.empty else m
 
 
 def maybe_constraint(x: jnp.ndarray, axes: Tuple[Optional[str], ...]):

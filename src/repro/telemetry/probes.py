@@ -47,7 +47,7 @@ def _probe_matmul_deit() -> Callable[[], object]:
     interp = ops._interpret()
     return lambda: mxint_matmul(
         x, mant, exp, w_block=32, act_block=16, act_mant_bits=8,
-        quantize_act=True, bm=16, bn=128, bk=192, interpret=interp,
+        quantize_act=True, bm=16, bn=128, interpret=interp,
         out_dtype=jnp.float32)
 
 
@@ -83,7 +83,7 @@ def _probe_matmul_bench() -> Callable[[], object]:
     interp = ops._interpret()
     return lambda: mxint_matmul(
         x, mant, exp, w_block=256, act_block=16, act_mant_bits=8,
-        quantize_act=True, bm=128, bn=128, bk=256, interpret=interp,
+        quantize_act=True, bm=128, bn=128, interpret=interp,
         out_dtype=jnp.float32)
 
 

@@ -82,11 +82,14 @@ def test_grid_semantics_sees_the_accumulator_gates():
     """The AST scan resolves gates through partials AND the flash
     kernels' helper call — the evidence the race check rests on."""
     caps = {c.label: c for c in KC.sweep_captures()}
-    for label, axis in (("matmul-bench", 2), ("ln-matmul-bench", 1),
-                        ("flash-bench", 2), ("flash-decode", 2)):
+    for label, axis in (("ln-matmul-bench", 1), ("flash-bench", 2),
+                        ("flash-decode", 2)):
         facts = GS.kernel_body_facts(caps[label])
         assert facts.src_ok, label
         assert axis in {g.axis for g in facts.gates}, (label, facts.gates)
+    # the single-K-tile matmul writes each output tile once: no gate
+    facts = GS.kernel_body_facts(caps["matmul-bench"])
+    assert facts.src_ok and not facts.gates, facts.gates
 
 
 def test_cost_model_clean_on_tree():

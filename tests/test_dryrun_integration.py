@@ -52,7 +52,7 @@ def test_tiny_dryrun_cell(arch, shape, tmp_path):
 
 def test_grad_compression_cell(tmp_path):
     """The beyond-paper MXInt gradient-compression train step must lower
-    on a pod mesh (shard_map manual 'pod' + GSPMD auto elsewhere)."""
+    on a pod mesh (fully manual shard_map splitting 'pod')."""
     env = dict(os.environ)
     env["REPRO_XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     env["PYTHONPATH"] = str(ROOT / "src")

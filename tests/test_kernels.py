@@ -43,7 +43,7 @@ class TestMXIntMatmul:
         w = _rand((k, n), seed=n, scale=0.1)
         wq = quantize(w, MXFormat(8, w_block), axis=0)
         got = mm_kernel(x, wq.mantissa, wq.exponent, w_block=wq.block_size,
-                        bm=8, bn=128, bk=128, interpret=True)
+                        bm=8, bn=128, interpret=True)
         want = ref.mxint_matmul_ref(x, wq.mantissa, wq.exponent,
                                     w_block=wq.block_size)
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),
@@ -55,7 +55,7 @@ class TestMXIntMatmul:
         w = _rand((256, 128), seed=2, scale=0.1)
         wq = quantize(w, MXFormat(6, 256), axis=0)
         got = mm_kernel(x, wq.mantissa, wq.exponent, w_block=256,
-                        bm=16, bn=128, bk=256, interpret=True)
+                        bm=16, bn=128, interpret=True)
         want = ref.mxint_matmul_ref(x, wq.mantissa, wq.exponent, w_block=256)
         np.testing.assert_allclose(np.asarray(got, np.float32),
                                    np.asarray(want, np.float32),
@@ -68,7 +68,7 @@ class TestMXIntMatmul:
         wq = quantize(w, MXFormat(6, 256), axis=0)
         got = mm_kernel(x, wq.mantissa, wq.exponent, w_block=256,
                         quantize_act=True, act_block=16, act_mant_bits=8,
-                        bm=32, bn=128, bk=256, interpret=True)
+                        bm=32, bn=128, interpret=True)
         want = ref.mxint_matmul_ref(x, wq.mantissa, wq.exponent, w_block=256,
                                     quantize_act=True, act_block=16,
                                     act_mant_bits=8)
@@ -76,12 +76,13 @@ class TestMXIntMatmul:
                                    rtol=1e-5, atol=1e-5)
 
     def test_small_wblock_multiple_tiles(self):
-        """bk < w_block: several K tiles share one exponent row."""
+        """w_block == K: one exponent row scales the whole contraction
+        (the exponent plane's block is a single row, the full dim)."""
         x = _rand((8, 512), seed=5)
         w = _rand((512, 128), seed=6, scale=0.1)
         wq = quantize(w, MXFormat(8, 512), axis=0)
         got = mm_kernel(x, wq.mantissa, wq.exponent, w_block=512,
-                        bm=8, bn=128, bk=128, interpret=True)
+                        bm=8, bn=128, interpret=True)
         want = ref.mxint_matmul_ref(x, wq.mantissa, wq.exponent, w_block=512)
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                    rtol=1e-5, atol=1e-5)
@@ -306,8 +307,7 @@ class TestMXIntFlashAttention:
         q = _rand((2, 3, 197, 64), seed=72, scale=0.5)
         k = _rand((2, 3, 197, 64), seed=73, scale=0.5)
         v = _rand((2, 3, 197, 64), seed=74)
-        o = ops.attention_op(q, k, v, causal=False,
-                             softmax_variant="online", exp_mode="mxint",
+        o = ops.attention_op(q, k, v, causal=False, exp_mode="mxint",
                              quantize_scores=True)
         qf, kf, vf = (x.reshape(6, 197, 64) for x in (q, k, v))
         want = ref.mxint_flash_attention_ref(qf, kf, vf, causal=False)
@@ -494,7 +494,7 @@ class TestOpsWrappers:
 
 
 # ---------------------------------------------------------------------------
-# ISSUE 8: dimension_semantics annotations + native exponent-plane tiling
+# dimension_semantics annotations
 # ---------------------------------------------------------------------------
 def _without_compiler_params(fn, *args, **kwargs):
     """Re-run a kernel wrapper with compiler_params stripped from every
@@ -532,7 +532,7 @@ class TestDimensionSemantics:
         wq = quantize(w, MXFormat(8, 32), axis=0)
         self._assert_bit_identical(
             mm_kernel, x, wq.mantissa, wq.exponent, w_block=32,
-            quantize_act=True, bm=8, bn=128, bk=128, interpret=True)
+            quantize_act=True, bm=8, bn=128, interpret=True)
 
     def test_ln_matmul(self):
         from repro.kernels.mxint_ln_matmul import mxint_ln_matmul
@@ -568,29 +568,3 @@ class TestDimensionSemantics:
         self._assert_bit_identical(
             flash_attention_decode, qd, kd, vd, valid, block_k=64,
             interpret=True)
-
-
-class TestExpBlockRows:
-    """mxint_matmul(exp_block_rows=32): the native int8 exponent-plane
-    fetch must be bit-identical to the per-K-step fetch (ROADMAP item)."""
-
-    @pytest.mark.parametrize("quantize_act", [False, True])
-    def test_parity_vs_default(self, quantize_act):
-        x = _rand((32, 1024), seed=71, scale=0.5)
-        w = _rand((1024, 256), seed=72, scale=0.1)
-        wq = quantize(w, MXFormat(8, 32), axis=0)
-        kw = dict(w_block=32, quantize_act=quantize_act, bm=32, bn=128,
-                  bk=512, interpret=True)
-        want = mm_kernel(x, wq.mantissa, wq.exponent, **kw)
-        got = mm_kernel(x, wq.mantissa, wq.exponent, exp_block_rows=32,
-                        **kw)
-        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
-
-    def test_ops_autoselect(self):
-        # the compiled-path policy: native tile exactly when the plane
-        # divides into (32, bn) blocks spanning whole K-steps
-        assert ops._pick_exp_block_rows(1024, 32, 512) == 32
-        assert ops._pick_exp_block_rows(768, 32, 128) is None   # 24 rows
-        assert ops._pick_exp_block_rows(1024, 32, 128) == 32    # 4-step
-        assert ops._pick_exp_block_rows(256, 256, 512) is None  # kb=2, 1 row
-        assert ops._pick_exp_block_rows(512, 512, 128) is None  # bk < wb

@@ -1,8 +1,8 @@
 """Sharded kernel-mode serving: bit-exactness + zero-recompile batching.
 
-Runs ``repro.serving.sharded_check`` as a SUBPROCESS (so the forced host
-devices never leak into this test process — the dryrun-test pattern) on a
-2-device 'model' mesh:
+Runs ``repro.serving.sharded_check`` in a SUBPROCESS whose environment
+forces fake host devices (they must exist before JAX starts, and must not
+leak into this test process) on a 2-device 'model' mesh:
 
   * column-parallel sharded kernel ``classify()`` on DeiT-Tiny shapes must
     equal the single-device ``mode='sim'`` oracle BIT-FOR-BIT;
@@ -25,8 +25,7 @@ ROOT = Path(__file__).resolve().parents[1]
 
 def _run_check(extra=(), devices=2):
     env = dict(os.environ)
-    env["REPRO_XLA_FLAGS"] = \
-        f"--xla_force_host_platform_device_count={devices}"
+    env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={devices}"
     env["PYTHONPATH"] = str(ROOT / "src")
     proc = subprocess.run(
         [sys.executable, "-m", "repro.serving.sharded_check", *extra],

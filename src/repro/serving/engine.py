@@ -431,8 +431,14 @@ class ViTServingEngine:
         ``ClassifyScheduler`` go through it with an identical argument
         signature (shape/dtype/sharding), which is what keeps the jit
         cache at one specialization across arbitrary request mixes.
+        Spans ``engine/put`` (the host -> device copy of a host chunk)
+        and ``engine/dispatch`` (the call, which returns before the
+        device is done).
         """
-        return self._logits(self.params, jnp.asarray(chunk))
+        with T.span("engine/put"):
+            chunk = jnp.asarray(chunk)
+        with T.span("engine/dispatch"):
+            return self._logits(self.params, chunk)
 
     def classify(self, images: jnp.ndarray):
         """(n, H, W, 3) images -> (labels (n,), logits (n, classes)).
@@ -444,8 +450,6 @@ class ViTServingEngine:
         images = jnp.asarray(images)
         n = images.shape[0]
         batch = self.cfg.batch
-        T.histogram("serving/batch_size",
-                    T.DEFAULT_SIZE_BUCKETS).record(batch)
         with T.span("serving/classify", images=n):
             chunks = []
             for i in range(0, n, batch):

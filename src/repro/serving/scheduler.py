@@ -26,9 +26,23 @@ past one entry regardless of the request-size mix (asserted via
 Both schedulers publish to ``repro.telemetry`` (DESIGN.md §15):
 ``scheduler/submitted``/``completed``/``admissions`` counters,
 ``queue_depth``/``slots_active``/``in_flight`` gauges (conserving
-``submitted == completed + in_flight`` at step boundaries),
-``request_latency_ms`` histograms, throughput gauges, and the
+``submitted == completed + in_flight`` at step boundaries), and the
 ``serving/recompiles`` counter via the engine's jit-cache delta.
+``BatchScheduler`` adds a ``request_latency_ms`` histogram and a
+``tokens_per_s`` gauge.  ``ClassifyScheduler`` opens a span at each
+layer boundary of a step, so a ``telemetry.recording()`` holds the tree
+of every step and the life of every request::
+
+    scheduler/step              one step (>= 1 image); attrs images, step
+    ├─ scheduler/pack           queue walk + filling the batch chunk
+    ├─ scheduler/classify_step  engine call + read-back; attr images
+    │  ├─ engine/put            host -> device copy of the chunk
+    │  ├─ engine/dispatch       the jitted call, returning unready
+    │  └─ scheduler/fetch       wait for the device + copy back
+    └─ scheduler/deliver        logits into requests, gauges, eviction
+    scheduler/request           submit -> done; id = uid; attrs images,
+    └─ scheduler/queue_wait       first_step; submit -> start of the
+                                  step that packs its first image
 """
 from __future__ import annotations
 
@@ -251,7 +265,8 @@ class ClassifyRequest:
     labels: Optional[np.ndarray] = None
     done: bool = False
     _next: int = 0                     # images admitted so far
-    _submit_ts: Optional[float] = None  # set by the scheduler at submit
+    _submit_ns: Optional[int] = None   # span stamp, set at submit
+    _first_step: Optional[int] = None  # step that packed its first image
 
 
 class ClassifyScheduler:
@@ -275,13 +290,14 @@ class ClassifyScheduler:
         self.n_classes = int(getattr(engine.model.cfg, "n_classes", 0))
         self.queue: deque[ClassifyRequest] = deque()
         self.finished: List[ClassifyRequest] = []
+        self.steps = 0                     # steps that classified images
 
     def submit(self, req: ClassifyRequest):
         """Enqueue a request; its images are admitted (possibly split
         across steps) in FIFO order.  A zero-image request completes in
         queue order too (with correctly shaped empty results), so
         position-based result/label pairing stays aligned."""
-        req._submit_ts = T.walltime()
+        req._submit_ns = T.now_ns()
         self.queue.append(req)
         T.counter("scheduler/submitted").inc()
         self._update_gauges()
@@ -299,7 +315,8 @@ class ClassifyScheduler:
 
     def _evict_completed(self):
         """Pop front requests whose images are all classified (including
-        zero-image requests) to ``finished``, preserving FIFO order."""
+        zero-image requests) to ``finished``, preserving FIFO order, and
+        close each one's ``scheduler/request`` span."""
         while self.queue and self.queue[0]._next >= \
                 self.queue[0].images.shape[0]:
             req = self.queue.popleft()
@@ -309,38 +326,43 @@ class ClassifyScheduler:
             req.done = True
             self.finished.append(req)
             T.counter("scheduler/completed").inc()
-            if req._submit_ts is not None:
-                T.histogram("scheduler/request_latency_ms",
-                            T.DEFAULT_MS_BUCKETS).record(
-                    (T.walltime() - req._submit_ts) * 1e3)
+            attrs = {"images": req.images.shape[0]}
+            if req._first_step is not None:
+                attrs["first_step"] = req._first_step
+            T.span.interval("scheduler/request", req._submit_ns, T.now_ns(),
+                            id=req.uid, **attrs)
         self._update_gauges()
 
-    def step(self) -> int:
-        """Classify up to ``batch`` images off the queue front; returns
-        the number of images classified (0 when the queue is empty)."""
-        self._evict_completed()
+    def _pack(self, step_start_ns: int):
+        """Take up to ``batch`` images off the queue front into a fresh
+        zero-padded chunk; returns (chunk, [(request, image index)]).
+        Closes the ``scheduler/queue_wait`` span of each request whose
+        first image is taken."""
         take: List[tuple] = []                 # (request, image index)
         for req in self.queue:
+            if req._next == 0 and req.images.shape[0]:
+                req._first_step = self.steps
+                T.span.interval("scheduler/queue_wait", req._submit_ns,
+                                step_start_ns, parent=req.uid)
             while len(take) < self.batch and \
                     req._next < req.images.shape[0]:
                 take.append((req, req._next))
                 req._next += 1
             if len(take) >= self.batch:
                 break
-        if not take:
-            return 0
         img = take[0][0].images
         chunk = np.zeros((self.batch,) + img.shape[1:], img.dtype)
         for j, (req, i) in enumerate(take):
             chunk[j] = req.images[i]
-        with T.span("scheduler/classify_step", images=len(take)) as sp:
-            logits = np.asarray(self.engine.logits_batch(chunk))
+        return chunk, take
+
+    def _deliver(self, logits: np.ndarray, take: List[tuple]):
+        """Scatter a step's logits and labels into its requests, publish
+        the step's counters, and evict the completed requests."""
         # slot occupancy for a stateless batch = filled rows this step
         # (the rest of the fixed shape is zero padding)
         T.gauge("scheduler/slots_active").set(len(take))
         T.counter("scheduler/images_classified").inc(len(take))
-        if sp.elapsed_s:
-            T.gauge("scheduler/images_per_s").set(len(take) / sp.elapsed_s)
         _note_recompiles(self.engine)
         for j, (req, i) in enumerate(take):
             if req.logits is None:
@@ -350,6 +372,24 @@ class ClassifyScheduler:
             req.logits[i] = logits[j]
             req.labels[i] = int(np.argmax(logits[j]))
         self._evict_completed()
+
+    def step(self) -> int:
+        """Classify up to ``batch`` images off the queue front; returns
+        the number of images classified (0 when the queue is empty)."""
+        self._evict_completed()
+        if not self.queue:      # else the front request has images left
+            return 0
+        self.steps += 1
+        with T.span("scheduler/step", step=self.steps) as sp:
+            with T.span("scheduler/pack"):
+                chunk, take = self._pack(sp.start_ns)
+            sp.attrs["images"] = len(take)
+            with T.span("scheduler/classify_step", images=len(take)):
+                logits = self.engine.logits_batch(chunk)
+                with T.span("scheduler/fetch"):
+                    logits = np.asarray(logits)
+            with T.span("scheduler/deliver"):
+                self._deliver(logits, take)
         return len(take)
 
     def run(self, max_steps: int = 4096) -> List[ClassifyRequest]:

@@ -16,6 +16,8 @@ Convenience surface (all over the default registry)::
     T.gauge("scheduler/queue_depth").set(len(queue))
     with T.span("serving/classify", images=n):
         ...                          # -> span/serving/classify/ms + /images
+    with T.recording() as rec:       # keep each span's start/end/parent
+        ...                          # -> rec.spans (SpanRecord list)
     snap = T.snapshot()              # coherent dict copy
     T.reset()                        # drop everything (tests)
 
@@ -26,8 +28,9 @@ after ``block_until_ready``, around jitted calls, never inside them).
 from repro.telemetry.metrics import (DEFAULT_MS_BUCKETS,       # noqa: F401
                                      DEFAULT_SIZE_BUCKETS, Counter, Gauge,
                                      Histogram, Registry, default_registry)
-from repro.telemetry.tracing import (current_span, span,       # noqa: F401
-                                     span_stats, walltime)
+from repro.telemetry.tracing import (Recorder, SpanRecord,     # noqa: F401
+                                     current_span, now_ns, recording,
+                                     span, span_stats, walltime)
 
 
 def counter(name: str) -> Counter:

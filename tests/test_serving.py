@@ -1,11 +1,13 @@
 """Serving substrate: packed weights, engine generate, batch scheduler."""
 import dataclasses
+from types import SimpleNamespace
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro import telemetry as T
 from repro.configs import smoke_config
 from repro.core.mx_types import MXINT8_WEIGHT, MXFormat, QuantConfig
 from repro.core.quantize import MXTensor
@@ -387,6 +389,99 @@ class TestClassifyScheduler:
         assert sched.step() == 4                   # spans request boundary
         assert sched.step() == 2                   # remainder, zero-padded
         assert sched.step() == 0
+
+
+class _MeanPixelModel:
+    """A stand-in classifier: each image's mean pixel through a fixed
+    (3, n_classes) matrix, so the real engine runs a trivial jit."""
+    cfg = SimpleNamespace(n_classes=5)
+
+    def logits(self, params, images):
+        return images.mean(axis=(1, 2)) @ params
+
+
+class TestClassifySchedulerSpans:
+    """The span tree of ``ClassifyScheduler.step`` and the request spans,
+    through a real ``ViTServingEngine`` around a stand-in model."""
+
+    STEP_TREE = {"scheduler/step": None,
+                 "scheduler/pack": "scheduler/step",
+                 "scheduler/classify_step": "scheduler/step",
+                 "engine/put": "scheduler/classify_step",
+                 "engine/dispatch": "scheduler/classify_step",
+                 "scheduler/fetch": "scheduler/classify_step",
+                 "scheduler/deliver": "scheduler/step"}
+
+    @pytest.fixture(scope="class")
+    def engine(self):
+        params = jnp.arange(15, dtype=jnp.float32).reshape(3, 5)
+        return ViTServingEngine(_MeanPixelModel(), params,
+                                ServeConfig(batch=4))
+
+    @staticmethod
+    def _request(uid, n):
+        images = np.full((n, 2, 2, 3), float(uid), np.float32)
+        return ClassifyRequest(uid=uid, images=images)
+
+    @staticmethod
+    def _host(spans):
+        return [r for r in spans if r.name != "python/gc"]
+
+    def test_one_step_is_one_tree(self, engine):
+        sched = ClassifyScheduler(engine)
+        sched.submit(self._request(0, 3))
+        sched.submit(self._request(1, 2))
+        with T.recording() as rec:
+            assert sched.step() == 4
+        spans = self._host(rec.spans)
+        tree = [r for r in spans if r.name in self.STEP_TREE]
+        assert sorted(r.name for r in tree) == sorted(self.STEP_TREE)
+        by_id = {r.id: r for r in tree}
+        for r in tree:
+            parent = by_id[r.parent].name if r.parent in by_id else None
+            assert parent == self.STEP_TREE[r.name], r
+            if parent is not None:
+                p = by_id[r.parent]
+                assert p.start <= r.start <= r.end <= p.end
+        (step,) = [r for r in tree if r.name == "scheduler/step"]
+        assert step.attrs == {"step": 1, "images": 4}
+        (cs,) = [r for r in tree if r.name == "scheduler/classify_step"]
+        assert cs.attrs == {"images": 4}
+        # both requests were packed; request 0 (3 images) is done
+        waits = [r for r in spans if r.name == "scheduler/queue_wait"]
+        assert sorted(r.parent for r in waits) == [0, 1]
+        assert all(r.end == step.start for r in waits)
+        (req,) = [r for r in spans if r.name == "scheduler/request"]
+        assert req.id == 0 and req.attrs == {"images": 3, "first_step": 1}
+        assert len(spans) == len(tree) + 3
+
+    def test_every_request_has_one_request_and_one_wait(self, engine):
+        sched = ClassifyScheduler(engine)
+        sizes = (3, 6, 1, 2, 5, 4)
+        with T.recording() as rec:
+            for uid, n in enumerate(sizes):
+                sched.submit(self._request(uid, n))
+            done = sched.run()
+        assert len(done) == len(sizes)
+        spans = self._host(rec.spans)
+        reqs = {r.id: r for r in spans if r.name == "scheduler/request"}
+        waits = {r.parent: r for r in spans
+                 if r.name == "scheduler/queue_wait"}
+        assert sorted(reqs) == sorted(waits) == list(range(len(sizes)))
+        assert len([r for r in spans if r.name == "scheduler/request"]) \
+            == len([r for r in spans if r.name == "scheduler/queue_wait"]) \
+            == len(sizes)
+        steps = {r.attrs["step"]: r for r in spans
+                 if r.name == "scheduler/step"}
+        assert len(steps) == sched.steps == 6          # 21 images, batch 4
+        for uid, n in enumerate(sizes):
+            req, wait = reqs[uid], waits[uid]
+            assert req.attrs["images"] == n
+            first = steps[req.attrs["first_step"]]
+            assert req.start == wait.start
+            assert wait.end == first.start             # packed in that step
+            assert first.start < req.end
+        assert sum(s.attrs["images"] for s in steps.values()) == sum(sizes)
 
 
 class TestScheduler:

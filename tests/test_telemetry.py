@@ -1,8 +1,10 @@
 """repro.telemetry unit tests: registry semantics, span recording,
 exporters, thread-safety, and the predicted-vs-measured join
 (DESIGN.md §15)."""
+import gc
 import json
 import threading
+import time
 
 import pytest
 
@@ -10,7 +12,8 @@ from repro import telemetry as T
 from repro.telemetry.export import (json_snapshot, predicted_vs_measured,
                                     prometheus_text)
 from repro.telemetry.metrics import Registry
-from repro.telemetry.tracing import current_span, span, span_stats
+from repro.telemetry.tracing import (current_span, now_ns, recording, span,
+                                     span_stats)
 
 
 @pytest.fixture
@@ -154,6 +157,105 @@ class TestSpans:
                 pass
         n, mean_ms = span_stats("s", registry=reg)
         assert n == 3 and mean_ms >= 0
+
+
+class TestRecording:
+    @pytest.fixture(autouse=True)
+    def _collect_only_when_asked(self):
+        """Automatic collections would add ``python/gc`` records at
+        random points; the tests that want one call ``gc.collect()``."""
+        was = gc.isenabled()
+        gc.disable()
+        yield
+        if was:
+            gc.enable()
+
+    def test_nothing_kept_outside_a_recording(self, reg):
+        with span("before", registry=reg):
+            pass
+        with recording() as rec:
+            pass
+        with span("after", registry=reg):
+            pass
+        span.interval("later", now_ns(), now_ns(), registry=reg)
+        assert rec.spans == [] and rec.dropped == 0
+        assert reg.histogram("span/after/ms").count == 1
+
+    def test_nested_ids_parents_stamps_and_attrs(self, reg):
+        with recording() as rec:
+            with span("outer", registry=reg, n=2) as so:
+                with span("inner", registry=reg) as si:
+                    pass
+                with span("inner", registry=reg) as sj:
+                    pass
+        inner, inner2, outer = rec.spans          # in the order they end
+        assert [r.name for r in rec.spans] == ["inner", "inner", "outer"]
+        assert (outer.id, inner.id, inner2.id) == (so.id, si.id, sj.id)
+        assert len({so.id, si.id, sj.id}) == 3
+        assert outer.parent is None
+        assert inner.parent == inner2.parent == so.id
+        assert outer.attrs == {"n": 2} and inner.attrs == {}
+        assert outer.start <= inner.start <= inner.end <= inner2.start \
+            <= inner2.end <= outer.end
+        assert outer.start == so.start_ns
+        assert (outer.end - outer.start) * 1e-9 == pytest.approx(so.elapsed_s)
+
+    def test_interval_carries_the_callers_id(self, reg):
+        a = now_ns()
+        b = now_ns()
+        with recording() as rec:
+            span.interval("scheduler/request", a, b, id=42, registry=reg,
+                          images=3)
+            span.interval("scheduler/queue_wait", a, b, parent=42,
+                          registry=reg)
+        req, wait = rec.spans
+        assert (req.name, req.id, req.parent, req.start, req.end) == \
+            ("scheduler/request", 42, None, a, b)
+        assert req.attrs == {"images": 3}
+        assert wait.parent == 42 and wait.id != 42
+        assert reg.histogram("span/scheduler/request/ms").count == 1
+        assert reg.histogram("span/scheduler/request/images").sum == 3
+
+    def test_epoch_ns_lies_between_wall_clock_reads(self):
+        with recording() as rec:
+            for _ in range(3):
+                lo = time.time_ns()
+                time.sleep(1e-3)
+                stamp = now_ns()
+                time.sleep(1e-3)
+                hi = time.time_ns()
+                assert lo < rec.epoch_ns(stamp) < hi
+
+    def test_capacity_and_dropped(self, reg):
+        with recording(capacity=2) as rec:
+            for i in range(5):
+                with span("s", registry=reg, i=i):
+                    pass
+        assert [r.attrs["i"] for r in rec.spans] == [0, 1]
+        assert rec.dropped == 3
+
+    def test_gc_collections_are_spans(self, reg):
+        with recording() as rec:
+            with span("outer", registry=reg) as so:
+                gc.collect()
+            assert rec._on_gc in gc.callbacks
+        assert rec._on_gc not in gc.callbacks
+        collections = [r for r in rec.spans if r.name == "python/gc"]
+        assert any(r.attrs == {"generation": 2} and r.parent == so.id
+                   and so.start_ns <= r.start <= r.end
+                   for r in collections)
+        n = len(rec.spans)
+        gc.collect()                              # hook gone: not kept
+        assert len(rec.spans) == n
+
+    def test_one_recording_at_a_time(self):
+        with recording():
+            with pytest.raises(RuntimeError):
+                with recording():
+                    pass
+        with recording() as rec:                  # released on exit
+            pass
+        assert rec.spans == []
 
 
 # ---------------------------------------------------------------------------

@@ -7,18 +7,20 @@ execution:
 * **HBM traffic** — Pallas walks the grid in lexicographic order (last
   axis fastest) and re-fetches an operand block only when its
   ``index_map`` value changes between consecutive steps.  The model
-  counts those transitions per operand (``bytes_traffic``) and also the
+  counts those transitions per operand (``bytes_traffic``; a ragged
+  last block by its in-bounds bytes) and also the
   distinct-block footprint (``bytes_unique`` — what an ideal
   infinite-VMEM schedule would move, and what the analytic counters in
   ``kernel_bench`` count).  Mantissa and exponent planes are separate
   operands, so their bytes are accounted separately, at 1 byte/element —
   the paper's packed-plane memory win is visible per row.
 * **FLOPs** — closed-form per kernel family from block shapes and the
-  partial's config (dot products 2·m·k·n; select-chain LUT lookups
-  2·elements·2^bits; O(10)·elements vector work for the rowwise
-  datapaths).  Formulas are in DESIGN.md §14; they feed the arithmetic-
-  intensity column of the roofline table, while the BYTE columns are the
-  CI-guarded quantity.
+  partial's config, over every grid step, so a ragged last row block
+  counts the rows it computes past the array (dot products 2·m·k·n;
+  select-chain LUT lookups 2·elements·2^bits; O(10)·elements vector
+  work for the rowwise datapaths).  Formulas are in DESIGN.md §14; they
+  feed the arithmetic-intensity column of the roofline table, while the
+  BYTE columns are the CI-guarded quantity.
 * **VMEM residency** — ``2 × (in+out block bytes) + scratch`` (the same
   double-buffering model the kernel-contracts VMEM cap uses).
 
@@ -64,9 +66,21 @@ def _block_bytes(use: BlockUse) -> int:
     return _nbytes(use.block_shape, use.dtype)
 
 
+def _bytes_in_bounds(use: BlockUse, bid: Tuple[int, ...]) -> int:
+    """Bytes of block ``bid`` that lie inside the array: a ragged last
+    block moves only its in-bounds part."""
+    if len(bid) != len(use.shape):
+        return _block_bytes(use)
+    n = jnp.dtype(use.dtype).itemsize
+    for dim, blk, b in zip(use.shape, use.block_shape, bid):
+        n *= max(0, min(blk, dim - b * blk))
+    return n
+
+
 def operand_traffic(use: BlockUse,
                     grid: Tuple[int, ...]) -> Optional[Tuple[int, int]]:
-    """(consecutive re-fetches, distinct blocks) for one operand.
+    """(bytes re-fetched over the grid, bytes of the distinct blocks) for
+    one operand, each block counted by its in-bounds part.
 
     Returns None when the grid is too large to enumerate (none of the
     swept kernels is)."""
@@ -76,7 +90,7 @@ def operand_traffic(use: BlockUse,
     if points > MAX_GRID_POINTS:
         return None
     im = use.index_map
-    fetches = 0
+    fetched = 0
     prev: object = object()
     uniq = set()
     for idx in itertools.product(*[range(g) for g in grid]):
@@ -87,10 +101,10 @@ def operand_traffic(use: BlockUse,
             raw = raw if isinstance(raw, (list, tuple)) else (raw,)
             bid = tuple(int(b) for b in raw)
         if bid != prev:
-            fetches += 1
+            fetched += _bytes_in_bounds(use, bid)
             prev = bid
         uniq.add(bid)
-    return fetches, len(uniq)
+    return fetched, sum(_bytes_in_bounds(use, b) for b in uniq)
 
 
 # ---------------------------------------------------------------------------
@@ -178,20 +192,19 @@ def capture_costs(cap: PallasCapture) -> Dict[str, object]:
     unique_total = 0
     for use in cap.inputs + cap.outputs:
         t = operand_traffic(use, cap.grid)
-        bb = _block_bytes(use)
         if t is None:
-            fetches, uniq = _steps(cap.grid), _steps(cap.grid)
+            fetched = uniq = _steps(cap.grid) * _block_bytes(use)
         else:
-            fetches, uniq = t
+            fetched, uniq = t
         operands.append({
             "name": use.name,
             "dtype": str(jnp.dtype(use.dtype)),
             "block": list(use.block_shape),
-            "bytes_traffic": fetches * bb,
-            "bytes_unique": uniq * bb,
+            "bytes_traffic": fetched,
+            "bytes_unique": uniq,
         })
-        traffic_total += fetches * bb
-        unique_total += uniq * bb
+        traffic_total += fetched
+        unique_total += uniq
     vmem = (DOUBLE_BUFFER * sum(_block_bytes(u)
                                 for u in cap.inputs + cap.outputs)
             + sum(_nbytes(s.shape, s.dtype) for s in cap.scratch))

@@ -11,7 +11,9 @@ executes).  Four contracts are then verified per captured call:
    must fit the configurable per-core cap (default 16 MiB, the v5e VMEM
    size; ``dbuf=2`` models Pallas' input/output double buffering).
 2. **Tile alignment** — on every axis a BlockSpec actually tiles
-   (block < array dim), the block must divide the dim; the minormost
+   (block < array dim), the block must divide the dim, except the row
+   axis of a kernel whose rows are independent
+   (:data:`RAGGED_ROW_KERNELS`), whose last block may be ragged; the minormost
    tiled axis must be a multiple of the 128-wide lane, the second-minor
    a multiple of the 8-row f32 sublane (or exactly 1 — a supported
    degenerate layout).  Narrow dtypes have larger NATIVE sublanes
@@ -52,6 +54,11 @@ SUBLANE_F32 = 8
 NATIVE_SUBLANE = {1: 32, 2: 16, 4: 8, 8: 8}
 # keep index-map enumeration cheap; none of the swept kernels comes close
 MAX_GRID_POINTS = 65536
+# kernels whose every stage is per row: a ragged last row block (grid
+# cdiv(rows, bm)) computes rows past the array from whatever the block
+# holds there, and Pallas drops them on write-back
+RAGGED_ROW_KERNELS = frozenset({"_mxint_matmul_kernel",
+                                "_mxint_ln_matmul_kernel"})
 
 
 @dataclasses.dataclass(frozen=True)
@@ -208,7 +215,10 @@ def _check_alignment(cap: PallasCapture, use: BlockUse) -> List[Violation]:
             f"{use.name}: block rank {use.block_shape} != array rank "
             f"{use.shape}"))
         return out
-    for dim, blk in zip(use.shape, use.block_shape):
+    ragged_ok = cap.kernel in RAGGED_ROW_KERNELS and len(use.shape) == 2
+    for axis, (dim, blk) in enumerate(zip(use.shape, use.block_shape)):
+        if blk > 0 and ragged_ok and axis == 0:
+            continue
         if blk <= 0 or dim % blk:
             out.append(Violation(
                 "kernel-contracts", _where(cap),
@@ -264,7 +274,7 @@ def _check_index_maps(cap: PallasCapture) -> List[Violation]:
     for use in cap.inputs + cap.outputs:
         if use.index_map is None:
             continue
-        nblocks = tuple(dim // blk for dim, blk
+        nblocks = tuple(-(-dim // blk) for dim, blk
                         in zip(use.shape, use.block_shape))
         if any(b == 0 for b in nblocks):
             continue  # divisibility already flagged
@@ -372,6 +382,7 @@ def _sds(shape, dtype=jnp.float32):
 
 def _sweep_matmul() -> List[PallasCapture]:
     from repro.kernels.mxint_matmul import mxint_matmul
+    from repro.kernels.ops import linear_row_tile
     caps = []
     # kernel_bench: 128x1024 @ 1024x512, paper W-block 256
     caps += capture_pallas_calls(
@@ -398,10 +409,19 @@ def _sweep_matmul() -> List[PallasCapture]:
     caps += capture_pallas_calls(
         lambda x, m, e: mxint_matmul.__wrapped__(
             x, m, e, w_block=32, act_block=16, act_mant_bits=8,
-            quantize_act=True, bm=16, bn=128, interpret=True,
-            out_dtype=jnp.float32),
+            quantize_act=True, bm=linear_row_tile(394, 192), bn=128,
+            interpret=True, out_dtype=jnp.float32),
         _sds((400, 192)), _sds((192, 256), jnp.int8),
         _sds((6, 256), jnp.int8), label="matmul-deit")
+    # DeiT-Base fc2 in a batch of 32 images: 6,304 rows, K=3072, the
+    # paper's W-block 256 — a ragged last row block (25 x 256 rows)
+    caps += capture_pallas_calls(
+        lambda x, m, e: mxint_matmul.__wrapped__(
+            x, m, e, w_block=256, act_block=16, act_mant_bits=8,
+            quantize_act=True, bm=linear_row_tile(6304, 3072), bn=128,
+            interpret=False, out_dtype=jnp.float32),
+        _sds((6304, 3072)), _sds((3072, 768), jnp.int8),
+        _sds((12, 768), jnp.int8), label="matmul-deit-bulk")
     return caps
 
 
@@ -438,13 +458,25 @@ def _sweep_rowwise() -> List[PallasCapture]:
 
 def _sweep_ln_matmul() -> List[PallasCapture]:
     from repro.kernels.mxint_ln_matmul import mxint_ln_matmul
-    return capture_pallas_calls(
+    from repro.kernels.ops import linear_row_tile
+    caps = capture_pallas_calls(
         lambda x, g, b, m, e: mxint_ln_matmul.__wrapped__(
             x, g, b, m, e, w_block=32, act_block=16, mant_bits=8,
             lut_bits=5, bm=128, bn=128, interpret=True),
         _sds((256, 768)), _sds((768,)), _sds((768,)),
         _sds((768, 768), jnp.int8), _sds((24, 768), jnp.int8),
         label="ln-matmul-bench")
+    # DeiT-Base LN->fc1 in a batch of 32 images: 6,304 rows in 25 row
+    # blocks of 256, the last ragged
+    caps += capture_pallas_calls(
+        lambda x, g, b, m, e: mxint_ln_matmul.__wrapped__(
+            x, g, b, m, e, w_block=256, act_block=16, mant_bits=8,
+            lut_bits=5, bm=linear_row_tile(6304, 768), bn=128,
+            interpret=False),
+        _sds((6304, 768)), _sds((768,)), _sds((768,)),
+        _sds((768, 3072), jnp.int8), _sds((3, 3072), jnp.int8),
+        label="ln-matmul-deit-bulk")
+    return caps
 
 
 def _sweep_flash() -> List[PallasCapture]:

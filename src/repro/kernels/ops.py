@@ -158,6 +158,66 @@ def _pick_block_rows(rows: int, cap: int = 256) -> int:
     return 1
 
 
+# Row tile of the MXInt linear kernels.  Every (row tile, N tile) grid
+# step re-fetches its K x 128 weight tile, widens it to f32 and scales it
+# by 2^e, so the tile is as tall as VMEM allows: 256 rows, or 128 (one
+# full pass of v5e's 128 x 128 MXU) where a 256-row f32 activation block
+# would pass _ROW_TILE_ACT_BYTES.  VMEM budget, from compiles for a
+# described v5e: at K = 3072 (DeiT-Base fc2) Mosaic's scoped VMEM for
+# mxint_matmul with the in-register quantizer is 7.0 MiB at 256 rows and
+# 16.6 MiB at 512, past the 16 MiB default limit; the fused LN kernel at
+# K = 768 needs 2.7 MiB at 256 rows.  tests/test_v5e_compile.py compiles
+# every DeiT linear at 6,304 rows.
+_ROW_TILE = 256
+_ROW_TILE_ACT_BYTES = 256 * 3072 * 4
+_LINEAR_PREFIX = "kernels/linear_"
+
+
+def linear_row_tile(rows: int, k: int) -> int:
+    """Row tile for a (rows, k) activation of a linear kernel.
+
+    The fewest row blocks of at most the cap, balanced and rounded up to
+    the 8-row sublane; the last block may be ragged.  A call of at most
+    the cap's rows (decode, small batches) is one block of ``rows``
+    rounded up to 8.
+    """
+    cap = _ROW_TILE if _ROW_TILE * k * 4 <= _ROW_TILE_ACT_BYTES else 128
+    blocks = -(-rows // cap)
+    return _ceil_to(-(-rows // blocks), 8)
+
+
+def _counted_row_tile(rows: int, k: int) -> int:
+    """``linear_row_tile`` of a call, counted at trace time in
+    ``repro.telemetry``: calls by row tile, rows, and rows the grid
+    computes past them (the pad to 8 and the ragged last block)."""
+    from repro import telemetry as T
+    bm = linear_row_tile(rows, k)
+    T.counter(f"{_LINEAR_PREFIX}row_tile/{bm}").inc()
+    T.counter(_LINEAR_PREFIX + "rows").inc(rows)
+    T.counter(_LINEAR_PREFIX + "rows_padded").inc(
+        _ceil_to(rows, bm) - rows)
+    return bm
+
+
+def linear_tiling() -> dict:
+    """The linear kernels' row tiling since the last reset: calls by row
+    tile, rows, padded rows and their share of the rows."""
+    from repro import telemetry as T
+    counts = T.default_registry().counters_with_prefix(_LINEAR_PREFIX)
+    rows = counts.get("rows", 0)
+    padded = counts.get("rows_padded", 0)
+    return {"row_tile_calls": {int(k.split("/")[1]): v
+                               for k, v in sorted(counts.items())
+                               if k.startswith("row_tile/")},
+            "rows": rows, "rows_padded": padded,
+            "padded_share": padded / rows if rows else 0.0}
+
+
+def reset_linear_tiling() -> None:
+    from repro import telemetry as T
+    T.reset(_LINEAR_PREFIX)
+
+
 def _pad_planes(w_mant: jnp.ndarray, w_exp: jnp.ndarray):
     """Pad packed (K, N) / (K/B, N) planes to a lane multiple of N."""
     n_p = _ceil_to(w_mant.shape[1], 128)
@@ -203,7 +263,8 @@ def mxint_linear(x: jnp.ndarray, w_mant: jnp.ndarray, w_exp: jnp.ndarray,
     The packed planes go into the Pallas kernel untouched — HBM traffic is
     the quantized bytes (the paper's memory win).  Rows are padded to the
     sublane multiple and output columns to the lane multiple so ANY model
-    shape runs through the kernel, compiled or interpreted alike; the K
+    shape runs through the kernel, compiled or interpreted alike; rows
+    are tiled by ``linear_row_tile``, with a ragged last block; the K
     contraction is a single tile, which keeps the accumulation order
     identical to the XLA einsum of the 'sim' oracle (bit-exact parity on
     the CPU).
@@ -222,7 +283,7 @@ def mxint_linear(x: jnp.ndarray, w_mant: jnp.ndarray, w_exp: jnp.ndarray,
     y = _mm_kernel(x2p, wm, we, w_block=w_block,
                    act_block=_resolve_block(K, act_block),
                    act_mant_bits=act_mant_bits, quantize_act=quantize_act,
-                   bm=_pick_block_rows(x2p.shape[0], 128), bn=128,
+                   bm=_counted_row_tile(rows, K), bn=128,
                    interpret=_interpret())[:rows, :N]
     if tp_axis is not None:
         if tp_mode == "gather":
@@ -309,7 +370,7 @@ def mxint_ln_linear_op(x: jnp.ndarray, gamma: jnp.ndarray,
                         act_block=_resolve_block(K, act_block),
                         mant_bits=mant_bits, lut_bits=lut_bits,
                         rms_only=rms_only,
-                        bm=_pick_block_rows(x2p.shape[0], 128), bn=128,
+                        bm=_counted_row_tile(rows, K), bn=128,
                         interpret=_interpret())[:rows, :N]
     if tp_axis is not None and tp_mode == "gather":
         y = jax.lax.all_gather(y, tp_axis, axis=1, tiled=True)

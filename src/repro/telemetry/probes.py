@@ -47,8 +47,8 @@ def _probe_matmul_deit() -> Callable[[], object]:
     interp = ops._interpret()
     return lambda: mxint_matmul(
         x, mant, exp, w_block=32, act_block=16, act_mant_bits=8,
-        quantize_act=True, bm=16, bn=128, interpret=interp,
-        out_dtype=jnp.float32)
+        quantize_act=True, bm=ops.linear_row_tile(394, 192), bn=128,
+        interpret=interp, out_dtype=jnp.float32)
 
 
 def _probe_flash_deit() -> Callable[[], object]:

@@ -59,6 +59,15 @@ def test_no_fallback_counted(tiny_phase):
     assert report["fallbacks"] == {}
 
 
+def test_linear_tiling_counted_once_per_call_site(tiny_phase):
+    """patch linear, 3 fused LN->q/k/v, out-proj, fused LN->wi, wo and
+    head, each counted once while the classify program was traced."""
+    report, _ = tiny_phase
+    tiling = report["linear_tiling"]
+    assert sum(tiling["row_tile_calls"].values()) == 8
+    assert 0 <= tiling["rows_padded"] < tiling["rows"]
+
+
 @pytest.mark.parametrize("level", ["logits", "op"])
 @pytest.mark.parametrize("where", ["max", "rms"])
 def test_reference_comparison_catches_perturbed_logit(where, level):

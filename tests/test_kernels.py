@@ -494,6 +494,108 @@ class TestOpsWrappers:
 
 
 # ---------------------------------------------------------------------------
+# linear row tile: a full tile from (rows, K), ragged last row block
+# ---------------------------------------------------------------------------
+def _linear_case(rows, k=256, n=256):
+    x = _rand((rows, k), seed=rows, scale=2.0)
+    w = _rand((k, n), seed=k + n, scale=0.1)
+    wq = quantize(w, MXFormat(6, 256), axis=0)
+    g = _rand((k,), seed=1, scale=0.5) + 1.0
+    b = _rand((k,), seed=2, scale=0.1)
+    return x, wq, g, b
+
+
+def _pad8(x):
+    return jnp.pad(x, ((0, (-x.shape[0]) % 8), (0, 0)))
+
+
+class TestLinearRowTile:
+    @pytest.mark.parametrize("rows,k,bm", [
+        (6304, 768, 256), (6304, 3072, 256), (6304, 4096, 128),
+        (6272, 768, 256), (1576, 768, 232), (197, 768, 200),
+        (32, 768, 32), (2, 64, 8)])
+    def test_tile_from_rows_and_k(self, rows, k, bm):
+        assert ops.linear_row_tile(rows, k) == bm
+
+    @pytest.mark.parametrize("rows", [197, 394, 650, 1576])
+    @pytest.mark.parametrize("quantize_act", [False, True])
+    def test_linear_bit_exact_at_rows_the_tile_does_not_divide(
+            self, rows, quantize_act):
+        x, wq, _, _ = _linear_case(rows)
+        got = ops.mxint_linear(x, wq.mantissa, wq.exponent, w_block=256,
+                               quantize_act=quantize_act)
+        assert got.shape == (rows, 256)
+        divides = mm_kernel(_pad8(x), wq.mantissa, wq.exponent, w_block=256,
+                            quantize_act=quantize_act, bm=8, bn=128,
+                            interpret=True)[:rows]
+        want = ref.mxint_matmul_ref(x, wq.mantissa, wq.exponent,
+                                    w_block=256, quantize_act=quantize_act)
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(divides))
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+    @pytest.mark.parametrize("rows", [197, 394, 650, 1576])
+    def test_ln_linear_bit_exact_at_rows_the_tile_does_not_divide(
+            self, rows):
+        from repro.core.quantize import quantize_dequantize
+        from repro.kernels.mxint_ln_matmul import mxint_ln_matmul
+        x, wq, g, b = _linear_case(rows)
+        got = ops.mxint_ln_linear_op(x, g, b, wq.mantissa, wq.exponent,
+                                     w_block=256)
+        assert got.shape == (rows, 256)
+        divides = mxint_ln_matmul(_pad8(x), g, b, wq.mantissa, wq.exponent,
+                                  w_block=256, bm=8, bn=128,
+                                  interpret=True)[:rows]
+        normed = quantize_dequantize(ref.mxint_layernorm_ref(x, g, b),
+                                     MXFormat(8, 16), axis=-1)
+        want = ref.mxint_matmul_ref(normed, wq.mantissa, wq.exponent,
+                                    w_block=256, quantize_act=True)
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(divides))
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+    @pytest.mark.parametrize("fused", [False, True])
+    def test_poisoned_rows_past_m_do_not_leak(self, fused):
+        """1,576 rows in 232-row tiles: the last block holds 48 rows past
+        M.  Filled with NaN they change no valid row, and the ragged call
+        (whose interpreter fills them with NaN too) returns M rows."""
+        from repro.kernels.mxint_ln_matmul import mxint_ln_matmul
+        rows, bm = 1576, 232
+        x, wq, g, b = _linear_case(rows)
+        poisoned = jnp.concatenate(
+            [x, jnp.full((7 * bm - rows, x.shape[1]), jnp.nan)])
+
+        def run(a):
+            if fused:
+                return mxint_ln_matmul(a, g, b, wq.mantissa, wq.exponent,
+                                       w_block=256, bm=bm, bn=128,
+                                       interpret=True)
+            return mm_kernel(a, wq.mantissa, wq.exponent, w_block=256,
+                             quantize_act=True, bm=bm, bn=128,
+                             interpret=True)
+
+        ragged = np.asarray(run(x))
+        full = np.asarray(run(poisoned))
+        assert ragged.shape == (rows, 256)
+        assert np.isfinite(ragged).all()
+        assert np.isnan(full[rows:]).all()
+        np.testing.assert_array_equal(full[:rows], ragged)
+
+    def test_tiling_counted_once_per_trace(self):
+        x, wq, g, b = _linear_case(1576)
+        fn = jax.jit(lambda a: ops.mxint_ln_linear_op(
+            a, g, b, wq.mantissa, wq.exponent, w_block=256) +
+            ops.mxint_linear(a, wq.mantissa, wq.exponent, w_block=256))
+        ops.reset_linear_tiling()
+        try:
+            for _ in range(3):
+                fn(x).block_until_ready()
+            assert ops.linear_tiling() == {
+                "row_tile_calls": {232: 2}, "rows": 2 * 1576,
+                "rows_padded": 2 * 48, "padded_share": 48 / 1576}
+        finally:
+            ops.reset_linear_tiling()
+
+
+# ---------------------------------------------------------------------------
 # dimension_semantics annotations
 # ---------------------------------------------------------------------------
 def _without_compiler_params(fn, *args, **kwargs):

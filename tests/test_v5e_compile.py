@@ -7,9 +7,11 @@ tiling, VMEM overruns).  Interpret-mode tests cannot see any of that.
 
 The kernels are compiled through the ``repro.kernels.ops`` wrappers, with
 ``ops.on_tpu`` patched so they take their compiled branch, at the shapes a
-batch of 8 images launches at DeiT-Tiny, -Small and -Base widths.  One
-test compiles the whole jitted DeiT-Base kernel-mode forward and counts
-its Pallas custom calls.
+batch of 8 and of 32 images launches at DeiT-Tiny, -Small and -Base
+widths: at 32 images every linear runs 6,304 rows in 256-row tiles with a
+ragged last block, fc2 at K = 3072 the largest VMEM case.  One test
+compiles the whole jitted DeiT-Base kernel-mode forward at each batch and
+counts its Pallas custom calls.
 
 The topology is described inside a fixture, never while a module is
 imported: only one process at a time may load the TPU library, and every
@@ -26,7 +28,7 @@ from repro.configs.deit import BY_NAME
 from repro.core.mx_types import QuantConfig
 from repro.kernels import ops
 
-BATCH = 8
+BATCHES = (8, 32)
 F32, I8 = jnp.float32, jnp.int8
 KCFG = QuantConfig(mode="kernel", quantize_nonlinear=True)
 WIDTHS = ("deit_tiny", "deit_small", "deit_base")
@@ -67,11 +69,11 @@ def _compile(fn, *shapes):
     return jax.jit(fn).lower(*shapes).compile()
 
 
-def _kernel_cases(cfg):
+def _kernel_cases(cfg, batch):
     """(name, fn, arg shapes) for every kernel the forward launches."""
     d, ff = cfg.d_model, cfg.d_ff
     tokens = (cfg.image_size // cfg.patch_size) ** 2
-    rows = BATCH * (tokens + 1)
+    rows = batch * (tokens + 1)
     wfmt, act = KCFG.weight_fmt, KCFG.act_fmt
     nl = KCFG.nonlinear
 
@@ -94,12 +96,12 @@ def _kernel_cases(cfg):
 
     patch_dim = 3 * cfg.patch_size ** 2
     return {
-        "patch_linear": linear(BATCH * tokens, patch_dim, d),
+        "patch_linear": linear(batch * tokens, patch_dim, d),
         "ln_qkv": ln_linear(d),
         "out_proj": linear(rows, d, d),
         "ln_wi": ln_linear(ff),
         "wo": linear(rows, ff, d),
-        "head": linear(BATCH, d, cfg.n_classes),
+        "head": linear(batch, d, cfg.n_classes),
         "final_ln": (lambda x, g, b: ops.mxint_layernorm_op(
             x, g, b, act_block=act.block_size, mant_bits=act.mant_bits,
             lut_bits=nl.ln_lut_bits, quantize_out=True),
@@ -107,7 +109,7 @@ def _kernel_cases(cfg):
         "softmax": (lambda s: ops.mxint_softmax_op(
             s, act_block=act.block_size, mant_bits=act.mant_bits,
             r_bits=nl.softmax_r_bits, quantize_out=True),
-            [((BATCH * cfg.n_heads, tokens + 1, tokens + 1), F32)]),
+            [((batch * cfg.n_heads, tokens + 1, tokens + 1), F32)]),
         "gelu": (lambda x: ops.mxint_gelu_op(
             x, act_block=act.block_size, mant_bits=act.mant_bits,
             lut_bits=nl.gelu_lut_bits, domain=nl.gelu_domain),
@@ -121,15 +123,18 @@ KERNELS = ("patch_linear", "ln_qkv", "out_proj", "ln_wi", "wo", "head",
 
 @pytest.mark.parametrize("kernel", KERNELS)
 @pytest.mark.parametrize("arch", WIDTHS)
-def test_kernel_compiles_for_v5e(arch, kernel, one_chip, compiled_path):
-    fn, shapes = _kernel_cases(BY_NAME[arch])[kernel]
+@pytest.mark.parametrize("batch", BATCHES)
+def test_kernel_compiles_for_v5e(batch, arch, kernel, one_chip,
+                                 compiled_path):
+    fn, shapes = _kernel_cases(BY_NAME[arch], batch)[kernel]
     args = [jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
             for shape, dtype in shapes]
     compiled = _compile(fn, *args)
     assert "tpu_custom_call" in compiled.as_text()
 
 
-def test_deit_base_forward_compiles_for_v5e(one_chip, compiled_path):
+@pytest.mark.parametrize("batch", BATCHES)
+def test_deit_base_forward_compiles_for_v5e(batch, one_chip, compiled_path):
     from repro.models import build_model
     from repro.serving.engine import pack_params_mxint
 
@@ -140,7 +145,7 @@ def test_deit_base_forward_compiles_for_v5e(one_chip, compiled_path):
     params = jax.tree_util.tree_map(
         lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
         params)
-    imgs = jax.ShapeDtypeStruct((BATCH, cfg.image_size, cfg.image_size, 3),
+    imgs = jax.ShapeDtypeStruct((batch, cfg.image_size, cfg.image_size, 3),
                                 jnp.float32, sharding=one_chip)
     text = _compile(model.logits, params, imgs).as_text()
     # patch linear + the 8 kernels of the scanned block body + final LN

@@ -77,8 +77,9 @@ def judged(numbers: dict, limits: dict) -> dict:
 
 def readings(cell, seed: int, seconds: float, faults: bool, peaks) -> dict:
     """Program, control and fault readings of one seed."""
-    from bench.harness import check, load
+    from bench.harness import check, load, manifest
     cfg, limits = cell.config, cell.workload["limits"]
+    fam = manifest.family(cfg["family"])
     t = time.perf_counter()
     s = load.setup(cell, seed, t)
     run = load.window(s, seconds, False, peaks)
@@ -95,13 +96,13 @@ def readings(cell, seed: int, seconds: float, faults: bool, peaks) -> dict:
                         np.random.default_rng([seed, 4]),
                         cell.workload["sample_images"])
     images = np.concatenate([s.pool[r.start:r.start + r.n] for r in reqs])
-    low = check.reference_logits(cfg, s.weights, images, "bfloat16")
-    inputs = check.op_inputs(cfg, s.batch, seed)
-    layer = check.layer0(s.weights)
+    low = check.reference_output(cfg, s.weights, images, "bfloat16")
+    inputs = fam.op_inputs(cfg, s.batch, seed)
+    layer = fam.layer0(s.weights)
     ctl_ops = check.ops_numbers(
-        check.reference_ops(cfg, layer, inputs, "bfloat16"),
-        check.reference_ops(cfg, layer, inputs, "highest"))
-    control = {"logits_rel_rms": check.logits_number(
+        fam.reference_ops(cfg, layer, inputs, "bfloat16"),
+        fam.reference_ops(cfg, layer, inputs, "highest"))
+    control = {fam.OUTPUT: check.output_number(
         cfg, s.weights, s.pool, reqs, served=low),
         "ops_rel_rms": max(ctl_ops.values())}
 
@@ -113,8 +114,8 @@ def readings(cell, seed: int, seconds: float, faults: bool, peaks) -> dict:
         reqs = check.sample(r.requests[:r.in_window],
                             np.random.default_rng([seed, 4]),
                             cell.workload["sample_images"])
-        fault = {"logits_rel_rms": check.logits_number(cfg, s.weights,
-                                                       s.pool, reqs),
+        fault = {fam.OUTPUT: check.output_number(cfg, s.weights, s.pool,
+                                                 reqs),
                  "ops_rel_rms": numbers["ops_rel_rms"]}
         out["faults"][name] = judged(fault, limits)
     out["seconds"] = time.perf_counter() - t

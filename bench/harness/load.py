@@ -25,7 +25,7 @@ class Request:
     n: int
     sent: float = 0.0
     finish: float | None = None
-    logits: np.ndarray | None = None
+    output: np.ndarray | None = None   # what the program answered
 
 
 @dataclasses.dataclass
@@ -117,7 +117,7 @@ class _Server:
         out = []
         for req in fin[self.done:]:
             r = self.requests[req.uid]
-            r.finish, r.logits = e, req.logits
+            r.finish, r.output = e, req.logits
             out.append(r)
         self.done = len(fin)
         return out

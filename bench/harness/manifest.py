@@ -1,21 +1,30 @@
 """Finds everything a cell needs by the names in ``BENCHMARK.json``.
 
-A cell ``<config>.<traffic>`` is assembled from data files only:
+A cell ``<config>.<traffic>`` is assembled from files found by name:
 
-* ``bench/configs/<config>.json``: the model's sizes, source and cuts;
+* ``bench/configs/<config>.json``: the model's sizes, source, cuts and
+  datapath, and its ``"family"``;
+* ``bench/families/<family>.py``: the architecture (``family``): weight
+  shapes, the program's model, the per-op cases on both sides, the plain
+  reference forward, and the name of its output number;
+* ``bench/work/<family>.py``: operations and bytes from shapes;
 * ``bench/traffic/<traffic>.json``: the traffic mix (loop, sizes, ...);
 * ``bench/workloads/<cell>.json``: what belongs to the cell alone (engine
   batch, correctness sample and limits);
 * ``bench/metrics/<metric>.py``: one reader per metric, ``read(run)``;
-* ``bench/work/<family>.py``: operations and bytes from shapes;
 * ``bench/peaks.json``: the chip's published peaks by ``device_kind``.
 
-So a later cell, configuration, traffic mix or metric is new files and
-new ``BENCHMARK.json`` entries, never an edit.
+So a new configuration is ``bench/configs/<config>.json``, the family's
+two modules if its family is new, a workload file per cell, a traffic
+file if its mix is new, and its ``BENCHMARK.json`` entries (config,
+cells, and the cells added to each metric's ``workloads``); a new metric
+is its reader and its entry.  None of them is an edit of a file the
+benchmark has.
 """
 from __future__ import annotations
 
 import dataclasses
+import functools
 import importlib.util
 import json
 from pathlib import Path
@@ -80,6 +89,21 @@ def metric_reader(name: str):
 def work(family: str):
     """The module ``bench/work/<family>.py``."""
     return _module(BENCH / "work" / f"{family}.py")
+
+
+@functools.cache
+def family(name: str):
+    """The module ``bench/families/<name>.py``, loaded once a process.
+
+    It provides ``OUTPUT`` (the name of the number comparing the served
+    output with the reference), ``DIMS`` and ``shapes(cfg)`` (the config
+    keys the weights depend on; leaf -> (shape, kind), kind one of
+    ``weights._leaf``'s), ``model(cfg)``, ``op_inputs(cfg, batch, seed)``,
+    ``layer0(tree)``, ``program_ops(engine, inputs)``,
+    ``reference_ops(cfg, layer, inputs, precision)`` and
+    ``forward(cfg, weights, images, precision)`` (the reference's output
+    per image of a block of images)."""
+    return _module(BENCH / "families" / f"{name}.py")
 
 
 def peaks(device_kind: str) -> dict:

@@ -1,4 +1,6 @@
-"""Plain reference of the served DeiT: the MXInt datapath in jax.numpy.
+"""Plain reference of the MXInt datapath in jax.numpy: the primitives
+every family's reference forward (``bench/families/<family>.py``) is
+built from.
 
 This is what ``correct`` compares the program with.  It imports nothing
 of the program and takes nothing the program made: the weights come from
@@ -20,10 +22,6 @@ For the paper's W6A8 configurations:
   exponential as 2^n times a 4-entry table of 2^r, division by the
   normalised sum, MXInt8 output;
 * attention: scores and the probability-weighted values in float32.
-
-Departures from the published DeiT, as the program runs it: the q/k/v
-and output projections carry no bias, and the patch features are laid
-out channel-major (the program's ``patchify``).
 
 ``precision`` is ``"highest"`` for the reference and ``"bfloat16"`` for
 the control: every matrix product then rounds its operands to bfloat16
@@ -200,44 +198,3 @@ def attention(q, k, v, dp: Datapath, precision: str):
     p = softmax(s, dp)
     return matmul("bhsS,bShd->bshd", p, v, precision)
 
-
-def patchify(images, patch: int):
-    """(b, H, W, 3) -> (b, patches, 3*patch*patch), channel-major."""
-    b, h, w, c = images.shape
-    x = images.reshape(b, h // patch, patch, w // patch, patch, c)
-    x = x.transpose(0, 1, 3, 5, 2, 4)
-    return x.reshape(b, (h // patch) * (w // patch), c * patch * patch)
-
-
-def block(x, bp, heads: int, dp: Datapath, precision: str):
-    """One pre-LayerNorm encoder block; ``bp`` holds one layer's leaves."""
-    b, s, d = x.shape
-    hd = d // heads
-    a = bp["attn"]
-    h = layernorm(x, bp["ln1_g"], bp["ln1_b"], dp)
-    q, k, v = (linear(h, a[n], None, dp, precision).reshape(b, s, heads, hd)
-               for n in ("wq", "wk", "wv"))
-    o = attention(q, k, v, dp, precision).reshape(b, s, d)
-    x = x + linear(o, a["wo"], None, dp, precision)
-    f = bp["ffn"]
-    h = layernorm(x, bp["ln2_g"], bp["ln2_b"], dp)
-    h = gelu(linear(h, f["wi"], f["bi"], dp, precision), dp)
-    return x + linear(h, f["wo"], f["bo"], dp, precision)
-
-
-@functools.partial(jax.jit,
-                   static_argnames=("heads", "patch", "dp", "precision"))
-def logits(params, images, *, heads: int, patch: int, dp: Datapath,
-           precision: str):
-    """Class logits of a batch of images, layer after layer."""
-    x = linear(patchify(images.astype(jnp.float32), patch),
-               params["patch_proj"], params["patch_bias"], dp, precision)
-    cls = jnp.broadcast_to(params["cls_token"], (x.shape[0], 1, x.shape[-1]))
-    x = jnp.concatenate([cls, x], axis=1) + params["pos_embed"][None]
-
-    def step(x, bp):
-        return block(x, bp, heads, dp, precision), None
-
-    x, _ = jax.lax.scan(step, x, params["blocks"])
-    x = layernorm(x, params["final_ln_g"], params["final_ln_b"], dp)
-    return linear(x[:, 0], params["head"], params["head_b"], dp, precision)

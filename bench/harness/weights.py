@@ -1,12 +1,12 @@
-"""Seeded DeiT weights, made on the device in one jitted call.
+"""Seeded weights of a configuration, made on the device in one jitted call.
 
-The tree uses the program's leaf names and shapes (``ViT.init``), with
-plain float32 arrays as leaves, so the reference reads it directly and
-``to_program`` only wraps each leaf in the program's ``Param``.  Values
-are drawn the way a trained DeiT's are scaled: matrices N(0, 1/fan_in),
-LayerNorm scales 1 + N(0, 0.1^2), shifts, biases, the class token and
-the position table N(0, 0.02^2) or N(0, 0.1^2).  Nonzero shifts and
-biases make every term of the datapath do work.
+The tree is the family's ``shapes(cfg)`` (``bench/families/<family>.py``):
+the program's leaf names and shapes, with plain float32 arrays as leaves,
+so the reference reads it directly and ``to_program`` only wraps each
+leaf in the program's ``Param``.  Values are drawn by each leaf's kind,
+the way a trained model's are scaled: matrices N(0, 1/fan_in), scales
+1 + N(0, 0.1^2), shifts N(0, 0.1^2), biases and embeddings N(0, 0.02^2).
+Nonzero shifts and biases make every term of the datapath do work.
 """
 from __future__ import annotations
 
@@ -16,33 +16,13 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from bench.harness import manifest
+
 
 def jax_key(seed: int):
     """A JAX key from any non-negative integer seed (wider than 32 bits
     too): the seed goes through numpy's SeedSequence."""
     return jax.random.key(int(np.random.SeedSequence(seed).generate_state(1)[0]))
-
-
-def shapes(cfg: dict) -> dict:
-    """Leaf name -> (shape, kind) of a DeiT with the config's sizes."""
-    d, ff, L = cfg["hidden_size"], cfg["intermediate_size"], cfg["num_hidden_layers"]
-    p, c = cfg["patch_size"], cfg["num_labels"]
-    tokens = (cfg["image_size"] // p) ** 2 + 1
-    return {
-        "patch_proj": ((3 * p * p, d), "matrix"),
-        "patch_bias": ((d,), "bias"),
-        "cls_token": ((1, 1, d), "bias"),
-        "pos_embed": ((tokens, d), "bias"),
-        "blocks": {
-            "ln1_g": ((L, d), "scale"), "ln1_b": ((L, d), "shift"),
-            "attn": {n: ((L, d, d), "matrix") for n in ("wq", "wk", "wv", "wo")},
-            "ln2_g": ((L, d), "scale"), "ln2_b": ((L, d), "shift"),
-            "ffn": {"wi": ((L, d, ff), "matrix"), "bi": ((L, ff), "bias"),
-                    "wo": ((L, ff, d), "matrix"), "bo": ((L, d), "bias")},
-        },
-        "final_ln_g": ((d,), "scale"), "final_ln_b": ((d,), "shift"),
-        "head": ((d, c), "matrix"), "head_b": ((c,), "bias"),
-    }
 
 
 def _leaf(key, shape, kind):
@@ -56,13 +36,9 @@ def _leaf(key, shape, kind):
     return 0.02 * z
 
 
-DIMS = ("hidden_size", "intermediate_size", "num_hidden_layers",
-        "patch_size", "image_size", "num_labels")
-
-
-@functools.partial(jax.jit, static_argnames="dims")
-def _make(key, dims):
-    spec = shapes(dict(dims))
+@functools.partial(jax.jit, static_argnames=("family", "dims"))
+def _make(key, family, dims):
+    spec = manifest.family(family).shapes(dict(dims))
     leaves, tree = jax.tree_util.tree_flatten(
         spec, is_leaf=lambda x: isinstance(x, tuple) and isinstance(x[1], str))
     keys = jax.random.split(key, len(leaves))
@@ -72,7 +48,9 @@ def _make(key, dims):
 
 def make(cfg: dict, seed: int) -> dict:
     """The weight tree of ``cfg`` for ``seed``, on the default device."""
-    return _make(jax_key(seed), dims=tuple((k, cfg[k]) for k in DIMS))
+    family = cfg["family"]
+    return _make(jax_key(seed), family=family, dims=tuple(
+        (k, cfg[k]) for k in manifest.family(family).DIMS))
 
 
 def to_program(tree: dict, model) -> dict:

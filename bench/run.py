@@ -1,5 +1,5 @@
-"""Chip benchmark of MXInt DeiT classification served through
-``ClassifyScheduler`` on a TPU.
+"""Chip benchmark of MXInt ViTs served through ``ClassifyScheduler`` on a
+TPU.
 
     python3 bench/run.py --workload deit_base.bulk --seed 7 --seconds 20 --trace 0
 
@@ -77,26 +77,27 @@ def read_metrics(specs, run) -> dict:
 
 
 def judge(s, run) -> tuple:
-    """Compare what the window served, and each op of a block, with the
-    reference.  Frees the program's state first.  Returns (numbers,
-    per-op numbers)."""
+    """Compare what the window served, and each op case of the family,
+    with the reference.  Frees the program's state first.  Returns
+    (numbers, per-op numbers)."""
     import numpy as np
-    from bench.harness import check, program
+    from bench.harness import check, manifest
 
     cfg, wl = s.cell.config, s.cell.workload
+    fam = manifest.family(cfg["family"])
     reqs = check.sample(run.requests[:run.in_window],
                         np.random.default_rng([s.seed, 4]),
                         wl["sample_images"])
-    inputs = check.op_inputs(cfg, s.batch, s.seed)
-    got = program.ops(s.engine, inputs)
+    inputs = fam.op_inputs(cfg, s.batch, s.seed)
+    got = fam.program_ops(s.engine, inputs)
     s.engine = s.sched = None
     gc.collect()
-    per_op = check.ops_numbers(got, check.reference_ops(
-        cfg, check.layer0(s.weights), inputs, "highest"))
+    per_op = check.ops_numbers(got, fam.reference_ops(
+        cfg, fam.layer0(s.weights), inputs, "highest"))
     numbers = {"ops_rel_rms": max(per_op.values())}
     if reqs:
-        numbers["logits_rel_rms"] = check.logits_number(cfg, s.weights,
-                                                        s.pool, reqs)
+        numbers[fam.OUTPUT] = check.output_number(cfg, s.weights, s.pool,
+                                                  reqs)
     return numbers, per_op
 
 

@@ -24,7 +24,9 @@ sys.path[:0] = [str(Path(__file__).resolve().parent),
 
 import control  # noqa: E402
 import tiny  # noqa: E402
-from bench.harness import check, program, weights as W  # noqa: E402
+from bench.harness import check, manifest, program, weights as W  # noqa: E402
+
+VIT = manifest.family("vit")
 
 SEED = 2 ** 35 + 11
 
@@ -51,26 +53,26 @@ def test_reference_is_the_program_datapath(tree, datapath):
 
     cfg = dict(tiny.CONFIG, datapath=DATAPATHS[datapath]
                or tiny.CONFIG["datapath"])
-    kernel = program.model(cfg)
+    kernel = VIT.model(cfg)
     sim = build_model(dataclasses.replace(
         kernel.cfg, quant=dataclasses.replace(kernel.cfg.quant, mode="sim")))
     images = np.random.default_rng(1).standard_normal(
         (5, 32, 32, 3)).astype(np.float32)
     with jax.default_matmul_precision("highest"):
         want = np.asarray(jax.jit(sim.logits)(W.to_program(tree, sim), images))
-    got = check.reference_logits(cfg, tree, images, "highest")
+    got = check.reference_output(cfg, tree, images, "highest")
     np.testing.assert_array_equal(got, want)
 
 
 def test_control_fails_the_op_limit(tree):
     limits = {"ops_rel_rms": tiny.cell("bulk").workload["limits"]["ops_rel_rms"]}
     engine, _ = program.scheduler(tiny.CONFIG, tree, 8)
-    layer = check.layer0(tree)
-    x = check.op_inputs(tiny.CONFIG, 8, SEED)
-    want = check.reference_ops(tiny.CONFIG, layer, x, "highest")
+    layer = VIT.layer0(tree)
+    x = VIT.op_inputs(tiny.CONFIG, 8, SEED)
+    want = VIT.reference_ops(tiny.CONFIG, layer, x, "highest")
     ctl = check.ops_numbers(
-        check.reference_ops(tiny.CONFIG, layer, x, "bfloat16"), want)
-    program_ops = check.ops_numbers(program.ops(engine, x), want)
+        VIT.reference_ops(tiny.CONFIG, layer, x, "bfloat16"), want)
+    program_ops = check.ops_numbers(VIT.program_ops(engine, x), want)
     assert not check.verdict({"ops_rel_rms": max(ctl.values())}, limits)[0]
     assert check.verdict({"ops_rel_rms": max(program_ops.values())},
                          limits)[0]
@@ -94,4 +96,7 @@ def test_broken_timed_path_is_not_correct(monkeypatch, fault):
         _break(monkeypatch, control.FAULTS[fault])
     res = tiny.execute(cell, seconds=1.0)
     assert res["correct"] is (fault is None), res["checks"]
+    if fault is not None:
+        out = res["checks"][VIT.OUTPUT]
+        assert out["value"] > out["limit"], res["checks"]
     assert list(res)[-1] == "checks" and res["attempted"] > 0

@@ -157,7 +157,8 @@ def test_every_cell_has_its_files_and_metrics():
         assert cell.per_layer
         for m in cell.end_to_end + cell.per_layer:
             assert callable(manifest.metric_reader(m["name"]))
-        assert set(cell.workload["limits"]) == {"logits_rel_rms", "ops_rel_rms"}
+        output = manifest.family(cell.config["family"]).OUTPUT
+        assert set(cell.workload["limits"]) == {output, "ops_rel_rms"}
 
 
 def test_peaks_are_keyed_by_device_kind():

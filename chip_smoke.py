@@ -242,6 +242,7 @@ def one_chip_phase(cfg, seed: int, *, batch: int = BATCH,
     on_chip = ops.on_tpu()
     ops.reset_attention_fallbacks()
     ops.reset_linear_tiling()
+    ops.reset_attention_tiling()
     m_ker, m_sim, m_off, params = build_models(cfg, seed)
     images = make_images(sum(sizes), cfg.image_size, seed + 1)
     engine = make_engine(m_ker, params, batch)
@@ -255,6 +256,7 @@ def one_chip_phase(cfg, seed: int, *, batch: int = BATCH,
     # counted while the classify program was traced: one count per
     # linear call site (the scanned block body once, not per layer)
     report["linear_tiling"] = ops.linear_tiling()
+    report["attention_tiling"] = ops.attention_tiling()
     if on_chip:
         report["pallas_kernels"] = count_kernels(engine, batch)
         if report["pallas_kernels"] != EXPECTED_KERNELS:

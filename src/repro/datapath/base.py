@@ -164,16 +164,24 @@ class Datapath:
         return s * kv_len <= 512 * 512
 
     def attention(self, qv, k, v, *, q, positions, causal: bool,
-                  window: int, scale: float, chunk: int):
+                  window: int, scale: float, chunk: int, bias=None):
         """Cache-less attention core.  qv: (b, s, kv, g, hd);
-        k/v: (b, S, kv, hd).  Returns (b, s, kv, g, hd)."""
+        k/v: (b, S, kv, hd).  Returns (b, s, kv, g, hd).
+
+        ``bias``: optional decomposed score bias (bias_h, bias_w), shapes
+        (b, kv, g, s, kh) and (b, kv, g, s, kw) with kh * kw == S, added
+        to the scaled scores before masking and softmax
+        (``attention.add_score_bias``); only the direct path takes it."""
         from repro.models import attention as A
         s = qv.shape[1]
         kv_len = k.shape[1]
         if self._attention_use_direct(q, s, kv_len):
             mask = A.positions_mask(positions, s, kv_len, causal, window)
             return A._direct_attention(qv, k, v, mask[:, None, None], q,
-                                       scale)
+                                       scale, bias)
+        if bias is not None:
+            raise ValueError(f"a score bias needs whole score rows; "
+                             f"{self.name} chunks {s} x {kv_len}")
         # per-row positions thread into the q-block masks: a left-padded
         # batch long enough to overflow the direct threshold must mask
         # exactly like ``positions_mask`` (ISSUE 6 ragged-chunked fix)

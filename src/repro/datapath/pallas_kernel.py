@@ -153,9 +153,10 @@ class PallasKernelDatapath(Datapath):
 
     # -- attention -----------------------------------------------------------
     def attention(self, qv, k, v, *, q, positions, causal: bool,
-                  window: int, scale: float, chunk: int):
+                  window: int, scale: float, chunk: int, bias=None):
         b, s, kvh, g, hd = qv.shape
         S = k.shape[1]
+        from repro.kernels import ops as kops
         if self.nl_on(q, "softmax") and s * S <= 512 * 512:
             # whole-row paper softmax (the ViT / encoder path): the sim
             # oracle's own masking and contractions around the Eq. 14-20
@@ -163,26 +164,30 @@ class PallasKernelDatapath(Datapath):
             # agree bit for bit.  The contractions take f32 operands off
             # any quantization grid; full precision keeps them f32 on the
             # TPU, whose default would round them to bf16.
+            kops.count_whole_row_attention(b * kvh * g * s, S, hd)
             with jax.default_matmul_precision("highest"):
                 return super().attention(
                     qv, k, v, q=q, positions=positions, causal=causal,
-                    window=window, scale=scale, chunk=chunk)
+                    window=window, scale=scale, chunk=chunk, bias=bias)
         # heads-major layout into the flash kernel: the blocked mxint
         # datapath for long sequences (no O(S^2) score matrix —
-        # DESIGN.md §11), float flash otherwise
-        from repro.kernels import ops as kops
+        # DESIGN.md §11), float flash otherwise; a score bias goes in as
+        # its (b, heads, s, kh|kw) rows and is spread in-kernel
         qh = jnp.einsum("bskgd->bkgsd", qv).reshape(b, kvh * g, s, hd)
         kh = jnp.einsum("bSkd->bkSd", k)          # (b, kvh, S, hd), no copy
         vh = jnp.einsum("bSkd->bkSd", v)
+        if bias is not None:
+            bias = tuple(x.reshape(b, kvh * g, s, x.shape[-1]) for x in bias)
         if self.nl_on(q, "softmax"):
             o = kops.attention_op(
-                qh, kh, vh, causal=causal, window=window, exp_mode="mxint",
-                quantize_scores=True, act_block=q.act_fmt.block_size,
+                qh, kh, vh, bias, causal=causal, window=window,
+                exp_mode="mxint", quantize_scores=True,
+                act_block=q.act_fmt.block_size,
                 mant_bits=q.act_fmt.mant_bits,
                 r_bits=q.nonlinear.softmax_r_bits)
         else:
-            o = kops.attention_op(qh, kh, vh, causal=causal, window=window,
-                                  exp_mode="float")
+            o = kops.attention_op(qh, kh, vh, bias, causal=causal,
+                                  window=window, exp_mode="float")
         return jnp.einsum("bkgsd->bskgd", o.reshape(b, kvh, g, s, hd))
 
     def attention_decode(self, qv, ck, cv, valid, *, q, scale: float):

@@ -32,6 +32,18 @@ row max, the Eq. 19 sum and the accumulator — unlike model-masked lanes,
 which are filled with ``NEG_INF`` BEFORE quantization exactly as the
 whole-row 'sim' datapath fills them.
 
+``bias`` adds a decomposed 2-D relative-position term to the scaled
+scores, before the Eq. 2-3 score quantization (the Segment Anything
+image encoder's global attention): keys lie on a (kh, kw) grid, key
+``c`` at row ``c // kw`` and column ``c % kw``, and
+
+  s[q, c] = scale * q.k_c + bias_h[q, c // kw] + bias_w[q, c % kw].
+
+The (bq, kh) and (bq, kw) bias tiles are spread over each (bq, bk) score
+tile by exact one-hot contractions, so no (Sq, Sk) bias ever exists.
+The score, bias and p @ V contractions run at full float32 precision
+(``Precision.HIGHEST``), as the whole-row path runs its einsums.
+
 ``flash_attention_decode`` is the single-query variant: one query position
 per KV head (the G query heads of a GQA group folded into sublane rows),
 K/V streamed from the cache ring in k blocks, slot validity supplied as an
@@ -67,6 +79,7 @@ _NEG_INF = NEG_INF
 # tile's row-max exponent above typical score exponents (~2^-6) and
 # re-floor the real mantissas, breaking whole-row parity.
 _PAD_FILL = 2.0 ** -100
+_HIGHEST = jax.lax.Precision.HIGHEST
 
 
 def _softmax_block_update(s, mask, pad_mask, v, write, m_sc, l_sc, acc_sc,
@@ -132,7 +145,7 @@ def _softmax_block_update(s, mask, pad_mask, v, write, m_sc, l_sc, acc_sc,
             pq = jnp.where(live, pq, 0.0)
             l_sc[...] = l_sc[...] * alpha + psum
             acc_sc[...] = acc_sc[...] * alpha + jax.lax.dot_general(
-                pq, v, (((1,), (0,)), ((), ())),
+                pq, v, (((1,), (0,)), ((), ())), precision=_HIGHEST,
                 preferred_element_type=jnp.float32)
             m_sc[...] = m_new
 
@@ -146,13 +159,13 @@ def _softmax_block_update(s, mask, pad_mask, v, write, m_sc, l_sc, acc_sc,
             yq = requantize_to_grid(y, act_block, mant_bits)
             yq = jnp.where(live, yq, 0.0)
             o = (acc_sc[...] * alpha) / l_m * inv_e + jax.lax.dot_general(
-                yq, v, (((1,), (0,)), ((), ())),
+                yq, v, (((1,), (0,)), ((), ())), precision=_HIGHEST,
                 preferred_element_type=jnp.float32)
             write(o)
     else:
         l_sc[...] = l_sc[...] * alpha + psum
         acc_sc[...] = acc_sc[...] * alpha + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())),
+            p, v, (((1,), (0,)), ((), ())), precision=_HIGHEST,
             preferred_element_type=jnp.float32)
         m_sc[...] = m_new
 
@@ -165,11 +178,29 @@ def _softmax_block_update(s, mask, pad_mask, v, write, m_sc, l_sc, acc_sc,
             write(o)
 
 
-def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_sc, l_sc, acc_sc, *,
-                  lut: tuple, scale: float, causal: bool, window: int,
-                  kv_len: int | None, exp_mode: str, r_bits: int,
-                  quantize_scores: bool, act_block: int, mant_bits: int,
-                  block_q: int, block_k: int, n_k: int):
+def _bias_tile(rel, k0, block_k: int, key_index):
+    """(bq, n) bias rows -> (bq, block_k) over keys k0..k0+block_k: key
+    ``c`` takes column ``key_index(c)`` of ``rel``.  An exact one-hot
+    contraction (one nonzero product per output)."""
+    n = rel.shape[1]
+    c = k0 + jax.lax.broadcasted_iota(jnp.int32, (n, block_k), 1)
+    col = jax.lax.broadcasted_iota(jnp.int32, (n, block_k), 0)
+    onehot = (key_index(c) == col).astype(jnp.float32)
+    return jax.lax.dot_general(rel, onehot, (((1,), (0,)), ((), ())),
+                               precision=_HIGHEST,
+                               preferred_element_type=jnp.float32)
+
+
+def _flash_kernel(*refs, lut: tuple, scale: float, causal: bool,
+                  window: int, kv_len: int | None, exp_mode: str,
+                  r_bits: int, quantize_scores: bool, act_block: int,
+                  mant_bits: int, block_q: int, block_k: int, n_k: int,
+                  bias_w: int):
+    if bias_w:
+        (q_ref, k_ref, v_ref, bh_ref, bw_ref, o_ref, m_sc, l_sc,
+         acc_sc) = refs
+    else:
+        q_ref, k_ref, v_ref, o_ref, m_sc, l_sc, acc_sc = refs
     kb = pl.program_id(2)
 
     @pl.when(kb == 0)
@@ -183,7 +214,15 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_sc, l_sc, acc_sc, *,
     v = v_ref[0].astype(jnp.float32)                       # (bk, d)
 
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                            precision=_HIGHEST,
                             preferred_element_type=jnp.float32) * scale
+    if bias_w:
+        # (s + bias_h) + bias_w, the order the whole-row path adds them
+        k0 = kb * block_k
+        s = s + _bias_tile(bh_ref[0].astype(jnp.float32), k0, block_k,
+                           lambda c: c // bias_w)
+        s = s + _bias_tile(bw_ref[0].astype(jnp.float32), k0, block_k,
+                           lambda c: c % bias_w)
 
     q_pos = pl.program_id(1) * block_q + jax.lax.broadcasted_iota(
         jnp.int32, s.shape, 0)
@@ -208,15 +247,16 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_sc, l_sc, acc_sc, *,
 @functools.partial(jax.jit, static_argnames=(
     "causal", "window", "exp_mode", "r_bits", "quantize_scores", "act_block",
     "mant_bits", "block_q", "block_k", "scale", "kv_len", "kv_groups",
-    "interpret"))
-def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
+    "vmem_limit_bytes", "interpret"))
+def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
+                    bias: tuple | None = None, *,
                     causal: bool = True, window: int = 0,
                     exp_mode: str = "float", r_bits: int = 2,
                     quantize_scores: bool = False, act_block: int = 16,
                     mant_bits: int = 8,
                     block_q: int = 128, block_k: int = 128,
                     scale: float | None = None, kv_len: int | None = None,
-                    kv_groups: int = 1,
+                    kv_groups: int = 1, vmem_limit_bytes: int | None = None,
                     interpret: bool = True) -> jnp.ndarray:
     """q: (BH, Sq, D); k, v: (BH // kv_groups, Sk, D).  Returns (BH, Sq, D).
 
@@ -226,6 +266,10 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
     ``exp_mode='mxint'``.  ``kv_groups``: GQA — query head b attends KV
     head b // kv_groups via the BlockSpec index map (q heads must be laid
     out KV-major), so grouped K/V are NEVER broadcast-copied.
+    ``bias``: optional (bias_h (BH, Sq, kh), bias_w (BH, Sq, kw)), the
+    decomposed relative-position term over a (kh, kw) key grid (see
+    module doc).  ``vmem_limit_bytes``: Mosaic's scoped VMEM limit, for
+    tiles past its default.
     """
     bh, sq, d = q.shape
     bhkv, sk, _ = k.shape
@@ -240,24 +284,34 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
         scale = 1.0 / (d ** 0.5)
     n_k = sk // block_k
 
+    in_specs = [
+        pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
+        pl.BlockSpec((1, block_k, d), lambda b, i, j: (b // kv_groups, j, 0)),
+        pl.BlockSpec((1, block_k, d), lambda b, i, j: (b // kv_groups, j, 0)),
+    ]
+    operands = [q, k, v]
+    bias_w = 0
+    if bias is not None:
+        bias_h, bias_w_rows = bias
+        assert bias_h.shape[:2] == bias_w_rows.shape[:2] == (bh, sq)
+        bias_w = bias_w_rows.shape[2]
+        for rel in bias:
+            in_specs.append(pl.BlockSpec((1, block_q, rel.shape[2]),
+                                         lambda b, i, j: (b, i, 0)))
+            operands.append(rel)
+
     kernel = functools.partial(
         _flash_kernel, lut=luts.pow2_table(r_bits), scale=scale,
         causal=causal, window=window,
         kv_len=kv_len if (kv_len is not None and kv_len < sk) else None,
         exp_mode=exp_mode, r_bits=r_bits, quantize_scores=quantize_scores,
         act_block=act_block, mant_bits=mant_bits, block_q=block_q,
-        block_k=block_k, n_k=n_k)
+        block_k=block_k, n_k=n_k, bias_w=bias_w)
 
     return pl.pallas_call(
         kernel,
         grid=(bh, sq // block_q, n_k),
-        in_specs=[
-            pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_k, d),
-                         lambda b, i, j: (b // kv_groups, j, 0)),
-            pl.BlockSpec((1, block_k, d),
-                         lambda b, i, j: (b // kv_groups, j, 0)),
-        ],
+        in_specs=in_specs,
         out_specs=pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         scratch_shapes=[
@@ -269,9 +323,11 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
         # carries the online-softmax (m, l, acc) scratch sequentially
         # (DESIGN.md §14).
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=vmem_limit_bytes),
+        name="mxint_flash_attention" if quantize_scores else None,
         interpret=interpret,
-    )(q, k, v)
+    )(*operands)
 
 
 # ---------------------------------------------------------------------------

@@ -419,7 +419,69 @@ def mxint_gelu_op(x: jnp.ndarray, *, fn: str = "gelu", act_block: int = 16,
     return y[:rows].reshape(x.shape)
 
 
-def attention_op(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
+# Attention tiling.  The quantized flash datapath keeps a whole score row
+# in one k block up to _FLASH_ROW_KEYS keys: one block degenerates to the
+# whole-row datapath exactly (DESIGN.md §11), where several blocks match
+# it only within LUT granularity.  At 4,096 keys with the relative-
+# position bias, compiles for a described v5e put the kernel's scoped
+# VMEM within Mosaic's 16 MiB default at block_q 128, 16.8 MiB at 256 and
+# 25.7 MiB at 512; whole rows take up to 256 query rows under a 32 MiB
+# limit (v5e has 128 MiB); tests/test_v5e_compile.py compiles that case.
+_FLASH_ROW_KEYS = 4096
+_FLASH_ROW_BLOCK_Q = 256
+_FLASH_ROW_VMEM = 32 * 1024 * 1024
+_ATTN_PREFIX = "kernels/attention/"
+
+
+def _count_attention(path: str, block_q: int, block_k: int, k_blocks: int,
+                     head_dim: int, keys_padded: int) -> None:
+    """Count one attention call by its tiling in ``repro.telemetry``, at
+    trace time."""
+    from repro import telemetry as T
+    T.counter(f"{_ATTN_PREFIX}{path}/{block_q}/{block_k}/{k_blocks}/"
+              f"{head_dim}/{keys_padded}").inc()
+
+
+def count_whole_row_attention(rows: int, keys: int, head_dim: int) -> None:
+    """Count a whole-row attention call: the softmax kernel's row block
+    over ``rows`` score rows of ``keys`` keys, contractions in XLA at the
+    unpadded head dim."""
+    _count_attention("whole_row", _pick_block_rows(_ceil_to(rows, 8)),
+                     keys, 1, head_dim, 0)
+
+
+def attention_tiling() -> list:
+    """The attention calls traced since the last reset, one dict per
+    tiling: ``path`` ('whole_row': score einsums around the softmax
+    kernel; 'flash': the blocked kernel), ``block_q``, ``block_k``,
+    ``k_blocks`` per row, ``head_dim`` as padded, ``keys_padded``, and
+    ``calls``."""
+    from repro import telemetry as T
+    out = []
+    for key, n in sorted(T.default_registry().counters_with_prefix(
+            _ATTN_PREFIX).items()):
+        path, *nums = key.split("/")
+        out.append(dict(zip(("block_q", "block_k", "k_blocks", "head_dim",
+                             "keys_padded"), map(int, nums)),
+                        path=path, calls=n))
+    return out
+
+
+def reset_attention_tiling() -> None:
+    from repro import telemetry as T
+    T.reset(_ATTN_PREFIX)
+
+
+def flash_tiling(sq: int, sk: int, quantize_scores: bool) -> tuple:
+    """(block_q, block_k, vmem_limit_bytes) of the flash kernel over
+    ``sq`` query rows and ``sk`` keys, both already padded (8, 128)."""
+    if quantize_scores and sk <= _FLASH_ROW_KEYS:
+        return _pick_block_rows(sq, _FLASH_ROW_BLOCK_Q), sk, _FLASH_ROW_VMEM
+    return _pick_block_rows(sq, 128), min(128, sk), None
+
+
+def attention_op(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
+                 bias: tuple | None = None, *,
                  causal: bool = True, window: int = 0,
                  exp_mode: str = "float", r_bits: int = 2,
                  quantize_scores: bool = False,
@@ -451,6 +513,11 @@ def attention_op(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
     copied per query head: the flash kernel maps query head b to KV head
     b // groups in its BlockSpec index map (``kv_groups``); only the
     pathological-head-dim oracle fallback broadcasts.
+
+    ``bias``: optional (bias_h (B, H, S, kh), bias_w (B, H, S, kw)) with
+    kh * kw keys, the decomposed relative-position term added to the
+    scaled scores in-kernel (``flash_attention``).  Tiling:
+    ``flash_tiling``, counted per call (``attention_tiling``).
     """
     b, h, sq, d = q.shape
     hkv = k.shape[1]
@@ -461,7 +528,13 @@ def attention_op(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
     kf = k.reshape(b * hkv, sk, d)
     vf = v.reshape(b * hkv, sk, d)
     d_p = _ceil_to(d, 128)
+    if bias is not None:
+        bias = tuple(x.reshape(b * h, sq, x.shape[-1]).astype(jnp.float32)
+                     for x in bias)
     if d_p > _FLASH_MAX_HEAD_DIM:
+        if bias is not None:
+            raise ValueError("a score bias needs the flash kernel; head dim "
+                             f"{d} pads past {_FLASH_MAX_HEAD_DIM}")
         _count_fallback("head_dim", f"d={d} pads to {d_p}")
         if groups > 1:                     # oracles want matched heads
             kf = jnp.broadcast_to(k[:, :, None], (b, hkv, groups, sk, d)
@@ -485,14 +558,17 @@ def attention_op(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
         qp = _pad_dim(_pad_dim(qf, 1, sq_p), 2, d_p)
         kp = _pad_dim(_pad_dim(kf, 1, sk_p), 2, d_p)
         vp = _pad_dim(_pad_dim(vf, 1, sk_p), 2, d_p)
-        o = flash_attention(qp, kp, vp, causal=causal, window=window,
+        if bias is not None:
+            bias = tuple(_pad_dim(x, 1, sq_p) for x in bias)
+        bq, bk, vmem = flash_tiling(sq_p, sk_p, quantize_scores)
+        _count_attention("flash", bq, bk, sk_p // bk, d_p, sk_p - sk)
+        o = flash_attention(qp, kp, vp, bias, causal=causal, window=window,
                             exp_mode=exp_mode, r_bits=r_bits,
                             quantize_scores=quantize_scores,
                             act_block=act_block, mant_bits=mant_bits,
-                            block_q=_pick_block_rows(sq_p, 128),
-                            block_k=min(128, sk_p), scale=scale,
+                            block_q=bq, block_k=bk, scale=scale,
                             kv_len=sk if sk != sk_p else None,
-                            kv_groups=groups,
+                            kv_groups=groups, vmem_limit_bytes=vmem,
                             interpret=_interpret())[:, :sq, :d]
     return o.reshape(b, h, sq, d)
 

@@ -137,8 +137,8 @@ def mxint_flash_attention_ref(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                               *, causal: bool = True, window: int = 0,
                               act_block: int = 16, mant_bits: int = 8,
                               r_bits: int = 2, scale: float | None = None,
-                              key_mask: jnp.ndarray | None = None
-                              ) -> jnp.ndarray:
+                              key_mask: jnp.ndarray | None = None,
+                              bias: tuple | None = None) -> jnp.ndarray:
     """Whole-row Eq. 14-20 attention oracle (DESIGN.md §11).
 
     The full paper softmax on MASKED score rows: Eq. 2-3 score
@@ -148,7 +148,9 @@ def mxint_flash_attention_ref(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     ``flash_attention(exp_mode='mxint', quantize_scores=True)`` computes
     blocked; when one k block covers the row the kernel matches this
     oracle exactly.  ``key_mask``: optional (Sk,) or per-row (BH, Sk)
-    validity (the decode variant's ring mask).
+    validity (the decode variant's ring mask).  ``bias``: optional
+    (bias_h (BH, sq, kh), bias_w (BH, sq, kw)), the decomposed relative-
+    position term added to the scaled scores (``flash_attention``).
     """
     bh, sq, d = q.shape
     sk = k.shape[1]
@@ -156,6 +158,11 @@ def mxint_flash_attention_ref(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
         scale = 1.0 / (d ** 0.5)
     s = jnp.einsum("bqd,bkd->bqk", q.astype(jnp.float32),
                    k.astype(jnp.float32)) * scale
+    if bias is not None:
+        bias_h, bias_w = bias
+        kh, kw = bias_h.shape[-1], bias_w.shape[-1]
+        s = s.reshape(bh, sq, kh, kw) + bias_h[..., :, None]
+        s = (s + bias_w[..., None, :]).reshape(bh, sq, sk)
     q_pos = jnp.arange(sq)[:, None]
     k_pos = jnp.arange(sk)[None, :]
     mask = jnp.ones((sq, sk), dtype=bool)

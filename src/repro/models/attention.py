@@ -43,13 +43,17 @@ import jax.numpy as jnp
 from repro.core.mx_types import NEG_INF as _NEG_INF
 from repro.core.mx_types import QuantConfig
 from repro.models import layers as L
-from repro.models.model_api import ModelConfig, Param, dense_init, ones_init
+from repro.models.model_api import (ModelConfig, Param, dense_init,
+                                    ones_init, zeros_init)
 
 
 # ---------------------------------------------------------------------------
 # params
 # ---------------------------------------------------------------------------
-def init_attn_params(key, cfg: ModelConfig, dtype, cross: bool = False):
+def init_attn_params(key, cfg: ModelConfig, dtype, cross: bool = False,
+                     bias: bool = False):
+    """q/k/v and output projections; ``bias`` adds their biases (bq, bk,
+    bv, bo), which ``models/sam.py`` applies (``attention`` does not)."""
     hd = cfg.hd
     ks = jax.random.split(key, 4)
     p = {
@@ -62,6 +66,13 @@ def init_attn_params(key, cfg: ModelConfig, dtype, cross: bool = False):
         "wo": dense_init(ks[3], (cfg.n_heads * hd, cfg.d_model),
                          ("q_heads", "embed"), dtype=dtype),
     }
+    if bias:
+        p["bq"] = zeros_init((cfg.n_heads * hd,), ("q_heads",), dtype=dtype)
+        p["bk"] = zeros_init((cfg.n_kv_heads * hd,), ("kv_heads",),
+                             dtype=dtype)
+        p["bv"] = zeros_init((cfg.n_kv_heads * hd,), ("kv_heads",),
+                             dtype=dtype)
+        p["bo"] = zeros_init((cfg.d_model,), ("embed",), dtype=dtype)
     if cfg.qk_norm and not cross:
         p["q_norm"] = ones_init((hd,), (None,), dtype=dtype)
         p["k_norm"] = ones_init((hd,), (None,), dtype=dtype)
@@ -105,9 +116,22 @@ def positions_mask(positions, s: int, kv_len: int, causal: bool,
     return mask
 
 
-def _direct_attention(q, k, v, mask, quant: QuantConfig, scale):
-    s = _gqa_scores(q, k, scale)
-    s = jnp.where(mask, s.astype(jnp.float32), _NEG_INF)
+def add_score_bias(s, bias):
+    """Scores (..., S) plus a decomposed bias over a (kh, kw) key grid:
+    ``s[..., c] + bias_h[..., c // kw] + bias_w[..., c % kw]``, added in
+    that order (the Segment Anything relative-position term)."""
+    bias_h, bias_w = bias
+    kh, kw = bias_h.shape[-1], bias_w.shape[-1]
+    s = s.reshape(*s.shape[:-1], kh, kw) + bias_h[..., :, None]
+    s = s + bias_w[..., None, :]
+    return s.reshape(*s.shape[:-2], kh * kw)
+
+
+def _direct_attention(q, k, v, mask, quant: QuantConfig, scale, bias=None):
+    s = _gqa_scores(q, k, scale).astype(jnp.float32)
+    if bias is not None:
+        s = add_score_bias(s, bias)
+    s = jnp.where(mask, s, _NEG_INF)
     p = L.softmax(s, quant, axis=-1).astype(q.dtype)
     p = jnp.where(mask, p, 0.0)
     return jnp.einsum("bkgsS,bSkd->bskgd", p, v)

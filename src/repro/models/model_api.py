@@ -74,7 +74,7 @@ class ModelConfig:
     """
 
     name: str = "model"
-    family: str = "dense"          # dense|moe|hybrid|ssm|vlm|audio|vit
+    family: str = "dense"          # dense|moe|hybrid|ssm|vlm|audio|vit|sam
     n_layers: int = 4
     d_model: int = 512
     n_heads: int = 8
@@ -109,6 +109,12 @@ class ModelConfig:
     patch_size: int = 16
     n_classes: int = 1000
     pool: str = "cls"
+    # sam (Segment Anything image encoder): window attention of
+    # attn_window_size^2 tokens, the last layer of every
+    # global_attn_period layers global, a neck of neck_dim channels
+    attn_window_size: int = 0
+    global_attn_period: int = 0
+    neck_dim: int = 0
     # numerics / runtime
     dtype: Any = jnp.bfloat16
     norm_eps: float = 1e-6

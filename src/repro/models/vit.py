@@ -36,6 +36,7 @@ class ViT:
         assert cfg.image_size % cfg.patch_size == 0
         self.n_patches = (cfg.image_size // cfg.patch_size) ** 2
         self.seq = self.n_patches + 1                     # + CLS
+        self.out_shape = (cfg.n_classes,)                 # per image
 
     # -- params -------------------------------------------------------------
     def init(self, rng) -> Dict[str, Any]:

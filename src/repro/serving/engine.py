@@ -315,7 +315,8 @@ class ServingEngine:
 # ViT classification engine (the paper's deployment scenario)
 # ---------------------------------------------------------------------------
 class ViTServingEngine:
-    """Batched image-classification serving for ViT/DeiT models.
+    """Batched image-classification serving for ViT/DeiT models, and for
+    any image model with a per-image output (the SAM image encoder).
 
     The token engines above are prefill/decode state machines; a classifier
     is a stateless batched forward, so this engine only needs weight packing
@@ -425,7 +426,9 @@ class ViTServingEngine:
         return int(fn()) if fn is not None else -1
 
     def logits_batch(self, chunk) -> jnp.ndarray:
-        """One jitted forward on a FIXED-shape (cfg.batch, H, W, 3) chunk.
+        """One jitted forward on a FIXED-shape (cfg.batch, H, W, 3) chunk:
+        the model's output per image, whatever its shape (class logits of
+        a ViT, the embedding of the SAM encoder).
 
         The single funnel into ``self._logits`` — both ``classify`` and
         ``ClassifyScheduler`` go through it with an identical argument
@@ -441,7 +444,9 @@ class ViTServingEngine:
             return self._logits(self.params, chunk)
 
     def classify(self, images: jnp.ndarray):
-        """(n, H, W, 3) images -> (labels (n,), logits (n, classes)).
+        """(n, H, W, 3) images -> (labels (n,), logits (n, classes)); for
+        a model whose output per image is not a vector (the SAM encoder's
+        embedding), (None, outputs (n, *out)).
 
         ``n`` is arbitrary: requests are served in fixed ``cfg.batch``
         chunks, the final partial chunk zero-padded (and the padding rows
@@ -463,12 +468,14 @@ class ViTServingEngine:
                 chunks.append(logits[:batch - pad] if pad else logits)
             logits = jnp.concatenate(chunks, axis=0)
         _note_recompiles(self)
+        if logits.ndim != 2:
+            return None, logits
         return jnp.argmax(logits, axis=-1), logits
 
 
 def make_engine(model, params, serve_cfg: ServeConfig, mesh=None):
     """Family-aware engine constructor.  ``mesh`` enables sharded serving
     for the ViT family (token engines are single-device for now)."""
-    if getattr(model.cfg, "family", None) == "vit":
+    if getattr(model.cfg, "family", None) in ("vit", "sam"):
         return ViTServingEngine(model, params, serve_cfg, mesh=mesh)
     return ServingEngine(model, params, serve_cfg)

@@ -255,9 +255,12 @@ class BatchScheduler:
 class ClassifyRequest:
     """One classification request of ``images.shape[0]`` images.
 
-    images: (n, H, W, 3) float; logits/labels: (n, classes)/(n,) numpy,
-    filled incrementally as the scheduler packs this request's images
-    into fixed-shape batches; done: set when all n are classified.
+    images: (n, H, W, 3) float; logits: (n, *out) numpy, the model's
+    output per image, whatever its shape (class logits (n, classes) for
+    a classifier, an embedding (n, g, g, c) for the SAM encoder); labels:
+    (n,) argmax labels, for a classifier only (None otherwise).  Both
+    are filled incrementally as the scheduler packs this request's
+    images into fixed-shape batches; done: set when all n are served.
     """
     uid: int
     images: np.ndarray
@@ -281,13 +284,18 @@ class ClassifyScheduler:
     or not), so mixed request sizes never recompile.
 
     engine: a ``ViTServingEngine``; batch_size defaults to the engine's
-    ``ServeConfig.batch``.
+    ``ServeConfig.batch``.  The per-image output shape is the model's
+    ``out_shape`` (``(n_classes,)`` for a model without one); a vector
+    output is a classifier's, and only then are labels taken.
     """
 
     def __init__(self, engine, batch_size: Optional[int] = None):
         self.engine = engine
         self.batch = batch_size or engine.cfg.batch
         self.n_classes = int(getattr(engine.model.cfg, "n_classes", 0))
+        self.out_shape = tuple(getattr(engine.model, "out_shape",
+                                       (self.n_classes,)))
+        self.classifier = len(self.out_shape) == 1
         self.queue: deque[ClassifyRequest] = deque()
         self.finished: List[ClassifyRequest] = []
         self.steps = 0                     # steps that classified images
@@ -321,8 +329,9 @@ class ClassifyScheduler:
                 self.queue[0].images.shape[0]:
             req = self.queue.popleft()
             if req.logits is None:             # zero-image request
-                req.logits = np.zeros((0, self.n_classes), np.float32)
-                req.labels = np.zeros((0,), np.int64)
+                req.logits = np.zeros((0,) + self.out_shape, np.float32)
+                if self.classifier:
+                    req.labels = np.zeros((0,), np.int64)
             req.done = True
             self.finished.append(req)
             T.counter("scheduler/completed").inc()
@@ -357,8 +366,9 @@ class ClassifyScheduler:
         return chunk, take
 
     def _deliver(self, logits: np.ndarray, take: List[tuple]):
-        """Scatter a step's logits and labels into its requests, publish
-        the step's counters, and evict the completed requests."""
+        """Scatter a step's outputs (and a classifier's labels) into its
+        requests, publish the step's counters, and evict the completed
+        requests."""
         # slot occupancy for a stateless batch = filled rows this step
         # (the rest of the fixed shape is zero padding)
         T.gauge("scheduler/slots_active").set(len(take))
@@ -367,10 +377,12 @@ class ClassifyScheduler:
         for j, (req, i) in enumerate(take):
             if req.logits is None:
                 n = req.images.shape[0]
-                req.logits = np.zeros((n, logits.shape[-1]), logits.dtype)
-                req.labels = np.zeros((n,), np.int64)
+                req.logits = np.zeros((n,) + logits.shape[1:], logits.dtype)
+                if self.classifier:
+                    req.labels = np.zeros((n,), np.int64)
             req.logits[i] = logits[j]
-            req.labels[i] = int(np.argmax(logits[j]))
+            if self.classifier:
+                req.labels[i] = int(np.argmax(logits[j]))
         self._evict_completed()
 
     def step(self) -> int:

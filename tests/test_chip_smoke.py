@@ -68,6 +68,15 @@ def test_linear_tiling_counted_once_per_call_site(tiny_phase):
     assert 0 <= tiling["rows_padded"] < tiling["rows"]
 
 
+def test_attention_tiling_counted_once(tiny_phase):
+    """The scanned block's attention, traced once: DeiT's short rows take
+    the whole-row path (score einsums around the softmax kernel)."""
+    report, _ = tiny_phase
+    (tiling,) = report["attention_tiling"]
+    assert tiling["path"] == "whole_row" and tiling["calls"] == 1
+    assert tiling["k_blocks"] == 1 and tiling["keys_padded"] == 0
+
+
 @pytest.mark.parametrize("level", ["logits", "op"])
 @pytest.mark.parametrize("where", ["max", "rms"])
 def test_reference_comparison_catches_perturbed_logit(where, level):

@@ -400,6 +400,49 @@ class _MeanPixelModel:
         return images.mean(axis=(1, 2)) @ params
 
 
+class _GridOutputModel:
+    """A stand-in image model whose output per image is a (2, 2, 3) grid
+    (each 2 x 2 quadrant's mean pixel), not a class vector: the shape of
+    an embedding model such as the SAM encoder."""
+    cfg = SimpleNamespace(n_classes=0)
+    out_shape = (2, 2, 3)
+
+    def logits(self, params, images):
+        b, h, w, c = images.shape
+        q = images.reshape(b, 2, h // 2, 2, w // 2, c)
+        return q.mean(axis=(2, 4)) * params
+
+
+class TestClassifySchedulerGridOutput:
+    """``ClassifyScheduler`` carries any per-image output: request outputs
+    keep the model's per-image shape, a zero-image request gets an empty
+    one of that shape, and no labels are taken from a non-vector output."""
+
+    @pytest.fixture(scope="class")
+    def engine(self):
+        return ViTServingEngine(_GridOutputModel(), jnp.float32(2.0),
+                                ServeConfig(batch=4))
+
+    def test_outputs_keep_their_shape(self, engine):
+        rng = np.random.default_rng(0)
+        sched = ClassifyScheduler(engine)
+        reqs = [ClassifyRequest(uid=i, images=rng.normal(
+            size=(n, 4, 4, 3)).astype(np.float32))
+            for i, n in enumerate((3, 0, 6))]
+        for r in reqs:
+            sched.submit(r)
+        done = sched.run()
+        assert [r.uid for r in done] == [0, 1, 2]
+        for r in done:
+            n = r.images.shape[0]
+            assert r.logits.shape == (n, 2, 2, 3)
+            assert r.labels is None
+            want = r.images.reshape(n, 2, 2, 2, 2, 3).mean(axis=(2, 4)) * 2
+            np.testing.assert_allclose(r.logits, want, rtol=1e-6)
+        labels, out = engine.classify(reqs[0].images)
+        assert labels is None and out.shape == (3, 2, 2, 3)
+
+
 class TestClassifySchedulerSpans:
     """The span tree of ``ClassifyScheduler.step`` and the request spans,
     through a real ``ViTServingEngine`` around a stand-in model."""

@@ -23,7 +23,8 @@ import json
 import sys
 from pathlib import Path
 
-sys.path.insert(0, str(Path(__file__).resolve().parent / "src"))
+ROOT = Path(__file__).resolve().parent
+sys.path[:0] = [str(ROOT / "src"), str(ROOT)]
 
 import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
@@ -227,6 +228,31 @@ def count_kernels(engine, batch: int) -> int:
     return text.count("tpu_custom_call")
 
 
+def benchmark_operand_paths(batch: int = BATCH) -> dict:
+    """Calls by MXU operand path ('bf16', 'f32', 'raw') of the linears in
+    the kernel-mode forward of each configuration of the benchmark
+    (``BENCHMARK.json``), counted while the forward is traced: abstract
+    shapes only, so nothing runs on the device."""
+    from bench.harness import manifest
+    from repro.kernels import ops
+    from repro.serving.engine import pack_params_mxint
+
+    paths = {}
+    for entry in manifest.benchmark()["configs"]:
+        cfg = manifest.load_json(ROOT / entry["file"])
+        model = manifest.family(cfg["family"]).model(cfg)
+        params = pack_params_mxint(
+            jax.eval_shape(model.init, jax.random.key(0)),
+            model.cfg.quant.weight_fmt, abstract=True)
+        size = model.cfg.image_size
+        ops.reset_linear_tiling()
+        jax.eval_shape(model.logits, params, jax.ShapeDtypeStruct(
+            (batch, size, size, 3), jnp.float32))
+        paths[entry["name"]] = ops.linear_tiling()["operand_calls"]
+    ops.reset_linear_tiling()
+    return paths
+
+
 def one_chip_phase(cfg, seed: int, *, batch: int = BATCH,
                    sizes=REQUEST_SIZES):
     """Serve mixed-size requests in kernel mode and check them.
@@ -256,6 +282,9 @@ def one_chip_phase(cfg, seed: int, *, batch: int = BATCH,
     # counted while the classify program was traced: one count per
     # linear call site (the scanned block body once, not per layer)
     report["linear_tiling"] = ops.linear_tiling()
+    if set(report["linear_tiling"]["operand_calls"]) != {"bf16"}:
+        failures.append(f"linears off the bf16 MXU operand: "
+                        f"{report['linear_tiling']['operand_calls']}")
     report["attention_tiling"] = ops.attention_tiling()
     if on_chip:
         report["pallas_kernels"] = count_kernels(engine, batch)
@@ -359,6 +388,10 @@ def main(argv=None) -> int:
         report, failures = four_chip_phase(DEIT_BASE, args.seed)
     else:
         report, failures = one_chip_phase(DEIT_BASE, args.seed)
+        report["benchmark_operand_paths"] = paths = benchmark_operand_paths()
+        failures += [f"{name}: linears off the bf16 MXU operand: {calls}"
+                     for name, calls in paths.items()
+                     if set(calls) != {"bf16"}]
     print(json.dumps({"smoke": report}, default=str))
     if failures:
         for f in failures:

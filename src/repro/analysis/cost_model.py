@@ -36,7 +36,6 @@ tiling change).
 """
 from __future__ import annotations
 
-import functools
 import itertools
 import json
 from pathlib import Path
@@ -47,7 +46,8 @@ import jax.numpy as jnp
 
 from repro.analysis.kernel_contracts import (DOUBLE_BUFFER, MAX_GRID_POINTS,
                                              BlockUse, PallasCapture,
-                                             _nbytes, capture_pallas_calls,
+                                             _nbytes, _partial_kwargs,
+                                             capture_pallas_calls,
                                              sweep_captures)
 from repro.analysis.registry import ERROR, WARN, Violation, register_rule
 
@@ -110,15 +110,6 @@ def operand_traffic(use: BlockUse,
 # ---------------------------------------------------------------------------
 # FLOPs (formulas: DESIGN.md §14)
 # ---------------------------------------------------------------------------
-def _partial_kwargs(cap: PallasCapture) -> Dict[str, object]:
-    kw: Dict[str, object] = {}
-    fn = cap.kernel_fn
-    while isinstance(fn, functools.partial):
-        kw.update(fn.keywords or {})
-        fn = fn.func
-    return kw
-
-
 def _steps(grid: Tuple[int, ...]) -> int:
     n = 1
     for g in grid:
@@ -126,21 +117,25 @@ def _steps(grid: Tuple[int, ...]) -> int:
     return n
 
 
-def _flops_matmul(cap, kw) -> int:
+def _flops_matmul_raw(cap, kw) -> int:
     bm, bk = cap.inputs[0].block_shape
     bn = cap.outputs[0].block_shape[-1]
-    per = 2 * bm * bk * bn + bk * bn          # dot + exponent scale
-    if kw.get("quantize_act"):
-        per += 6 * bm * bk                    # in-register act quantize
-    return _steps(cap.grid) * per
+    return _steps(cap.grid) * (2 * bm * bk * bn + bk * bn)  # dot + scale
+
+
+def _flops_matmul(cap, kw) -> int:
+    bm, bk = cap.inputs[0].block_shape
+    quantize = cap.grid[0] * 6 * bm * bk      # act quantize, j == 0 only
+    return _flops_matmul_raw(cap, kw) + quantize
 
 
 def _flops_ln_matmul(cap, kw) -> int:
     bm, d = cap.inputs[0].block_shape
     bn = cap.outputs[0].block_shape[-1]
     lut = 2 ** int(kw.get("lut_bits", 5))
-    dot = _steps(cap.grid) * 2 * bm * d * bn
-    ln = cap.grid[0] * (12 * bm * d + 2 * bm * lut)   # j == 0 only
+    dot = _steps(cap.grid) * (2 * bm * d * bn + d * bn)   # dot + scale
+    # LN and the act quantize into the operand scratch, j == 0 only
+    ln = cap.grid[0] * (12 * bm * d + 2 * bm * lut + 6 * bm * d)
     return dot + ln
 
 
@@ -174,6 +169,7 @@ def _flops_flash(cap, kw) -> int:
 
 FLOPS: Dict[str, Callable[[PallasCapture, Dict[str, object]], int]] = {
     "_mxint_matmul_kernel": _flops_matmul,
+    "_mxint_matmul_raw_kernel": _flops_matmul_raw,
     "_mxint_ln_matmul_kernel": _flops_ln_matmul,
     "_mxint_layernorm_kernel": _flops_layernorm,
     "_mxint_softmax_kernel": _flops_softmax,

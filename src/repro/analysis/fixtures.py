@@ -74,16 +74,16 @@ def uncovered_output_block() -> List[Violation]:
 
 
 def wrong_scratch_dtype() -> List[Violation]:
-    """A kernel posing as mxint_ln_matmul whose LN scratch is f32 while
-    the model dtype is bf16 — the model-dtype scratch contract."""
+    """A kernel posing as mxint_ln_matmul whose operand scratch is f32
+    though its 8-bit act mantissas are exact in bf16 — the operand-dtype
+    scratch contract."""
     from jax.experimental.pallas import tpu as pltpu
 
     def _mxint_ln_matmul_kernel(*refs):
         pass
 
     return check_captures(_capture_2d(
-        (128, 256), (128, 256), dtype=jnp.bfloat16,
-        kernel=_mxint_ln_matmul_kernel,
+        (128, 256), (128, 256), kernel=_mxint_ln_matmul_kernel,
         scratch=(pltpu.VMEM((128, 256), jnp.float32),)))
 
 

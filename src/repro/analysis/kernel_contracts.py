@@ -25,9 +25,10 @@ executes).  Four contracts are then verified per captured call:
    must be produced by at least one grid step (a constant out map over a
    tiled output silently leaves garbage blocks).
 4. **Scratch-dtype contracts** — per-kernel declarations
-   (:data:`SCRATCH_CONTRACTS`), e.g. ``mxint_ln_matmul`` keeps its
-   normalised tile in MODEL dtype scratch while the matmul accumulators
-   are always f32.
+   (:data:`SCRATCH_CONTRACTS`), e.g. the linear kernels keep one
+   ``(bm, K)`` activation operand in the dtype ``operand_path`` picks
+   from their static arguments (bf16 for act mantissas of at most 8
+   bits) while the flash accumulators are always f32.
 
 The built-in sweep (:func:`sweep_captures`) drives every kernel in
 ``repro/kernels/`` through the shapes ``benchmarks/kernel_bench.py``
@@ -36,6 +37,7 @@ uses plus the padded DeiT shapes the model path produces.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import itertools
 from pathlib import Path
 from typing import Callable, Dict, List, Optional, Sequence, Tuple
@@ -58,6 +60,7 @@ MAX_GRID_POINTS = 65536
 # cdiv(rows, bm)) computes rows past the array from whatever the block
 # holds there, and Pallas drops them on write-back
 RAGGED_ROW_KERNELS = frozenset({"_mxint_matmul_kernel",
+                                "_mxint_matmul_raw_kernel",
                                 "_mxint_ln_matmul_kernel"})
 
 
@@ -322,17 +325,41 @@ def _check_vmem(cap: PallasCapture, cap_bytes: int) -> List[Violation]:
     return []
 
 
-def _ln_matmul_scratch(cap: PallasCapture) -> List[str]:
-    """mxint_ln_matmul: scratch[0] holds the normalised x tile in the
-    MODEL dtype (DESIGN.md §12) — an f32-only scratch would silently
-    change the requantisation grid for bf16 models."""
-    if not cap.scratch:
-        return ["expected a (bm, d) model-dtype scratch, found none"]
-    want = cap.inputs[0].dtype
-    got = cap.scratch[0].dtype
-    if got != want:
-        return [f"LN scratch dtype {got} != model/x dtype {want}"]
-    return []
+def _partial_kwargs(cap: PallasCapture) -> Dict[str, object]:
+    """The keywords a captured kernel's ``functools.partial`` binds."""
+    kw: Dict[str, object] = {}
+    fn = cap.kernel_fn
+    while isinstance(fn, functools.partial):
+        kw.update(fn.keywords or {})
+        fn = fn.func
+    return kw
+
+
+def _operand_scratch(cap: PallasCapture) -> List[str]:
+    """mxint_matmul (quantized) and mxint_ln_matmul: scratch[0] holds the
+    row block's (bm, K) MXU operand in the dtype ``operand_path`` picks
+    from the act mantissa width and the weight plane's dtype (DESIGN.md
+    §2) — bf16 only where both sides are exact in it."""
+    from repro.kernels.mxint_matmul import OPERAND_DTYPES, operand_path
+    kw = _partial_kwargs(cap)
+    bits = int(kw.get("act_mant_bits", kw.get("mant_bits", 8)))
+    w_mant = next((u.dtype for u in cap.inputs
+                   if jnp.issubdtype(u.dtype, jnp.integer)), jnp.int8)
+    want = jnp.dtype(OPERAND_DTYPES[operand_path(True, bits, w_mant)])
+    if len(cap.scratch) != 1:
+        return [f"expected one (bm, K) {want} operand scratch, found "
+                f"{len(cap.scratch)}"]
+    shape = (cap.inputs[0].block_shape[0], cap.inputs[0].shape[1])
+    got = cap.scratch[0]
+    probs = []
+    if jnp.dtype(got.dtype) != want:
+        probs.append(f"operand scratch dtype {jnp.dtype(got.dtype)} != "
+                     f"{want} for {bits}-bit act mantissas and "
+                     f"{jnp.dtype(w_mant)} weight mantissas")
+    if tuple(got.shape) != shape:
+        probs.append(f"operand scratch shape {tuple(got.shape)} != the "
+                     f"(bm, K) activation block {shape}")
+    return probs
 
 
 def _f32_scratch(cap: PallasCapture) -> List[str]:
@@ -352,8 +379,8 @@ def _flash_scratch(cap: PallasCapture) -> List[str]:
 
 
 SCRATCH_CONTRACTS: Dict[str, Callable[[PallasCapture], List[str]]] = {
-    "_mxint_ln_matmul_kernel": _ln_matmul_scratch,
-    "_mxint_matmul_kernel": _f32_scratch,
+    "_mxint_ln_matmul_kernel": _operand_scratch,
+    "_mxint_matmul_kernel": _operand_scratch,
     "_flash_kernel": _flash_scratch,
     "_decode_kernel": _flash_scratch,
 }
@@ -392,6 +419,22 @@ def _sweep_matmul() -> List[PallasCapture]:
             out_dtype=jnp.float32),
         _sds((128, 1024)), _sds((1024, 512), jnp.int8),
         _sds((4, 512), jnp.int8), label="matmul-bench")
+    # the two other operand paths at the bench shape: activations not
+    # quantized (f32 as they arrive, no scratch) and 10-bit act
+    # mantissas (an f32 operand scratch)
+    caps += capture_pallas_calls(
+        lambda x, m, e: mxint_matmul.__wrapped__(
+            x, m, e, w_block=256, quantize_act=False, bm=128, bn=128,
+            interpret=True, out_dtype=jnp.float32),
+        _sds((128, 1024)), _sds((1024, 512), jnp.int8),
+        _sds((4, 512), jnp.int8), label="matmul-raw")
+    caps += capture_pallas_calls(
+        lambda x, m, e: mxint_matmul.__wrapped__(
+            x, m, e, w_block=256, act_block=16, act_mant_bits=10,
+            quantize_act=True, bm=128, bn=128, interpret=True,
+            out_dtype=jnp.float32),
+        _sds((128, 1024)), _sds((1024, 512), jnp.int8),
+        _sds((4, 512), jnp.int8), label="matmul-f32-operand")
     # OCP-32 weight blocks over K=1024: the exponent plane's (32, 128)
     # block is int8's native tile
     caps += capture_pallas_calls(

@@ -11,22 +11,25 @@ N-tile of the matmul contracts directly against that resident tile.
 Grid: (cdiv(rows, bm), N/bn), N innermost — the same scratch-persistence
 pattern as the matmul accumulator, but inverted: instead of one output
 tile surviving across K steps, one *input* tile survives across N steps.
-The last row block may be ragged, as in ``mxint_matmul``: the LayerNorm
-is per row, so rows past ``rows`` stay in their own rows and are dropped.
+The N axis is therefore "arbitrary", the row axis "parallel".  The last
+row block may be ragged, as in ``mxint_matmul``: the LayerNorm is per
+row, so rows past ``rows`` stay in their own rows and are dropped.
 
   j == 0:  x tile (bm, d) -> block-quantize -> row-max requantize ->
            integer mean/var -> rsqrt LUT -> gamma/beta -> output
-           quantization (Eq. 2-3 epilogue) -> VMEM scratch ``y``
-           (stored in the model dtype, so the scratch round-trip is
-           bit-identical to the unfused HBM round-trip);
-  all j:   y -> in-register act quantization -> mantissa x mantissa
-           contraction against the packed (d, bn) weight planes
-           (identical stages to mxint_matmul with quantize_act=True).
+           quantization (Eq. 2-3 epilogue) -> round trip through the
+           model dtype (the unfused path's HBM round trip) -> act
+           quantization into the MXU operand -> VMEM scratch ``a``
+           (bf16 for act mantissas of at most 8 bits against int8
+           weight mantissas, else f32: ``mxint_matmul.operand_path``);
+  all j:   ``a`` x the packed (d, bn) weight planes, one contraction with
+           an f32 result (``mxint_matmul.tile_product``).
 
 Bit-exactness vs the unfused sequence holds by construction: both paths
 execute the same float ops in the same order on the same tiles (the K
 contraction is a single tile in both, matching the interpret-mode
-``mxint_linear``); asserted in tests/test_datapath.py.
+``mxint_linear``), and the bf16 operand holds its grid values exactly;
+asserted in tests/test_datapath.py and tests/test_kernels.py.
 """
 from __future__ import annotations
 
@@ -41,15 +44,16 @@ from repro.core import luts
 from repro.kernels.block_quant import (block_quantize, requantize_rows,
                                        requantize_to_grid)
 from repro.kernels.mxint_layernorm import _rsqrt_lut_stage
-from repro.kernels.mxint_matmul import mxint_tile_product
+from repro.kernels.mxint_matmul import (OPERAND_DTYPES, operand_path,
+                                       tile_product)
 
 
 def _mxint_ln_matmul_kernel(x_ref, g_ref, b_ref, wm_ref, we_ref, o_ref,
-                            y_ref, *, act_block: int, mant_bits: int,
+                            a_ref, *, act_block: int, mant_bits: int,
                             lut: tuple, lut_bits: int, rms_only: bool,
-                            w_block: int):
-    """One (bm, bn) output tile; the LN stage runs only at j == 0 and its
-    result stays resident in the ``y_ref`` VMEM scratch for every j."""
+                            w_block: int, interpret: bool):
+    """One (bm, bn) output tile; the LN stage and the act quantizer run
+    only at j == 0 and their operand stays resident in ``a_ref``."""
 
     @pl.when(pl.program_id(1) == 0)
     def _ln():
@@ -67,14 +71,15 @@ def _mxint_ln_matmul_kernel(x_ref, g_ref, b_ref, wm_ref, we_ref, o_ref,
         if not rms_only:
             y = y + b_ref[...]
         y = requantize_to_grid(y, act_block, mant_bits)
-        y_ref[...] = y.astype(y_ref.dtype)
+        # the model dtype round trip of the unfused path, then the
+        # consuming linear's act quantizer (mxint_matmul, quantize_act)
+        a_ref[...] = requantize_to_grid(
+            y.astype(x_ref.dtype).astype(jnp.float32), act_block,
+            mant_bits).astype(a_ref.dtype)
 
-    # matmul stage — the same tile product as mxint_matmul with
-    # quantize_act=True (a single K tile, bk == d)
-    o_ref[...] = mxint_tile_product(
-        y_ref[...], wm_ref[...], we_ref[...], w_block=w_block,
-        act_block=act_block, act_mant_bits=mant_bits,
-        quantize_act=True).astype(o_ref.dtype)
+    o_ref[...] = tile_product(a_ref[...], wm_ref[...], we_ref[...],
+                              w_block=w_block, interpret=interpret
+                              ).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=(
@@ -89,7 +94,7 @@ def mxint_ln_matmul(x: jnp.ndarray, gamma: jnp.ndarray, beta: jnp.ndarray,
     """y[M,N] = MXIntLN(x)[M,K] @ (w_mant * 2^w_exp)[K,N], one kernel.
 
     x: (rows, d) activations (any float dtype — the LN stage computes in
-    f32 and the scratch holds the model dtype); gamma/beta: (d,) scale /
+    f32 and rounds its output through this dtype); gamma/beta: (d,) scale /
     shift (beta ignored with ``rms_only``); w_mant: (d, N) int8 mantissas;
     w_exp: (d/w_block, N) int8 shared exponents.  The output is NOT
     bias-added (the wrapper adds bias after any tensor-parallel
@@ -110,7 +115,7 @@ def mxint_ln_matmul(x: jnp.ndarray, gamma: jnp.ndarray, beta: jnp.ndarray,
     kernel = functools.partial(
         _mxint_ln_matmul_kernel, act_block=act_block, mant_bits=mant_bits,
         lut=luts.rsqrt_table(lut_bits), lut_bits=lut_bits,
-        rms_only=rms_only, w_block=w_block)
+        rms_only=rms_only, w_block=w_block, interpret=interpret)
 
     return pl.pallas_call(
         kernel,
@@ -124,9 +129,11 @@ def mxint_ln_matmul(x: jnp.ndarray, gamma: jnp.ndarray, beta: jnp.ndarray,
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((rows, N), jnp.float32),
-        scratch_shapes=[pltpu.VMEM((bm, d), x.dtype)],
-        # Row blocks are independent; the N axis reuses the normalised
-        # tile cached in scratch at j == 0, so it must run in order
+        scratch_shapes=[pltpu.VMEM(
+            (bm, d),
+            OPERAND_DTYPES[operand_path(True, mant_bits, w_mant.dtype)])],
+        # Row blocks are independent; the N axis reuses the operand
+        # cached in scratch at j == 0, so it must run in order
         # (DESIGN.md §14).
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),

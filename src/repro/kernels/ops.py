@@ -32,6 +32,7 @@ from repro.kernels.flash_attention import (flash_attention,
 from repro.kernels.mxint_gelu import mxint_gelu as _gelu_kernel
 from repro.kernels.mxint_layernorm import mxint_layernorm as _ln_kernel
 from repro.kernels.mxint_matmul import mxint_matmul as _mm_kernel
+from repro.kernels.mxint_matmul import operand_path
 from repro.kernels.mxint_softmax import mxint_softmax as _sm_kernel
 
 # ---------------------------------------------------------------------------
@@ -159,15 +160,22 @@ def _pick_block_rows(rows: int, cap: int = 256) -> int:
 
 
 # Row tile of the MXInt linear kernels.  Every (row tile, N tile) grid
-# step re-fetches its K x 128 weight tile, widens it to f32 and scales it
-# by 2^e, so the tile is as tall as VMEM allows: 256 rows, or 128 (one
-# full pass of v5e's 128 x 128 MXU) where a 256-row f32 activation block
-# would pass _ROW_TILE_ACT_BYTES.  VMEM budget, from compiles for a
-# described v5e: at K = 3072 (DeiT-Base fc2) Mosaic's scoped VMEM for
-# mxint_matmul with the in-register quantizer is 7.0 MiB at 256 rows and
-# 16.6 MiB at 512, past the 16 MiB default limit; the fused LN kernel at
-# K = 768 needs 2.7 MiB at 256 rows.  tests/test_v5e_compile.py compiles
-# every DeiT linear at 6,304 rows.
+# step re-fetches its K x 128 weight tile, widens it and scales it by
+# 2^e, so the tile is as tall as VMEM allows: 256 rows, or 128 (one full
+# pass of v5e's 128 x 128 MXU) where a 256-row f32 activation block
+# would pass _ROW_TILE_ACT_BYTES.  The quantized activation of a row
+# block is made once, at the first N tile, into a (bm, K) VMEM scratch
+# (bf16 for act mantissas of at most 8 bits against int8 weight
+# mantissas, exact there; f32 otherwise), which
+# every N tile contracts.  VMEM budget: the smallest vmem_limit_bytes
+# under which Mosaic compiles each kernel for a described v5e (to 1/16
+# MiB), 256 rows: DeiT-Base fc2 (K = 3072) 10.06 MiB, of it the bf16
+# operand scratch 1.5 MiB (8.56 MiB with the quantizer rerun per N tile
+# and no scratch); SAM's neck (K = 2304) 7.56 MiB (6.44); the fused LN
+# kernel at K = 768 3.94 MiB (4.56 with an f32 LN scratch).  A 512-row
+# tile at K = 3072 passes Mosaic's 16 MiB default.
+# tests/test_v5e_compile.py compiles every DeiT and SAM linear at the
+# benchmark's batches.
 _ROW_TILE = 256
 _ROW_TILE_ACT_BYTES = 256 * 3072 * 4
 _LINEAR_PREFIX = "kernels/linear_"
@@ -186,12 +194,14 @@ def linear_row_tile(rows: int, k: int) -> int:
     return _ceil_to(-(-rows // blocks), 8)
 
 
-def _counted_row_tile(rows: int, k: int) -> int:
+def _counted_row_tile(rows: int, k: int, operand: str) -> int:
     """``linear_row_tile`` of a call, counted at trace time in
-    ``repro.telemetry``: calls by row tile, rows, and rows the grid
-    computes past them (the pad to 8 and the ragged last block)."""
+    ``repro.telemetry``: calls by row tile and by MXU operand path
+    (``mxint_matmul.operand_path``), rows, and rows the grid computes
+    past them (the pad to 8 and the ragged last block)."""
     from repro import telemetry as T
     bm = linear_row_tile(rows, k)
+    T.counter(f"{_LINEAR_PREFIX}operand/{operand}").inc()
     T.counter(f"{_LINEAR_PREFIX}row_tile/{bm}").inc()
     T.counter(_LINEAR_PREFIX + "rows").inc(rows)
     T.counter(_LINEAR_PREFIX + "rows_padded").inc(
@@ -200,8 +210,9 @@ def _counted_row_tile(rows: int, k: int) -> int:
 
 
 def linear_tiling() -> dict:
-    """The linear kernels' row tiling since the last reset: calls by row
-    tile, rows, padded rows and their share of the rows."""
+    """The linear kernels' tiling since the last reset: calls by row
+    tile, calls by MXU operand path ('bf16', 'f32', 'raw'), rows, padded
+    rows and their share of the rows."""
     from repro import telemetry as T
     counts = T.default_registry().counters_with_prefix(_LINEAR_PREFIX)
     rows = counts.get("rows", 0)
@@ -209,6 +220,9 @@ def linear_tiling() -> dict:
     return {"row_tile_calls": {int(k.split("/")[1]): v
                                for k, v in sorted(counts.items())
                                if k.startswith("row_tile/")},
+            "operand_calls": {k.split("/")[1]: v
+                              for k, v in sorted(counts.items())
+                              if k.startswith("operand/")},
             "rows": rows, "rows_padded": padded,
             "padded_share": padded / rows if rows else 0.0}
 
@@ -283,8 +297,9 @@ def mxint_linear(x: jnp.ndarray, w_mant: jnp.ndarray, w_exp: jnp.ndarray,
     y = _mm_kernel(x2p, wm, we, w_block=w_block,
                    act_block=_resolve_block(K, act_block),
                    act_mant_bits=act_mant_bits, quantize_act=quantize_act,
-                   bm=_counted_row_tile(rows, K), bn=128,
-                   interpret=_interpret())[:rows, :N]
+                   bm=_counted_row_tile(rows, K, operand_path(
+                       quantize_act, act_mant_bits, w_mant.dtype)),
+                   bn=128, interpret=_interpret())[:rows, :N]
     if tp_axis is not None:
         if tp_mode == "gather":
             y = jax.lax.all_gather(y, tp_axis, axis=1, tiled=True)
@@ -370,8 +385,9 @@ def mxint_ln_linear_op(x: jnp.ndarray, gamma: jnp.ndarray,
                         act_block=_resolve_block(K, act_block),
                         mant_bits=mant_bits, lut_bits=lut_bits,
                         rms_only=rms_only,
-                        bm=_counted_row_tile(rows, K), bn=128,
-                        interpret=_interpret())[:rows, :N]
+                        bm=_counted_row_tile(rows, K, operand_path(
+                            True, mant_bits, w_mant.dtype)),
+                        bn=128, interpret=_interpret())[:rows, :N]
     if tp_axis is not None and tp_mode == "gather":
         y = jax.lax.all_gather(y, tp_axis, axis=1, tiled=True)
         N = y.shape[1]

@@ -82,13 +82,14 @@ def test_grid_semantics_sees_the_accumulator_gates():
     """The AST scan resolves gates through partials AND the flash
     kernels' helper call — the evidence the race check rests on."""
     caps = {c.label: c for c in KC.sweep_captures()}
-    for label, axis in (("ln-matmul-bench", 1), ("flash-bench", 2),
-                        ("flash-decode", 2)):
+    for label, axis in (("ln-matmul-bench", 1), ("matmul-bench", 1),
+                        ("flash-bench", 2), ("flash-decode", 2)):
         facts = GS.kernel_body_facts(caps[label])
         assert facts.src_ok, label
         assert axis in {g.axis for g in facts.gates}, (label, facts.gates)
-    # the single-K-tile matmul writes each output tile once: no gate
-    facts = GS.kernel_body_facts(caps["matmul-bench"])
+    # the raw single-K-tile matmul writes each output tile once from its
+    # own inputs: no gate
+    facts = GS.kernel_body_facts(caps["matmul-raw"])
     assert facts.src_ok and not facts.gates, facts.gates
 
 
@@ -152,13 +153,19 @@ def test_neg_inf_literal_allowed_only_at_home():
 def test_capture_returns_real_blockspecs():
     caps = KC.sweep_captures()
     byk = {c.kernel for c in caps}
-    assert {"_mxint_matmul_kernel", "_mxint_layernorm_kernel",
-            "_mxint_softmax_kernel", "_mxint_gelu_kernel",
-            "_mxint_ln_matmul_kernel", "_flash_kernel",
-            "_decode_kernel"} <= byk
-    ln = next(c for c in caps if c.kernel == "_mxint_ln_matmul_kernel")
-    # the documented model-dtype scratch contract is actually visible
-    assert ln.scratch[0].dtype == ln.inputs[0].dtype
+    assert {"_mxint_matmul_kernel", "_mxint_matmul_raw_kernel",
+            "_mxint_layernorm_kernel", "_mxint_softmax_kernel",
+            "_mxint_gelu_kernel", "_mxint_ln_matmul_kernel",
+            "_flash_kernel", "_decode_kernel"} <= byk
+    # the documented operand-dtype scratch contract is actually visible:
+    # a bf16 (bm, K) operand for 8-bit act mantissas, f32 for 10-bit
+    for label in ("ln-matmul-bench", "matmul-bench"):
+        cap = next(c for c in caps if c.label == label)
+        assert cap.scratch[0].dtype == jnp.bfloat16, label
+        assert cap.scratch[0].shape == (cap.inputs[0].block_shape[0],
+                                        cap.inputs[0].shape[1]), label
+    wide = next(c for c in caps if c.label == "matmul-f32-operand")
+    assert wide.scratch[0].dtype == jnp.float32
 
 
 def test_trace_lint_flags_xla_backend_with_pallas():

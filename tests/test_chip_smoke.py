@@ -65,7 +65,18 @@ def test_linear_tiling_counted_once_per_call_site(tiny_phase):
     report, _ = tiny_phase
     tiling = report["linear_tiling"]
     assert sum(tiling["row_tile_calls"].values()) == 8
+    assert tiling["operand_calls"] == {"bf16": 8}
     assert 0 <= tiling["rows_padded"] < tiling["rows"]
+
+
+def test_benchmark_operand_paths_all_bf16():
+    """Every linear of the benchmark's configurations, traced once per
+    call site, feeds the MXU bf16: DeiT's patch linear, 3 fused LN->q/k/v,
+    out-proj, fused LN->wi, wo and head; SAM's patch linear, those 6 in
+    each of the scanned period's 3 layers, and the neck's two."""
+    paths = cs.benchmark_operand_paths(batch=1)
+    assert paths == {"deit_base": {"bf16": 8}, "deit_small": {"bf16": 8},
+                     "sam_vit_b": {"bf16": 1 + 3 * 6 + 2}}
 
 
 def test_attention_tiling_counted_once(tiny_phase):

@@ -14,6 +14,7 @@ import pytest
 
 from repro.core import MXFormat, quantize
 from repro.kernels import ref
+from repro.kernels.block_quant import block_quantize
 from repro.kernels.flash_attention import (flash_attention,
                                            flash_attention_decode)
 from repro.kernels.mxint_gelu import mxint_gelu as gelu_kernel
@@ -589,8 +590,174 @@ class TestLinearRowTile:
             for _ in range(3):
                 fn(x).block_until_ready()
             assert ops.linear_tiling() == {
-                "row_tile_calls": {232: 2}, "rows": 2 * 1576,
+                "row_tile_calls": {232: 2},
+                "operand_calls": {"bf16": 1, "raw": 1}, "rows": 2 * 1576,
                 "rows_padded": 2 * 48, "padded_share": 48 / 1576}
+        finally:
+            ops.reset_linear_tiling()
+
+
+# ---------------------------------------------------------------------------
+# the hoisted MXU operand: bit-identical to the per-tile f32 product
+# ---------------------------------------------------------------------------
+def _per_tile_product_kernel(x_ref, wm_ref, we_ref, o_ref, *, w_block,
+                             act_block, act_mant_bits, quantize_act):
+    """The linear kernels' grid step before the operand was hoisted: the
+    activation tile quantized again at every column tile, the weight
+    tile widened to f32, an f32 x f32 contraction."""
+    x = x_ref[...].astype(jnp.float32)
+    if quantize_act:
+        xm, xe = block_quantize(x, act_block, act_mant_bits)
+        x = xm * jnp.exp2(xe.astype(jnp.float32))
+    scale = jnp.repeat(jnp.exp2(we_ref[...].astype(jnp.float32)), w_block,
+                       axis=0)
+    w = wm_ref[...].astype(jnp.float32) * scale
+    o_ref[...] = jax.lax.dot_general(x, w, (((1,), (0,)), ((), ())),
+                                     preferred_element_type=jnp.float32)
+
+
+def _per_tile_reference(x, wm, we, *, bm, w_block, act_mant_bits,
+                        quantize_act):
+    from jax.experimental import pallas as pl
+    (M, K), N = x.shape, wm.shape[1]
+    kernel = functools.partial(
+        _per_tile_product_kernel, w_block=w_block, act_block=16,
+        act_mant_bits=act_mant_bits, quantize_act=quantize_act)
+    return pl.pallas_call(
+        kernel, grid=(pl.cdiv(M, bm), N // 128),
+        in_specs=[pl.BlockSpec((bm, K), lambda i, j: (i, 0)),
+                  pl.BlockSpec((K, 128), lambda i, j: (0, j)),
+                  pl.BlockSpec((K // w_block, 128), lambda i, j: (0, j))],
+        out_specs=pl.BlockSpec((bm, 128), lambda i, j: (i, j)),
+        out_shape=jax.ShapeDtypeStruct((M, N), jnp.float32),
+        interpret=True)(x, wm, we)
+
+
+def _edge_rows(rows, k, seed, dtype=jnp.float32):
+    """Seeded activations with, in every row, a block of 16 zeros and a
+    block whose values all lie below 2^-120, and one row of zeros."""
+    x = _rand((rows, k), seed=seed, scale=2.0)
+    x = x.at[:, :16].set(0.0).at[:, 16:32].multiply(2.0 ** -125)
+    return x.at[rows // 2].set(0.0).astype(dtype)
+
+
+def _planes(k, n, seed, w_bits=6):
+    """MXInt weight planes of (k, n) (int8 mantissas up to 8 bits, int16
+    above), padded to a lane multiple of n."""
+    from repro.core.quantize import _resolve_block
+    w_block = _resolve_block(k, 256)
+    wq = quantize(_rand((k, n), seed=seed, scale=k ** -0.5),
+                  MXFormat(w_bits, w_block), axis=0)
+    wm, we = ops._pad_planes(wq.mantissa, wq.exponent)
+    return wm, we, w_block
+
+
+class TestHoistedOperand:
+    """The operand quantized once per row block (bf16, or f32 past 8 act
+    mantissa bits) against the per-tile f32 product, bit for bit in
+    interpret mode."""
+
+    @pytest.mark.parametrize("rows,k,n,act_mant_bits,w_bits", [
+        (8, 384, 128, 8, 6), (197, 768, 768, 8, 6), (197, 768, 1000, 4, 6),
+        (6304, 384, 128, 8, 6), (8, 3072, 3072, 8, 6),
+        (197, 2304, 256, 10, 6), (197, 768, 256, 8, 10),
+        (197, 768, 768, None, 6), (6304, 768, 128, None, 6)])
+    def test_matmul_bit_identical_to_per_tile_product(self, rows, k, n,
+                                                      act_mant_bits, w_bits):
+        from repro.kernels.mxint_matmul import operand_path
+        x = _edge_rows(rows, k, seed=rows + k)
+        wm, we, w_block = _planes(k, n, seed=n, w_bits=w_bits)
+        quantize_act = act_mant_bits is not None
+        bits = act_mant_bits or 8
+        bm = ops.linear_row_tile(rows, k)
+        got = mm_kernel(x, wm, we, w_block=w_block, act_block=16,
+                        act_mant_bits=bits, quantize_act=quantize_act,
+                        bm=bm, bn=128, interpret=True)
+        want = _per_tile_reference(x, wm, we, bm=bm, w_block=w_block,
+                                   act_mant_bits=bits,
+                                   quantize_act=quantize_act)
+        assert operand_path(quantize_act, bits, wm.dtype) == (
+            "raw" if not quantize_act
+            else "bf16" if bits <= 8 and w_bits <= 8 else "f32")
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+    @pytest.mark.parametrize("rows,d,n,dtype,rms_only,mant_bits", [
+        (197, 768, 768, jnp.float32, False, 8),
+        (197, 384, 1000, jnp.bfloat16, False, 8),
+        (8, 768, 3072, jnp.float32, True, 8),
+        (6304, 384, 128, jnp.float32, False, 8),
+        (197, 768, 256, jnp.float32, False, 10),
+        (197, 768, 768, jnp.bfloat16, True, 4)])
+    def test_ln_matmul_bit_identical_to_per_tile_product(
+            self, rows, d, n, dtype, rms_only, mant_bits):
+        """The fused kernel against the LN kernel's quantized output,
+        rounded through the model dtype, into the per-tile product."""
+        from repro.kernels.mxint_ln_matmul import mxint_ln_matmul
+        x = _edge_rows(rows, d, seed=rows + d, dtype=dtype)
+        g = _rand((d,), seed=1, scale=0.5) + 1.0
+        b = _rand((d,), seed=2, scale=0.1)
+        wm, we, w_block = _planes(d, n, seed=n)
+        bm = ops.linear_row_tile(rows, d)
+        got = mxint_ln_matmul(x, g, b, wm, we, w_block=w_block,
+                              act_block=16, mant_bits=mant_bits,
+                              rms_only=rms_only, bm=bm, bn=128,
+                              interpret=True)
+        xp = _pad8(x.astype(jnp.float32))
+        normed = ln_kernel(xp, g, b, act_block=16, mant_bits=mant_bits,
+                           rms_only=rms_only, quantize_out=True,
+                           block_rows=8, interpret=True)[:rows]
+        want = _per_tile_reference(normed.astype(dtype), wm, we, bm=bm,
+                                   w_block=w_block, act_mant_bits=mant_bits,
+                                   quantize_act=True)
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+    @pytest.mark.parametrize("operand", ["act4", "act8", "weight"])
+    def test_bf16_operand_round_trips(self, operand):
+        """The operand's grid values, mantissas times exact powers of two
+        (np.ldexp), come back from bf16 to f32 unchanged."""
+        if operand == "weight":
+            rng = np.random.default_rng(3)
+            m = rng.integers(-127, 128, size=(256, 128))
+            e = rng.integers(-128, 100, size=(1, 128))
+        else:
+            m, e = block_quantize(_edge_rows(64, 768, seed=4), 16,
+                                  int(operand[3:]))
+        f32 = np.ldexp(np.asarray(m, np.float64),
+                       np.asarray(e)).astype(np.float32)
+        back = f32.astype(jnp.bfloat16).astype(np.float32)
+        np.testing.assert_array_equal(back, f32)
+
+    @pytest.mark.parametrize("quantize_act,bits,w_dtype,path", [
+        (True, 4, jnp.int8, "bf16"), (True, 8, jnp.int8, "bf16"),
+        (True, 9, jnp.int8, "f32"), (True, 8, jnp.int16, "f32"),
+        (False, 8, jnp.int8, "raw")])
+    def test_operand_path_rule(self, quantize_act, bits, w_dtype, path):
+        from repro.kernels.mxint_matmul import operand_path
+        assert operand_path(quantize_act, bits, w_dtype) == path
+
+    @pytest.mark.parametrize("act_mant_bits,path", [(8, "bf16"), (10, "f32")])
+    def test_deit_tiny_forward_counts_operand_paths(self, act_mant_bits,
+                                                    path):
+        """Patch linear, 3 fused LN->q/k/v, out-proj, fused LN->wi, wo and
+        head of a kernel-mode DeiT-Tiny forward, each counted once while
+        it is traced, all under the path its act format gives."""
+        import dataclasses
+        from repro.configs.deit import BY_NAME
+        from repro.core.mx_types import QuantConfig
+        from repro.models import build_model
+        from repro.serving.engine import pack_params_mxint
+        q = QuantConfig(mode="kernel", quantize_nonlinear=True,
+                        act_fmt=MXFormat(act_mant_bits, 16))
+        cfg = dataclasses.replace(BY_NAME["deit_tiny"], quant=q)
+        model = build_model(cfg)
+        params = pack_params_mxint(
+            jax.eval_shape(model.init, jax.random.key(0)), q.weight_fmt,
+            abstract=True)
+        imgs = jax.ShapeDtypeStruct((3, 224, 224, 3), jnp.float32)
+        ops.reset_linear_tiling()
+        try:
+            jax.eval_shape(model.logits, params, imgs)
+            assert ops.linear_tiling()["operand_calls"] == {path: 8}
         finally:
             ops.reset_linear_tiling()
 

@@ -1,4 +1,4 @@
-"""Shared benchmark helpers: a trained micro-DeiT + format emulations.
+"""Shared benchmark helpers: a trained micro-DeiT and its accuracy.
 
 The container has no ImageNet, so accuracy numbers come from a DeiT of the
 same family trained on a synthetic 10-class task (class-conditional blobs,
@@ -9,7 +9,6 @@ quantity the paper budgets (<1%).
 from __future__ import annotations
 
 import functools
-import time
 from pathlib import Path
 
 import jax
@@ -26,15 +25,6 @@ from repro.train.step import make_train_step
 
 CACHE = Path(__file__).resolve().parent / "_cache"
 CACHE.mkdir(exist_ok=True)
-
-
-def timer(fn, *args, repeats: int = 5, warmup: int = 1):
-    for _ in range(warmup):
-        jax.block_until_ready(fn(*args))
-    t0 = time.perf_counter()
-    for _ in range(repeats):
-        jax.block_until_ready(fn(*args))
-    return (time.perf_counter() - t0) / repeats * 1e6        # us
 
 
 # A HARD task config: 100 thin-margin classes, heavy noise, outlier image
@@ -84,32 +74,3 @@ def eval_accuracy(model, params, n_batches: int = 8, seed: int = 99) -> float:
         accs.append(float(acc_fn(params, b)))
     return float(np.mean(accs))
 
-
-# ---------------------------------------------------------------------------
-# format emulations for Table V
-# ---------------------------------------------------------------------------
-def qdq_int(x, bits: int):
-    amax = jnp.maximum(jnp.max(jnp.abs(x)), 1e-12)
-    s = amax / (2 ** (bits - 1) - 1)
-    return jnp.clip(jnp.round(x / s), -(2 ** (bits - 1)),
-                    2 ** (bits - 1) - 1) * s
-
-
-def qdq_fp8_e4m3(x):
-    """e4m3 emulation: 3 mantissa bits, exponent range [-6, 8]."""
-    xf = jnp.asarray(x, jnp.float32)
-    m, e = jnp.frexp(xf)
-    e = jnp.clip(e, -6, 9)
-    scale = jnp.exp2(3.0 - e.astype(jnp.float32))          # 3 mantissa bits
-    q = jnp.round(xf * scale) / scale
-    return jnp.clip(q, -448.0, 448.0)
-
-
-def map_weights(params, fn):
-    """Apply fn to every >=2-D kernel leaf (PTQ of the weights)."""
-    def one(p: Param):
-        v = p.value
-        if hasattr(v, "ndim") and v.ndim >= 2 and v.size > 256:
-            return Param(fn(v), p.axes)
-        return p
-    return jax.tree_util.tree_map(one, params, is_leaf=is_param)

@@ -42,8 +42,7 @@ def run():
     space = SearchSpace(base=base, groups=tuple(
         GroupSpace(scope=scope, weight_mant_bits=WIDTHS)
         for _, scope in ROW_SCOPES))
-    ev = Evaluator(space, model.cfg, params, batch["images"],
-                   kernel_rows=())
+    ev = Evaluator(space, model.cfg, params, batch["images"])
 
     t0 = time.perf_counter()
     res = greedy_search(space, ev, budget=0.01,

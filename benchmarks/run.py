@@ -5,10 +5,7 @@ Prints ``name,us_per_call,derived`` CSV.  Modules:
   table5_quantization  — Table V / Fig 1b format sweep + outlier microbench
   per_op_tables        — Tables II/III/IV + Figs 4/7/8/9 datapath DSE
   table6_lut_savings   — Table VI LUT-entry savings (>=16x claim)
-  fig10_speedup        — Fig 10 modeled MXInt-vs-float speedup (roofline)
-  table7_system        — Table VII system resource/performance analogue
-  kernel_bench         — Pallas kernel wall-times (interpret mode)
-  roofline             — §Roofline 40-cell table from dry-run artifacts
+  greedy_search_bench  — Table V greedy bitwidth search
 """
 from __future__ import annotations
 
@@ -19,11 +16,7 @@ MODULES = [
     "table5_quantization",
     "per_op_tables",
     "table6_lut_savings",
-    "fig10_speedup",
-    "table7_system",
     "greedy_search_bench",
-    "kernel_bench",
-    "roofline",
 ]
 
 

@@ -24,10 +24,8 @@ boundaries into the fixed batch shape — zero recompiles after warmup.
 
 ``--metrics-json PATH`` dumps the full ``repro.telemetry`` snapshot of
 the serving run — request-latency histograms, queue/slot gauges, the
-``serving/recompiles`` counter (0 after warmup) — plus a
-``predicted_vs_measured`` section joining live DeiT kernel probes
-(``matmul-deit``, ``flash-deit``) against the static cost-model table
-by row label (DESIGN.md §15).
+``serving/recompiles`` counter (0 after warmup) — plus a ``run`` block
+with the image, request and throughput totals (DESIGN.md §15).
 
 Run:  PYTHONPATH=src python examples/serve_deit_mxint.py \
           [--requests 64] [--batch 16] [--tp 2] [--metrics-json out.json]
@@ -48,8 +46,8 @@ def _parse_args():
     ap.add_argument("--tp", type=int, default=1,
                     help="shard packed planes over an N-way 'model' mesh")
     ap.add_argument("--metrics-json", default=None, metavar="PATH",
-                    help="write the telemetry snapshot + the "
-                         "predicted-vs-measured kernel roofline here")
+                    help="write the telemetry snapshot + the run's "
+                         "totals here")
     return ap.parse_args()
 
 
@@ -153,23 +151,14 @@ def main():
 
     if args.metrics_json:
         from repro.telemetry import export as tel_export
-        from repro.telemetry import probes as tel_probes
 
-        print("\nrunning kernel probes for the predicted-vs-measured "
-              "join (DeiT matmul + flash attention)...")
-        tel_probes.run_probes()
-        pvm = tel_export.predicted_vs_measured()
         payload = tel_export.json_snapshot(
             path=args.metrics_json,
-            extra={"predicted_vs_measured": pvm,
-                   "run": {"images": int(n), "requests": len(done),
+            extra={"run": {"images": int(n), "requests": len(done),
                            "img_per_s": round(n / dt, 2),
                            "tp": args.tp}})
-        joined = {k["label"]: k["measured_ms"]
-                  for k in pvm["kernels"]}
         print(f"  metrics -> {args.metrics_json}  "
-              f"({len(payload['histograms'])} histograms, "
-              f"joined kernels: {joined})")
+              f"({len(payload['histograms'])} histograms)")
 
 
 if __name__ == "__main__":

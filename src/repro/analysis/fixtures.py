@@ -28,41 +28,29 @@ def _noop_kernel(*refs):
     pass
 
 
-def _capture_2d(shape, block, *, out_block=None, grid=None,
-                index_map=None, out_index_map=None, dtype=jnp.float32,
-                kernel=_noop_kernel, scratch=(), compiler_params=None):
+def _capture_2d(shape, block, *, grid=None, index_map=None,
+                out_index_map=None, kernel=_noop_kernel, scratch=(),
+                compiler_params=None):
     """Fabricate one 2-D pallas_call capture with the given specs."""
     from jax.experimental import pallas as pl
 
     grid = grid or tuple(d // b for d, b in zip(shape, block))
     index_map = index_map or (lambda i, j: (i, j))
     out_index_map = out_index_map or index_map
-    out_block = out_block or block
 
     def fn(x):
         return pl.pallas_call(
             kernel,
             grid=grid,
             in_specs=[pl.BlockSpec(block, index_map)],
-            out_specs=pl.BlockSpec(out_block, out_index_map),
-            out_shape=jax.ShapeDtypeStruct(shape, dtype),
+            out_specs=pl.BlockSpec(block, out_index_map),
+            out_shape=jax.ShapeDtypeStruct(shape, jnp.float32),
             scratch_shapes=list(scratch),
             compiler_params=compiler_params,
             interpret=True)(x)
 
-    return capture_pallas_calls(fn, jax.ShapeDtypeStruct(shape, dtype),
+    return capture_pallas_calls(fn, jax.ShapeDtypeStruct(shape, jnp.float32),
                                 label="fixture")
-
-
-def vmem_over_budget() -> List[Violation]:
-    """A (2048, 2048) f32 block is 16 MiB; double-buffered in+out blows
-    the whole per-core budget several times over."""
-    return check_captures(_capture_2d((4096, 2048), (2048, 2048)))
-
-
-def misaligned_tile() -> List[Violation]:
-    """Minormost tiled block of 100 lanes (not a 128 multiple)."""
-    return check_captures(_capture_2d((64, 400), (8, 100)))
 
 
 def uncovered_output_block() -> List[Violation]:
@@ -71,20 +59,6 @@ def uncovered_output_block() -> List[Violation]:
     return check_captures(_capture_2d(
         (512, 128), (128, 128), grid=(4,),
         index_map=lambda i: (i, 0), out_index_map=lambda i: (0, 0)))
-
-
-def wrong_scratch_dtype() -> List[Violation]:
-    """A kernel posing as mxint_ln_matmul whose operand scratch is f32
-    though its 8-bit act mantissas are exact in bf16 — the operand-dtype
-    scratch contract."""
-    from jax.experimental.pallas import tpu as pltpu
-
-    def _mxint_ln_matmul_kernel(*refs):
-        pass
-
-    return check_captures(_capture_2d(
-        (128, 256), (128, 256), kernel=_mxint_ln_matmul_kernel,
-        scratch=(pltpu.VMEM((128, 256), jnp.float32),)))
 
 
 def float_softmax_in_kernel_trace() -> List[Violation]:
@@ -199,18 +173,6 @@ def unaliased_inplace_output() -> List[Violation]:
             dimension_semantics=("parallel", "parallel"))))
 
 
-def cost_model_regression() -> List[Violation]:
-    """The current tree diffed against a baseline whose byte counts are
-    10% smaller — every row regresses past the 2% CI threshold."""
-    from repro.analysis.cost_model import build_table, compare_to_baseline
-
-    rows = build_table()
-    deflated = {"rows": {
-        r["label"]: {"hbm_bytes": int(r["hbm_bytes"] * 0.9)}
-        for r in rows}}
-    return compare_to_baseline(rows, deflated)
-
-
 def raw_neg_inf_literal() -> List[Violation]:
     return check_source(
         "MASK_VALUE = -2.0e38\n",
@@ -270,10 +232,7 @@ def adhoc_timing_in_src() -> List[Violation]:
 
 
 FIXTURES: Dict[str, Callable[[], List[Violation]]] = {
-    "vmem-over-budget": vmem_over_budget,
-    "misaligned-tile": misaligned_tile,
     "uncovered-output-block": uncovered_output_block,
-    "wrong-scratch-dtype": wrong_scratch_dtype,
     "float-softmax-kernel-trace": float_softmax_in_kernel_trace,
     "f64-leak": f64_leak,
     "raw-neg-inf-literal": raw_neg_inf_literal,
@@ -285,15 +244,11 @@ FIXTURES: Dict[str, Callable[[], List[Violation]]] = {
     "race-parallel-accumulator": race_parallel_accumulator,
     "reversed-init-flush": reversed_init_flush,
     "unaliased-inplace-output": unaliased_inplace_output,
-    "cost-model-regression": cost_model_regression,
 }
 
 # the rule each fixture must trip (self-test assertion)
 FIXTURE_RULES: Dict[str, str] = {
-    "vmem-over-budget": "kernel-contracts",
-    "misaligned-tile": "kernel-contracts",
     "uncovered-output-block": "kernel-contracts",
-    "wrong-scratch-dtype": "kernel-contracts",
     "float-softmax-kernel-trace": "trace-invariants",
     "f64-leak": "trace-invariants",
     "raw-neg-inf-literal": "neg-inf-literal",
@@ -305,7 +260,6 @@ FIXTURE_RULES: Dict[str, str] = {
     "race-parallel-accumulator": "grid-semantics",
     "reversed-init-flush": "grid-semantics",
     "unaliased-inplace-output": "grid-semantics",
-    "cost-model-regression": "cost-model",
 }
 
 

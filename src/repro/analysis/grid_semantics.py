@@ -37,7 +37,7 @@ explicit and machine-checked, per captured call:
 
 The rule walks the same abstract-eval sweep as ``kernel_contracts``
 (shared memo), so every kernel in ``repro/kernels/`` is covered at the
-kernel_bench + DeiT shapes.
+stand-alone + DeiT shapes.
 """
 from __future__ import annotations
 
@@ -425,7 +425,7 @@ def check_captures_semantics(
     "grid-semantics",
     "Pallas dimension_semantics race checker: accumulator axes declared "
     "\"arbitrary\", independent axes \"parallel\", init/flush ordering "
-    "and output aliasing over the kernel_bench + DeiT sweep")
+    "and output aliasing over the stand-alone + DeiT sweep")
 def run(root: Path) -> List[Violation]:
     caps = sweep_captures()
     out = check_captures_semantics(caps)

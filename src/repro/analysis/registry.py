@@ -6,9 +6,8 @@ runner (``tools/repro_lint.py``) and the tier-1 self-tests
 pass lists, so a new invariant is one ``@register_rule`` away from CI.
 
 Severity: ``error`` violations fail the run; ``warn`` violations are
-printed but do not affect the exit code (used for contracts we believe in
-but cannot validate off-hardware, e.g. narrow-dtype native sublane tiling
-— see kernel_contracts).
+printed but do not affect the exit code (e.g. a grid too large for
+kernel_contracts to enumerate its index maps).
 """
 from __future__ import annotations
 

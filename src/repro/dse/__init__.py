@@ -16,15 +16,14 @@ from repro.dse.drivers import (GreedyResult, evolutionary_search,
                                exhaustive_search, greedy_search,
                                random_search)
 from repro.dse.evaluate import (CandidateCost, EvalResult, Evaluator,
-                                measure_kernels, weight_groups)
+                                weight_groups)
 from repro.dse.report import (DEFAULT_OBJECTIVES, build_report, dominates,
                               objective_vector, pareto_front, write_report)
 from repro.dse.space import (GroupSpace, Knob, SearchSpace, point_key)
 
 __all__ = [
     "SearchSpace", "GroupSpace", "Knob", "point_key",
-    "Evaluator", "EvalResult", "CandidateCost", "measure_kernels",
-    "weight_groups",
+    "Evaluator", "EvalResult", "CandidateCost", "weight_groups",
     "exhaustive_search", "greedy_search", "random_search",
     "evolutionary_search", "GreedyResult",
     "dominates", "pareto_front", "objective_vector", "DEFAULT_OBJECTIVES",

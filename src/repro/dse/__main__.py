@@ -57,9 +57,6 @@ def main(argv=None) -> dict:
     p.add_argument("--samples", type=int, default=16,
                    help="random-driver sample count")
     p.add_argument("--seed", type=int, default=0)
-    p.add_argument("--probes", action="store_true",
-                   help="also run the telemetry kernel probes "
-                        "(measured wall-clock; interpret-mode on CPU)")
     p.add_argument("--out", default="dse_report.json")
     args = p.parse_args(argv)
 
@@ -68,8 +65,8 @@ def main(argv=None) -> dict:
     from repro.configs.deit import BY_NAME
     from repro.data.pipeline import SyntheticImageData
     from repro.dse import (Evaluator, build_report, evolutionary_search,
-                           exhaustive_search, greedy_search, measure_kernels,
-                           random_search, write_report)
+                           exhaustive_search, greedy_search, random_search,
+                           write_report)
     from repro.models import build_model
 
     cfg = BY_NAME[args.arch]
@@ -92,22 +89,19 @@ def main(argv=None) -> dict:
     else:
         results = greedy_search(space, ev, budget=args.budget).results
 
-    measured = measure_kernels() if args.probes else None
     report = build_report(space, results, driver=args.driver,
-                          n_evaluations=ev.n_evaluated,
-                          measured_ms=measured)
+                          n_evaluations=ev.n_evaluated)
     path = write_report(args.out, report)
 
     print(f"# {args.arch} layers={cfg.n_layers} batch={args.batch} "
           f"driver={args.driver} space={space.size()} "
           f"evaluated={ev.n_evaluated}")
-    print(f"{'pareto':>6} {'w_bits':>7} {'acc':>6} {'fid':>6} "
-          f"{'hbm_bytes':>10}")
+    print(f"{'pareto':>6} {'w_bits':>7} {'acc':>6} {'fid':>6}")
     for row in report["candidates"]:
         c = row["cost"]
         print(f"{'*' if row['pareto'] else '':>6} "
               f"{c['weight_bits']:>7.2f} {row['accuracy']:>6.3f} "
-              f"{row['fidelity']:>6.3f} {c['kernel_hbm_bytes']:>10}")
+              f"{row['fidelity']:>6.3f}")
     print(f"report -> {path}")
     return report
 

@@ -14,17 +14,9 @@ Cost side — static only, no execution:
   per-layer override shows up exactly in proportion to the parameters
   it covers (the paper's Fig. 1b x-axis).  Groups whose scoped mode is
   'off' are priced at float32.
-* kernel FLOPs / HBM-traffic / VMEM — the ``analysis.cost_model`` rows
-  for the deployment kernels (default: the DeiT pair ``matmul-deit`` +
-  ``flash-deit``), with each int8 mantissa-plane operand's bytes scaled
-  by ``weight_bits/8`` — the static table is built at 1 byte/element.
 * ``lut_entries`` — total LUT provisioning: the per-table MAX across
   scopes (shared hardware must fit the widest requested table), summed
   over the three §III-B tables.
-
-Optionally, measured wall-clock: ``measure_kernels`` runs the
-``telemetry.probes`` twins of the same labels and the report carries
-``{label: mean_ms}`` next to the predictions.
 
 Every evaluation is cached on the canonical point key and counted in
 telemetry (``dse/evaluations``, ``dse/cache_hits``, ``span/dse/eval``).
@@ -40,9 +32,6 @@ from repro.dse.space import Point, SearchSpace, point_key
 from repro.telemetry import metrics
 from repro.telemetry.tracing import span
 
-# the paper's DeiT deployment kernels (same labels as telemetry.probes)
-DEFAULT_KERNEL_ROWS: Tuple[str, ...] = ("matmul-deit", "flash-deit")
-
 FLOAT_BITS = 32.0
 
 
@@ -53,9 +42,6 @@ class CandidateCost:
     weight_bits: float          # weighted mean bits/element, weights
     act_bits: float             # weighted mean bits/element, activations
     weight_bytes: int           # total packed weight footprint
-    kernel_flops: int
-    kernel_hbm_bytes: int       # traffic, mantissa planes scaled to width
-    kernel_vmem_bytes: int
     lut_entries: int
 
     def as_dict(self) -> dict:
@@ -133,30 +119,8 @@ def _fmt_bits(q: QuantConfig, which: str) -> float:
     return getattr(q, which).bits_per_element
 
 
-def _scaled_kernel_rows(rows: Dict[str, dict],
-                        weight_scale: float) -> Tuple[int, int, int]:
-    """(flops, hbm_bytes, vmem_bytes) summed over rows, with each row's
-    largest int8 operand — the weight mantissa plane the table prices at
-    8 bits — rescaled to the candidate's mean weight width."""
-    flops = hbm = vmem = 0
-    for row in rows.values():
-        flops += int(row["flops"])
-        vmem += int(row["vmem_bytes"])
-        int8_ops = [o for o in row["operands"]
-                    if o["dtype"] == "int8"]
-        mant = max(int8_ops, key=lambda o: o["bytes_traffic"],
-                   default=None)
-        for o in row["operands"]:
-            b = int(o["bytes_traffic"])
-            if o is mant:
-                b = int(round(b * weight_scale))
-            hbm += b
-    return flops, hbm, vmem
-
-
-def static_cost(space: SearchSpace, point: Point, groups: Sequence[tuple],
-                kernel_rows: Optional[Dict[str, dict]] = None
-                ) -> CandidateCost:
+def static_cost(space: SearchSpace, point: Point,
+                groups: Sequence[tuple]) -> CandidateCost:
     q = space.to_config(point)
     scopes = [s for s, _ in groups]
     total = sum(n for _, n in groups) or 1
@@ -175,26 +139,12 @@ def static_cost(space: SearchSpace, point: Point, groups: Sequence[tuple],
                 per_scope.append(getattr(qs.nonlinear, entries))
         lut += max(per_scope, default=0)
 
-    flops = hbm = vmem = 0
-    if kernel_rows:
-        flops, hbm, vmem = _scaled_kernel_rows(kernel_rows, w_bits / 8.0)
     return CandidateCost(
         weight_bits=round(float(w_bits), 4),
         act_bits=round(float(a_bits), 4),
         weight_bytes=int(round(sum(n for _, n in groups) * w_bits / 8.0)),
-        kernel_flops=flops,
-        kernel_hbm_bytes=hbm,
-        kernel_vmem_bytes=vmem,
         lut_entries=lut,
     )
-
-
-def measure_kernels(labels: Sequence[str] = DEFAULT_KERNEL_ROWS,
-                    repeats: int = 2) -> Dict[str, float]:
-    """Optional measured wall-clock: run the telemetry probe twins of
-    the cost-model labels (interpret-mode on CPU — plumbing, not perf)."""
-    from repro.telemetry.probes import run_probes
-    return run_probes(labels, repeats=repeats)
 
 
 # ---------------------------------------------------------------------------
@@ -210,7 +160,6 @@ class Evaluator:
     """
 
     def __init__(self, space: SearchSpace, cfg, params, images, *,
-                 kernel_rows: Sequence[str] = DEFAULT_KERNEL_ROWS,
                  registry: Optional[metrics.Registry] = None):
         self.space = space
         self.cfg = cfg
@@ -221,13 +170,6 @@ class Evaluator:
         self._cache: Dict[tuple, EvalResult] = {}
         self._logits_cache: Dict[tuple, object] = {}
         self._ref = None
-        self._rows = (dict() if not kernel_rows else
-                      self._load_rows(tuple(kernel_rows)))
-
-    @staticmethod
-    def _load_rows(labels: Tuple[str, ...]) -> Dict[str, dict]:
-        from repro.analysis.cost_model import query
-        return query(labels)
 
     def _logits(self, q: QuantConfig):
         import dataclasses as dc
@@ -267,7 +209,7 @@ class Evaluator:
             point=dict(point),
             accuracy=argmax_agreement(out, self.reference),
             fidelity=cosine_fidelity(out, self.reference),
-            cost=static_cost(self.space, point, self.groups, self._rows),
+            cost=static_cost(self.space, point, self.groups),
         )
         self._cache[key] = result
         return result

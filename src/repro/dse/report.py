@@ -12,8 +12,7 @@ Report schema (§16)::
     {"schema": 1, "driver": ..., "space": SearchSpace.describe(),
      "objectives": [names...], "n_candidates": N, "n_evaluations": N,
      "candidates": [EvalResult.as_dict() + {"pareto": bool}],
-     "pareto": [indices into candidates],
-     "measured_ms": {label: mean_ms} | null}
+     "pareto": [indices into candidates]}
 """
 from __future__ import annotations
 
@@ -25,12 +24,14 @@ from repro.dse.evaluate import EvalResult
 
 Objective = Tuple[str, int, Callable[[EvalResult], float]]
 
-# the Fig. 1b axes: maximize agreement, minimize bits-per-element and
-# the deployment kernels' HBM traffic
+# the Fig. 1b axes: maximize agreement, minimize bits-per-element.  A
+# kernel-traffic axis would add nothing: a linear's HBM bytes are a
+# constant plus the mantissa planes' bytes, an increasing affine
+# function of ``weight_bits``, so it orders candidates exactly as
+# ``weight_bits`` does and never changes which dominate.
 DEFAULT_OBJECTIVES: Tuple[Objective, ...] = (
     ("accuracy", +1, lambda r: r.accuracy),
     ("weight_bits", -1, lambda r: r.cost.weight_bits),
-    ("kernel_hbm_bytes", -1, lambda r: float(r.cost.kernel_hbm_bytes)),
 )
 
 
@@ -61,8 +62,7 @@ def pareto_front(results: Sequence[EvalResult],
 
 def build_report(space, results: Sequence[EvalResult], *, driver: str,
                  objectives: Sequence[Objective] = DEFAULT_OBJECTIVES,
-                 n_evaluations: Optional[int] = None,
-                 measured_ms: Optional[dict] = None) -> dict:
+                 n_evaluations: Optional[int] = None) -> dict:
     # dedupe on the canonical point key (drivers may revisit; the
     # evaluator already served those from cache)
     uniq: List[EvalResult] = []
@@ -87,7 +87,6 @@ def build_report(space, results: Sequence[EvalResult], *, driver: str,
                           else n_evaluations),
         "candidates": candidates,
         "pareto": sorted(front),
-        "measured_ms": measured_ms,
     }
 
 

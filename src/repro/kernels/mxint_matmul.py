@@ -68,7 +68,7 @@ def operand_path(quantize_act: bool, act_mant_bits: int, w_mant_dtype) -> str:
     activations and weight mantissas, both exact in bf16), 'f32'
     (quantized, but a side is wider than bf16 holds) or 'raw'
     (activations not quantized).  The one rule both linear kernels'
-    wrappers, their counters and the static contracts read."""
+    wrappers and their counters read."""
     if not quantize_act:
         return "raw"
     exact = (act_mant_bits <= BF16_MANT_BITS

@@ -10,9 +10,7 @@ batch with SLOT-level admission: the KV cache carries a per-row
 queued request prefilled into that slot immediately (one batch-1
 prefill scattered into the live cache — ``make_slot_prefill_step``)
 while the other rows keep decoding.  No wave barrier: a slot freed at
-step t serves a new request at step t+1.  ``admission='wave'`` retains
-the old whole-batch-drain policy for throughput comparison
-(benchmarks/kernel_bench.py ``lm_batching_rows``).
+step t serves a new request at step t+1.
 
 ``ClassifyScheduler`` (ViT engines): classification is stateless, so
 admission is fully continuous — each step packs up to ``batch`` images
@@ -83,10 +81,7 @@ class BatchScheduler:
     buckets each prompt to the next power of two — one jit
     specialization per bucket ever seen, flat after warmup.
 
-    admission: 'slot' (default) admits a queued request into every
-    freed slot at each step.  'wave' defers admission until the whole
-    batch has drained — the policy the scalar cache index used to
-    force; kept only as the throughput baseline.
+    A queued request is admitted into every freed slot at each step.
 
     Token contract: a request's first generated token comes from its
     prefill logits (recorded at admission), the rest from decode steps
@@ -96,14 +91,11 @@ class BatchScheduler:
     """
 
     def __init__(self, engine, batch_size: int, eos_id: Optional[int] = None,
-                 prefill_len: Optional[int] = None, admission: str = "slot"):
-        if admission not in ("slot", "wave"):
-            raise ValueError(admission)
+                 prefill_len: Optional[int] = None):
         self.engine = engine
         self.batch = batch_size
         self.eos = eos_id
         self.prefill_len = prefill_len
-        self.admission = admission
         self.queue: deque[Request] = deque()
         self.active: List[Optional[Request]] = [None] * batch_size
         self.finished: List[Request] = []
@@ -154,12 +146,8 @@ class BatchScheduler:
         T.gauge("scheduler/in_flight").set(len(self.queue) + slots)
 
     def _evict(self):
-        """Move done requests out of their slots.  Slot mode frees each
-        slot the step after its request finishes; wave mode holds every
-        slot until the whole batch has drained."""
-        if self.admission == "wave" and \
-                any(r is not None and not r.done for r in self.active):
-            return
+        """Move done requests out of their slots: each slot is freed
+        the step after its request finishes."""
         for i, r in enumerate(self.active):
             if r is not None and r.done:
                 self.finished.append(r)
@@ -176,9 +164,6 @@ class BatchScheduler:
         prefill each — the live rows' cache state is untouched (per-row
         index contract, DESIGN.md §7)."""
         if not self.queue:
-            return
-        if self.admission == "wave" and \
-                any(r is not None for r in self.active):
             return
         for i in range(self.batch):
             if not self.queue or self.active[i] is not None:

@@ -1,12 +1,10 @@
-"""repro.telemetry — runtime metrics, spans, and the predicted-vs-
-measured roofline for the serving stack (DESIGN.md §15).
+"""repro.telemetry — runtime metrics and spans for the serving stack
+(DESIGN.md §15).
 
-The measured half of the repo's performance story: the static analysis
-layer predicts per-kernel FLOPs/bytes (DESIGN.md §14); this package
-records what the engines, schedulers, kernels, and loops actually did
-— request latency, batch/prefill shape histograms, queue/slot gauges,
-recompile and fallback counters — in one process-local registry, and
-exports it as JSON, Prometheus text, or the predicted-vs-measured join.
+This package records what the engines, schedulers, kernels, and loops
+actually did — request latency, batch/prefill shape histograms,
+queue/slot gauges, recompile and fallback counters — in one
+process-local registry, and exports it as JSON or Prometheus text.
 
 Convenience surface (all over the default registry)::
 

@@ -1,51 +1,20 @@
-"""Snapshot exporters + the predicted-vs-measured roofline join
-(DESIGN.md §15).
+"""Snapshot exporters (DESIGN.md §15).
 
-Three surfaces over one :meth:`Registry.snapshot`:
+Two surfaces over one :meth:`Registry.snapshot`:
 
 * :func:`json_snapshot` — the machine-readable dump CI archives and
   ``examples/serve_deit_mxint.py --metrics-json`` writes;
 * :func:`prometheus_text` — Prometheus text exposition (counters,
-  gauges, cumulative-bucket histograms) for scrape endpoints;
-* :func:`predicted_vs_measured` — joins measured kernel spans
-  (``span/kernel:<label>/ms``, recorded by ``repro.telemetry.probes``)
-  against the STATIC cost-model table (DESIGN.md §14) by row label and
-  reports the achieved fraction of the analytic roofline per kernel —
-  the measured half of the compile-time-predicted vs hardware-measured
-  loop the accelerator literature (e.g. CHOSEN) evaluates with.
-
-The cost table is resolved like ``benchmarks/roofline.py`` resolves it:
-a live import of ``repro.analysis.cost_model`` first, else an explicit
-``repro_lint --json`` report path.
+  gauges, cumulative-bucket histograms) for scrape endpoints.
 """
 from __future__ import annotations
 
-import dataclasses
 import json
 import re
 from pathlib import Path
-from typing import Dict, List, Optional, Sequence, Union
+from typing import List, Optional, Union
 
 from repro.telemetry import metrics
-
-KERNEL_SPAN_PREFIX = "span/kernel:"
-
-
-@dataclasses.dataclass(frozen=True)
-class RooflinePeaks:
-    """Peak rates the predicted times are derived from.
-
-    Defaults are TPU v4 order-of-magnitude (275 TFLOP/s bf16 MXU,
-    1.2 TB/s HBM).  On the CPU interpret path the achieved fraction is
-    microscopic — the JOIN is the product; absolute fractions only mean
-    something on real hardware (ROADMAP "TPU-compiled benchmarks").
-    """
-    flops_per_s: float = 275e12
-    hbm_bytes_per_s: float = 1.2e12
-    name: str = "tpu-v4-like"
-
-
-DEFAULT_PEAKS = RooflinePeaks()
 
 
 def json_snapshot(snapshot: Optional[dict] = None,
@@ -100,93 +69,3 @@ def prometheus_text(snapshot: Optional[dict] = None, registry=None) -> str:
         out.append(f"{p}_sum {_fmt(h['sum'])}")
         out.append(f"{p}_count {h['count']}")
     return "\n".join(out) + "\n"
-
-
-# ---------------------------------------------------------------------------
-# predicted vs measured
-# ---------------------------------------------------------------------------
-def load_cost_rows(path: Union[str, Path, None] = None
-                   ) -> Dict[str, dict]:
-    """Static cost-model rows keyed by label (sweep + DeiT fusion rows).
-
-    ``path``: a ``repro_lint --json`` report (or bare cost_model
-    payload); default imports ``repro.analysis.cost_model`` live.
-    """
-    if path is None:
-        from repro.analysis import cost_model
-        rows = list(cost_model.build_table())
-        rows += cost_model.fusion_study()["rows"]
-    else:
-        payload = json.loads(Path(path).read_text())
-        payload = payload.get("cost_model", payload)
-        rows = list(payload.get("rows", []))
-        rows += payload.get("fusion_rows", [])
-    return {r["label"]: r for r in rows}
-
-
-def predicted_vs_measured(snapshot: Optional[dict] = None,
-                          rows: Union[Dict[str, dict],
-                                      Sequence[dict], None] = None,
-                          peaks: RooflinePeaks = DEFAULT_PEAKS,
-                          cost_report: Union[str, Path, None] = None,
-                          registry=None) -> dict:
-    """Join measured ``span/kernel:<label>/ms`` histograms against the
-    static cost-model rows of the same label.
-
-    Per joined kernel: measured mean wall-clock, the analytic roofline
-    time ``max(flops/peak_flops, hbm_bytes/peak_bw)``, which term binds,
-    and the achieved fraction ``predicted/measured`` (1.0 == running at
-    the roofline; CPU interpret mode sits far below by design).
-    Measured spans with no table row land in ``unmatched`` — a probe
-    label drifting from the sweep is a finding, not a silent drop.
-    """
-    if snapshot is None:
-        snapshot = (registry or metrics.default_registry()).snapshot()
-    if rows is None:
-        rows = load_cost_rows(cost_report)
-    elif not isinstance(rows, dict):
-        rows = {r["label"]: r for r in rows}
-
-    joined: List[dict] = []
-    unmatched: List[str] = []
-    for name, h in snapshot.get("histograms", {}).items():
-        if not (name.startswith(KERNEL_SPAN_PREFIX)
-                and name.endswith("/ms")):
-            continue
-        label = name[len(KERNEL_SPAN_PREFIX):-len("/ms")]
-        if not h["count"]:
-            continue
-        row = rows.get(label)
-        if row is None:
-            unmatched.append(label)
-            continue
-        measured_ms = h["mean"]
-        flops = int(row.get("flops", 0))
-        hbm = int(row.get("hbm_bytes", 0))
-        compute_s = flops / peaks.flops_per_s
-        memory_s = hbm / peaks.hbm_bytes_per_s
-        predicted_s = max(compute_s, memory_s)
-        measured_s = measured_ms / 1e3
-        joined.append({
-            "label": label,
-            "kernel": row.get("kernel"),
-            "samples": h["count"],
-            "measured_ms": round(measured_ms, 6),
-            "predicted_ms": round(predicted_s * 1e3, 6),
-            "bottleneck": "compute" if compute_s >= memory_s else "memory",
-            "flops": flops,
-            "hbm_bytes": hbm,
-            "intensity": row.get("intensity"),
-            "achieved_fraction":
-                round(predicted_s / measured_s, 9) if measured_s else None,
-            "achieved_gflop_per_s":
-                round(flops / measured_s / 1e9, 3) if measured_s else None,
-            "achieved_gb_per_s":
-                round(hbm / measured_s / 1e9, 3) if measured_s else None,
-        })
-    joined.sort(key=lambda r: r["label"])
-    return {
-        "peaks": dataclasses.asdict(peaks),
-        "kernels": joined,
-        "unmatched": sorted(unmatched),
-    }

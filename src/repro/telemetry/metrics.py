@@ -1,16 +1,13 @@
 """Process-local metrics registry: counters, gauges, histograms
 (DESIGN.md §15).
 
-The runtime half of the repo's observability story — the static
-cost-model table (DESIGN.md §14) predicts what the datapath *should*
-cost; these metrics record what the serving stack *actually* did, and
-``repro.telemetry.export.predicted_vs_measured`` joins the two.
+These metrics record what the serving stack *actually* did.
 
 Design constraints:
 
-* **Dependency-free** — stdlib only.  ``tracing``/``export``/``probes``
-  layer jax forwarding and kernel probes on top; this module must import
-  in any process.
+* **Dependency-free** — stdlib only.  ``tracing``/``export`` layer jax
+  forwarding and the exporters on top; this module must import in any
+  process.
 * **jit-safe by construction** — every recording method coerces its
   argument with ``float()``/``int()`` on the HOST.  A jax tracer cannot
   be coerced (it raises), so recording *inside* traced code fails loudly

@@ -14,7 +14,6 @@ import jax.numpy as jnp
 import pytest
 
 import repro.analysis as AN
-from repro.analysis import cost_model as CM
 from repro.analysis import grid_semantics as GS
 from repro.analysis import kernel_contracts as KC
 from repro.analysis import source_rules as SR
@@ -37,8 +36,8 @@ def test_fixture_fires(name):
 
 
 def test_fixture_messages_name_the_defect():
-    msgs = " ".join(str(v) for v in run_fixture("vmem-over-budget"))
-    assert "VMEM" in msgs and "cap" in msgs
+    msgs = " ".join(str(v) for v in run_fixture("race-parallel-accumulator"))
+    assert "data race" in msgs and "arbitrary" in msgs
     msgs = " ".join(str(v) for v in run_fixture("uncovered-output-block"))
     assert "never writes" in msgs
 
@@ -82,8 +81,8 @@ def test_grid_semantics_sees_the_accumulator_gates():
     """The AST scan resolves gates through partials AND the flash
     kernels' helper call — the evidence the race check rests on."""
     caps = {c.label: c for c in KC.sweep_captures()}
-    for label, axis in (("ln-matmul-bench", 1), ("matmul-bench", 1),
-                        ("flash-bench", 2), ("flash-decode", 2)):
+    for label, axis in (("ln-matmul-768", 1), ("matmul-1024", 1),
+                        ("flash-causal-256", 2), ("flash-decode", 2)):
         facts = GS.kernel_body_facts(caps[label])
         assert facts.src_ok, label
         assert axis in {g.axis for g in facts.gates}, (label, facts.gates)
@@ -91,27 +90,6 @@ def test_grid_semantics_sees_the_accumulator_gates():
     # own inputs: no gate
     facts = GS.kernel_body_facts(caps["matmul-raw"])
     assert facts.src_ok and not facts.gates, facts.gates
-
-
-def test_cost_model_clean_on_tree():
-    assert _errors(CM.run(ROOT)) == [], [str(v) for v in CM.run(ROOT)]
-
-
-def test_cost_model_reproduces_deit_fusion_saving():
-    """The static model must reproduce the ~23% LN->qkv HBM saving the
-    bench's analytic counters claim (ISSUE 8 acceptance)."""
-    fus = CM.fusion_study()
-    assert 20.0 <= fus["saving_pct"] <= 26.0, fus["saving_pct"]
-    assert fus["fused_bytes"] < fus["unfused_bytes"]
-
-
-def test_cost_model_counts_planes_separately():
-    """Mantissa and exponent planes appear as separate int8 operands."""
-    rows = {r["label"]: r for r in CM.build_table()}
-    ops = rows["ln-matmul-bench"]["operands"]
-    int8 = [o for o in ops if o["dtype"] == "int8"]
-    assert len(int8) == 2, ops
-    assert {o["bytes_unique"] for o in int8} == {768 * 768, 24 * 768}
 
 
 # ---------------------------------------------------------------------------
@@ -159,7 +137,7 @@ def test_capture_returns_real_blockspecs():
             "_flash_kernel", "_decode_kernel"} <= byk
     # the documented operand-dtype scratch contract is actually visible:
     # a bf16 (bm, K) operand for 8-bit act mantissas, f32 for 10-bit
-    for label in ("ln-matmul-bench", "matmul-bench"):
+    for label in ("ln-matmul-768", "matmul-1024"):
         cap = next(c for c in caps if c.label == label)
         assert cap.scratch[0].dtype == jnp.bfloat16, label
         assert cap.scratch[0].shape == (cap.inputs[0].block_shape[0],
@@ -223,24 +201,21 @@ def test_repro_lint_fixture_exits_nonzero(name):
 def test_repro_lint_lists_all_rules():
     r = _run_lint("--list")
     assert r.returncode == 0
-    for rule in ("kernel-contracts", "grid-semantics", "cost-model",
-                 "trace-invariants", "source-rules", "dispatch-seam",
-                 "docs-links"):
+    for rule in ("kernel-contracts", "grid-semantics", "trace-invariants",
+                 "source-rules", "dispatch-seam", "docs-links"):
         assert rule in r.stdout
 
 
 @pytest.mark.slow
 def test_repro_lint_json_roofline_table():
-    """--only cost-model --json emits the machine-readable roofline the
-    CI lanes archive and benchmarks/roofline.py ingests."""
+    """--json emits the machine-readable violations report the CI lanes
+    archive: every registered rule, each violation, the error count."""
     import json
 
-    r = _run_lint("--only", "cost-model", "--json")
+    r = _run_lint("--only", "source-rules,kernel-contracts", "--json")
     assert r.returncode == 0, r.stderr
     payload = json.loads(r.stdout)
-    rows = {row["label"]: row for row in payload["cost_model"]["rows"]}
-    assert "ln-matmul-bench" in rows and "flash-deit" in rows
-    for row in rows.values():
-        assert row["hbm_bytes"] > 0 and row["vmem_bytes"] > 0
-    fusion = payload["cost_model"]["fusion"]
-    assert 20.0 <= fusion["saving_pct"] <= 26.0
+    assert {"source-rules", "kernel-contracts",
+            "grid-semantics"} <= set(payload["rules"])
+    assert payload["errors"] == 0
+    assert all(v["severity"] != AN.ERROR for v in payload["violations"])

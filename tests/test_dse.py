@@ -278,7 +278,7 @@ class TestEvaluator:
         space = SearchSpace(base=base, groups=(
             GroupSpace(scope="block/*", weight_mant_bits=(8, 4)),))
         reg = metrics.Registry()
-        ev = Evaluator(space, cfg, params, imgs, kernel_rows=(),
+        ev = Evaluator(space, cfg, params, imgs,
                        registry=reg)
         p = space.baseline_point()
         r1 = ev(p)
@@ -299,7 +299,7 @@ class TestEvaluator:
         cfg, base, params, imgs = _micro_setup(n_layers=2)
         space = SearchSpace(base=base, groups=(
             GroupSpace(scope="block/*", weight_mant_bits=(8, 4)),))
-        ev = Evaluator(space, cfg, params, imgs, kernel_rows=(),
+        ev = Evaluator(space, cfg, params, imgs,
                        registry=metrics.Registry())
         wide = ev(space.baseline_point())
         p = space.baseline_point()
@@ -323,7 +323,7 @@ class TestDrivers:
             GroupSpace(scope="block/*/ffn", weight_mant_bits=(8, 3)),
             GroupSpace(scope="head", weight_mant_bits=(8, 3))))
         assert space.size() == 8 <= 16
-        ev = Evaluator(space, cfg, params, imgs, kernel_rows=(),
+        ev = Evaluator(space, cfg, params, imgs,
                        registry=metrics.Registry())
         results = exhaustive_search(space, ev)
         assert len(results) == 8
@@ -350,7 +350,7 @@ class TestDrivers:
         cfg, base, params, imgs = _micro_setup()
         space = SearchSpace(base=base, groups=(
             GroupSpace(scope="block/*", weight_mant_bits=(8, 6, 4)),))
-        ev = Evaluator(space, cfg, params, imgs, kernel_rows=(),
+        ev = Evaluator(space, cfg, params, imgs,
                        registry=metrics.Registry())
         with pytest.raises(ValueError, match="exhaustive limit"):
             exhaustive_search(space, ev, limit=2)
@@ -359,7 +359,7 @@ class TestDrivers:
         cfg, base, params, imgs = _micro_setup()
         space = SearchSpace(base=base, groups=(
             GroupSpace(scope="block/*", weight_mant_bits=(8, 6, 4)),))
-        ev = Evaluator(space, cfg, params, imgs, kernel_rows=(),
+        ev = Evaluator(space, cfg, params, imgs,
                        registry=metrics.Registry())
         res = greedy_search(space, ev, budget=1.0)
         assert res.bits == {"block/*": 4}
@@ -374,7 +374,7 @@ class TestDrivers:
         cfg, base, params, imgs = _micro_setup()
         space = SearchSpace(base=base, groups=(
             GroupSpace(scope="block/*", weight_mant_bits=(8, 6, 4)),))
-        ev = Evaluator(space, cfg, params, imgs, kernel_rows=(),
+        ev = Evaluator(space, cfg, params, imgs,
                        registry=metrics.Registry())
         res = greedy_search(space, ev, budget=-1.0)
         assert res.bits == {"block/*": 8}
@@ -384,7 +384,7 @@ class TestDrivers:
         cfg, base, params, imgs = _micro_setup()
         space = SearchSpace(base=base, groups=(
             GroupSpace(scope="block/*", weight_mant_bits=(8, 4)),))
-        ev = Evaluator(space, cfg, params, imgs, kernel_rows=(),
+        ev = Evaluator(space, cfg, params, imgs,
                        registry=metrics.Registry())
         with pytest.raises(ValueError, match="act_mant_bits"):
             greedy_search(space, ev, knob="act_mant_bits")

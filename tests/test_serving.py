@@ -216,9 +216,8 @@ class _StubEngine:
 
 
 class TestSchedulerEdgeCases:
-    def _mk(self, batch=2, eos=None, admission="slot"):
-        return BatchScheduler(_StubEngine(), batch_size=batch, eos_id=eos,
-                              admission=admission)
+    def _mk(self, batch=2, eos=None):
+        return BatchScheduler(_StubEngine(), batch_size=batch, eos_id=eos)
 
     def test_empty_queue_step_is_noop(self):
         sched = self._mk()
@@ -279,25 +278,6 @@ class TestSchedulerEdgeCases:
         assert not long.done                       # still occupying its slot
         done = sched.run()
         assert {r.uid for r in done} == {r.uid for r in shorts} | {0}
-
-    def test_wave_admission_defers_until_batch_drains(self):
-        """admission='wave' retains the old policy (the kernel_bench
-        baseline): no admission while any slot is active."""
-        sched = self._mk(batch=2, admission="wave")
-        a = Request(uid=0, prompt=np.asarray([10]), max_new_tokens=2)
-        b = Request(uid=1, prompt=np.asarray([20]), max_new_tokens=4)
-        c = Request(uid=2, prompt=np.asarray([30]), max_new_tokens=2)
-        sched.submit(a)
-        sched.submit(b)
-        sched.step()                               # admit wave {A, B}
-        sched.submit(c)
-        sched.step()                               # A done; B alive
-        assert a.done
-        sched.step()                               # slot must idle
-        assert c.generated == []                   # deferred admission
-        done = sched.run()
-        assert c.done and c.generated == [30, 31]  # admitted after drain
-        assert {r.uid for r in done} == {0, 1, 2}
 
     def test_eos_request_evicted_to_finished(self):
         sched = self._mk(batch=1, eos=12)

@@ -1,6 +1,5 @@
 """repro.telemetry unit tests: registry semantics, span recording,
-exporters, thread-safety, and the predicted-vs-measured join
-(DESIGN.md §15)."""
+exporters and thread-safety (DESIGN.md §15)."""
 import gc
 import json
 import threading
@@ -9,8 +8,7 @@ import time
 import pytest
 
 from repro import telemetry as T
-from repro.telemetry.export import (json_snapshot, predicted_vs_measured,
-                                    prometheus_text)
+from repro.telemetry.export import json_snapshot, prometheus_text
 from repro.telemetry.metrics import Registry
 from repro.telemetry.tracing import (current_span, now_ns, recording, span,
                                      span_stats)
@@ -288,35 +286,6 @@ class TestExport:
         on_disk = json.loads(out.read_text())
         assert on_disk["counters"]["req/total"] == 2
         assert on_disk["tag"] == "t1"
-
-    def test_predicted_vs_measured_join(self, reg):
-        # two measured kernel spans; only one has a static row
-        for label, ms in (("matmul-deit", 2.0), ("mystery", 1.0)):
-            reg.histogram(f"span/kernel:{label}/ms",
-                          T.DEFAULT_MS_BUCKETS).record(ms)
-        rows = [{"label": "matmul-deit", "kernel": "mxint_matmul",
-                 "flops": 2 * 400 * 192 * 256,
-                 "hbm_bytes": 400 * 192 * 4 + 192 * 256 + 6 * 256,
-                 "intensity": 7.9}]
-        rep = predicted_vs_measured(reg.snapshot(), rows)
-        assert rep["unmatched"] == ["mystery"]
-        (k,) = rep["kernels"]
-        assert k["label"] == "matmul-deit"
-        assert k["kernel"] == "mxint_matmul"
-        assert k["samples"] == 1
-        assert k["measured_ms"] == pytest.approx(2.0)
-        # predicted = max(flops/peak, bytes/bw); join math is exact
-        peaks = rep["peaks"]
-        want = max(k["flops"] / peaks["flops_per_s"],
-                   k["hbm_bytes"] / peaks["hbm_bytes_per_s"]) * 1e3
-        assert k["predicted_ms"] == pytest.approx(want, abs=1e-6)
-        assert k["achieved_fraction"] == pytest.approx(want / 2.0, abs=1e-6)
-        assert k["bottleneck"] in ("compute", "memory")
-
-    def test_predicted_vs_measured_skips_empty_histograms(self, reg):
-        reg.histogram("span/kernel:idle/ms", T.DEFAULT_MS_BUCKETS)
-        rep = predicted_vs_measured(reg.snapshot(), [])
-        assert rep["kernels"] == [] and rep["unmatched"] == []
 
 
 # ---------------------------------------------------------------------------

@@ -13,7 +13,11 @@ ragged last block, fc2 at K = 3072 the largest VMEM case.  One test
 compiles the whole jitted DeiT-Base kernel-mode forward at each batch and
 counts its Pallas custom calls.  The SAM ViT-B encoder's attention cores
 (window and global, with the relative-position bias), neck kernels and
-whole forward compile at the benchmark's batch of 8 images.
+whole forward compile at the benchmark's batch of 8 images.  The kernel
+variants no served configuration launches (activations not quantized,
+f32 operands, int16 weight planes, RMSNorm, SiLU, causal, grouped and
+windowed flash attention over several key blocks) compile at stand-alone
+shapes.
 
 The topology is described inside a fixture, never while a module is
 imported: only one process at a time may load the TPU library, and every
@@ -32,7 +36,7 @@ from repro.kernels import ops
 from repro.models.model_api import Param
 
 BATCHES = (8, 32)
-F32, I8 = jnp.float32, jnp.int8
+F32, I8, I16 = jnp.float32, jnp.int8, jnp.int16
 KCFG = QuantConfig(mode="kernel", quantize_nonlinear=True)
 WIDTHS = ("deit_tiny", "deit_small", "deit_base")
 
@@ -250,3 +254,70 @@ def test_sam_vit_b_forward_compiles_for_v5e(one_chip, compiled_path):
     # (the same with the flash kernel for the softmax); the neck's two
     # linears and two LayerNorms
     assert text.count("tpu_custom_call") == 1 + 2 * 8 + 8 + 4
+
+
+# -- kernel variants no served configuration launches ------------------------
+def _variant_linear(*, quantize_act=True, act_mant_bits=8, w_dtype=I8):
+    """128x1024 @ 1024x512 with the paper's 256-wide weight blocks."""
+    fn = (lambda x, wm, we: ops.mxint_linear(
+        x, wm, we, w_block=256, quantize_act=quantize_act,
+        act_mant_bits=act_mant_bits))
+    return fn, [((128, 1024), F32), ((1024, 512), w_dtype), ((4, 512), I8)]
+
+
+def _variant_ln_linear(*, rms_only=False, mant_bits=8):
+    """LayerNorm/RMSNorm -> 256x768 @ 768x768."""
+    fn = (lambda x, g, b, wm, we: ops.mxint_ln_linear_op(
+        x, g, None if rms_only else b, wm, we, w_block=256,
+        mant_bits=mant_bits, rms_only=rms_only))
+    return fn, [((256, 768), F32), ((768,), F32), ((768,), F32),
+                ((768, 768), I8), ((3, 768), I8)]
+
+
+def _variant_flash(*, sq, sk, heads=4, kv_heads=4, **kw):
+    """(1, heads, sq, 64) queries over (1, kv_heads, sk, 64) keys."""
+    fn = lambda q, k, v: ops.attention_op(q, k, v, **kw)    # noqa: E731
+    kv = ((1, kv_heads, sk, 64), F32)
+    return fn, [((1, heads, sq, 64), F32), kv, kv]
+
+
+VARIANTS = {
+    "matmul_raw": _variant_linear(quantize_act=False),
+    "matmul_f32_operand": _variant_linear(act_mant_bits=10),
+    "matmul_int16_planes": _variant_linear(w_dtype=I16),
+    "matmul_1024": _variant_linear(),
+    "ln_matmul_rms": _variant_ln_linear(rms_only=True),
+    "ln_matmul_f32_operand": _variant_ln_linear(mant_bits=10),
+    "layernorm_rms": (lambda x, g: ops.mxint_layernorm_op(
+        x, g, None, rms_only=True, quantize_out=True),
+        [((256, 768), F32), ((768,), F32)]),
+    # 256-row blocks of 768; at 3,072 wide SiLU's blocks overrun the
+    # 16 MiB scoped VMEM (GELU's fit), a defect ROADMAP.md records
+    "gelu_silu": (lambda x: ops.mxint_gelu_op(x, fn="silu"),
+                  [((256, 768), F32)]),
+    # float exp, no score quantization: 128-key blocks over 1,024 keys
+    "flash_float_causal": _variant_flash(sq=1024, sk=1024, causal=True,
+                                         exp_mode="float"),
+    # quantized scores past one whole-row block: 128-key blocks
+    "flash_quantized_multiblock": _variant_flash(
+        sq=256, sk=ops._FLASH_ROW_KEYS + 128, causal=False,
+        exp_mode="mxint", quantize_scores=True),
+    "flash_gqa": _variant_flash(sq=256, sk=1024, kv_heads=2, causal=True,
+                                exp_mode="mxint"),
+    "flash_window": _variant_flash(sq=1024, sk=1024, causal=True,
+                                   window=256, exp_mode="mxint"),
+}
+
+
+@pytest.mark.parametrize("case", sorted(VARIANTS))
+def test_kernel_variant_compiles_for_v5e(case, one_chip, compiled_path):
+    fn, shapes = VARIANTS[case]
+    args = [jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+            for shape, dtype in shapes]
+    ops.reset_attention_tiling()
+    text = _compile(fn, *args).as_text()
+    assert "tpu_custom_call" in text
+    if case.startswith("flash_"):
+        (tiling,) = ops.attention_tiling()
+        assert tiling["path"] == "flash" and tiling["block_k"] == 128
+        assert tiling["k_blocks"] > 1

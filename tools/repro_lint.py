@@ -10,10 +10,9 @@ registry in-process via ``tests/test_analysis.py``::
     PYTHONPATH=src python tools/repro_lint.py            # whole tree
     python tools/repro_lint.py --list                    # show rules
     python tools/repro_lint.py --only source-rules       # subset
-    python tools/repro_lint.py --fixture vmem-over-budget  # must exit 1
+    python tools/repro_lint.py --fixture f64-leak        # must exit 1
     python tools/repro_lint.py --fixtures                # list fixtures
-    python tools/repro_lint.py --only cost-model --json  # roofline table
-    python tools/repro_lint.py --update-cost-baseline    # refresh bytes
+    python tools/repro_lint.py --json report.json        # violations report
 
 Exit code 0 iff no error-severity violation (``warn`` findings print but
 do not fail).  ``--fixture NAME`` runs one deliberately violating
@@ -76,21 +75,12 @@ def main(argv=None) -> int:
                     help="list fixture names and exit")
     ap.add_argument("--json", nargs="?", const="-", default=None,
                     metavar="PATH",
-                    help="emit a machine-readable report (violations + "
-                         "cost-model roofline table) to PATH or stdout")
-    ap.add_argument("--update-cost-baseline", action="store_true",
-                    help="rewrite benchmarks/_cache/cost_model_baseline"
-                         ".json from the current tree and exit")
+                    help="emit a machine-readable violations report "
+                         "to PATH or stdout")
     args = ap.parse_args(argv)
 
     import repro.analysis as AN
     _register_legacy_rules()
-
-    if args.update_cost_baseline:
-        from repro.analysis import cost_model
-        path = cost_model.write_baseline(ROOT)
-        print(f"repro_lint: wrote {path.relative_to(ROOT)}")
-        return 0
 
     if args.list:
         for rule in AN.rules():
@@ -127,14 +117,12 @@ def main(argv=None) -> int:
     if args.json is not None:
         import json as _json
 
-        from repro.analysis import cost_model
         payload = {
             "rules": [r.name for r in AN.rules()],
             "violations": [
                 {"rule": v.rule, "where": v.where, "severity": v.severity,
                  "message": v.message} for v in violations],
             "errors": len(errors),
-            "cost_model": cost_model.report(ROOT),
         }
         text = _json.dumps(payload, indent=1, sort_keys=True)
         if args.json == "-":
